@@ -105,6 +105,9 @@ def main(argv=None) -> None:
                     help="append rows to a JSON perf-trajectory file")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (churn, fig6_frac_bits, fig35_breakdown,
                             kernel_bench, roofline_report, table1_lut_depth,
                             table2_resources, table3_throughput)

@@ -60,12 +60,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fxp import FxpFormat
 from repro.core.quantize import quantize_lstm_model, quantized_lstm_forward
-from repro.data.traffic import make_pems_like_series, make_windows, normalize
+from repro.data.traffic import (make_fleet_windows, make_pems_like_fleet,
+                                make_traffic_dataset, normalize)
 from repro.models.lstm_model import (evaluate_mse, evaluate_quantized_mse,
                                      train_traffic_model)
-from repro.data.traffic import make_traffic_dataset
 
 
 def main(argv=None):
@@ -153,6 +154,7 @@ def main(argv=None):
         ap.error("--kill-after needs --checkpoint-dir to restore from")
     if args.ingest and not args.engine:
         ap.error("--ingest fronts the SensorFleetEngine; pass --engine too")
+    enable_compile_cache()
     _enable_obs(args)
 
     # --- train on one sensor (paper; --cell gru swaps the recurrent cell) ---
@@ -203,8 +205,8 @@ def main(argv=None):
     # --- fleet serving -------------------------------------------------------
     print(f"serving {args.sensors} sensors (windows of 6 x 5-min points) "
           f"via backend={args.backend!r}")
-    fleet = np.stack([normalize(make_pems_like_series(seed=s))[0]
-                      for s in range(args.sensors)])          # (N, 8064)
+    fleet = np.stack([normalize(series)[0] for series in
+                      make_pems_like_fleet(range(args.sensors))])  # (N, 8064)
     serve = jax.jit(functools.partial(quantized_lstm_forward, backend=args.backend))
 
     total = 0
@@ -274,16 +276,9 @@ def serve_fleet_engine(qmodel, args):
           "(all layers' state carried per slot)")
 
     def _streams():
-        rng = np.random.default_rng(0)
-        out = []
-        for s in range(args.sensors):
-            series, _, _ = normalize(make_pems_like_series(seed=s))
-            lo = int(rng.integers(100, 200))
-            n = int(rng.integers(6, 19))              # ragged history length
-            window = series[lo : lo + n][:, None].astype(np.float32)
-            qxs = np.asarray(fxp_mod.quantize(jnp.asarray(window), fmt))
-            out.append(SensorStream(rid=s, qxs=qxs))
-        return out
+        return [SensorStream(rid=s, qxs=np.asarray(
+                    fxp_mod.quantize(jnp.asarray(window), fmt)))
+                for s, window in enumerate(make_fleet_windows(args.sensors))]
 
     def _engine():
         return SensorFleetEngine(qmodel.lstm, fmt, luts, batch_slots=slots,

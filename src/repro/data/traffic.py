@@ -23,6 +23,8 @@ import numpy as np
 __all__ = [
     "PEMS_POINTS_PER_DAY",
     "make_pems_like_series",
+    "make_pems_like_fleet",
+    "make_fleet_windows",
     "normalize",
     "make_windows",
     "train_test_split",
@@ -37,7 +39,15 @@ PEMS_TOTAL_POINTS = PEMS_POINTS_PER_DAY * 7 * PEMS_WEEKS  # 8064, as in the pape
 
 def make_pems_like_series(seed: int = 0, n_points: int = PEMS_TOTAL_POINTS) -> np.ndarray:
     """Synthetic single-sensor freeway speed series in mph."""
-    rng = np.random.default_rng(seed)
+    return make_pems_like_fleet([seed], n_points)[0]
+
+
+def make_pems_like_fleet(seeds, n_points: int = PEMS_TOTAL_POINTS) -> np.ndarray:
+    """``(len(seeds), n_points)`` series, row ``i`` bit-identical to
+    ``make_pems_like_series(seeds[i], n_points)``.  The AR(1) noise runs
+    vectorised over sensors (the same float64 operations in the same order
+    per sensor), so a deployment-scale fleet is made in seconds."""
+    rngs = [np.random.default_rng(s) for s in seeds]
     t = np.arange(n_points)
     tod = (t % PEMS_POINTS_PER_DAY) / PEMS_POINTS_PER_DAY  # time of day in [0,1)
     dow = (t // PEMS_POINTS_PER_DAY) % 7                   # day of week
@@ -54,22 +64,46 @@ def make_pems_like_series(seed: int = 0, n_points: int = PEMS_TOTAL_POINTS) -> n
     weekend_dip = 6.0 * gauss(tod, 13.0 / 24, 2.5 / 24) * (1.0 - weekday)
     speed = free_flow - weekday * (am_dip + pm_dip) - weekend_dip
 
-    # AR(1) measurement noise (loop detectors are noisy but correlated)
-    noise = np.zeros(n_points)
+    # AR(1) measurement noise (loop detectors are noisy but correlated);
+    # time-major so each step updates one contiguous row of sensors
+    noise = np.zeros((n_points, len(rngs)))
+    for j, rng in enumerate(rngs):
+        noise[1:, j] = rng.normal(0.0, 1.1, size=n_points - 1)
     for i in range(1, n_points):
-        noise[i] = 0.85 * noise[i - 1] + rng.normal(0.0, 1.1)
-    speed = speed + noise
+        noise[i] = 0.85 * noise[i - 1] + noise[i]
+    speed = (speed[:, None] + noise).T
 
     # sporadic incidents: sharp dips with exponential recovery
     n_incidents = max(1, n_points // 2000)
-    for _ in range(n_incidents):
-        start = rng.integers(0, n_points - 60)
-        depth = rng.uniform(15.0, 35.0)
-        dur = rng.integers(6, 30)
-        rec = np.exp(-np.arange(dur) / (dur / 3.0))
-        speed[start : start + dur] -= depth * rec
+    for row, rng in zip(speed, rngs):
+        for _ in range(n_incidents):
+            start = rng.integers(0, n_points - 60)
+            depth = rng.uniform(15.0, 35.0)
+            dur = rng.integers(6, 30)
+            rec = np.exp(-np.arange(dur) / (dur / 3.0))
+            row[start : start + dur] -= depth * rec
 
     return np.clip(speed, 3.0, 80.0)
+
+
+def make_fleet_windows(n_sensors: int, seed: int = 0,
+                       block: int = 1024) -> list[np.ndarray]:
+    """Ragged recent histories for a PeMS-like sensor fleet (sensors report
+    unevenly in the wild): sensor ``s`` is series ``s``, normalised, and
+    reports 6..18 consecutive 5-minute points from a random offset in
+    [100, 200).  ``seed`` draws the offsets and lengths.  Returns one
+    ``(n_s, 1)`` float32 window per sensor; series are made ``block`` at a
+    time to bound memory."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for first in range(0, n_sensors, block):
+        for series in make_pems_like_fleet(
+                range(first, min(first + block, n_sensors))):
+            norm, _, _ = normalize(series)
+            lo = int(rng.integers(100, 200))
+            n = int(rng.integers(6, 19))
+            out.append(norm[lo : lo + n][:, None].astype(np.float32))
+    return out
 
 
 def normalize(series: np.ndarray) -> tuple[np.ndarray, float, float]:

@@ -15,8 +15,9 @@ One ``pallas_call`` performs all ``n_seq`` steps of all ``L`` layers:
 
 * int32 stacked-gate weights ``(L*4, F, Hp)``, biases and both LUT tables are
   loaded into VMEM once (C5);
-* each step is, per layer, one int32-accumulate matmul over ``[x_t, h]``
-  (C1), a round-half-up shift + saturate into that gate's own ``(x, y)``
+* each step is, per layer, one exact integer matmul over ``[x_t, h]`` (C1;
+  bf16 pieces on the MXU, see ``_int_dot``), a round-half-up shift +
+  saturate into that gate's own ``(x, y)``
   format (C4), the LUT gather for all four gates (C3, as a one-hot MXU
   contraction), and the fused elementwise tail (C2) — all against
   VMEM-resident tiles;
@@ -86,6 +87,7 @@ hetero-``H`` stack against ``tests/golden/lstm_mixed_golden.json``.  Oracle:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -100,13 +102,66 @@ __all__ = [
     "lstm_sequence_fxp_stack_pallas",
     "gru_sequence_fxp_pallas",
     "gru_sequence_fxp_stack_pallas",
+    "DEFAULT_BLOCK_B",
 ]
+
+# Batch rows per grid step.  Every LUT gather materialises a (bb, 128, depth)
+# f32 one-hot, so Mosaic's compile time grows with bb: compiling for TPU v5e
+# takes 1-2 s per (L=1, H=20, depth-256) shape at 16 rows against ~10 s at
+# 64, ~40 s at 128 and over 10 minutes at 1024.  Larger batches tile over the
+# (sequential) batch grid instead.
+DEFAULT_BLOCK_B = 16
+
+
+# C1 on the MXU.  TPU v5e's MXU multiplies bf16 and int8 but refuses an
+# int32 x int32 dot, so the integer matmul is decomposed exactly: every
+# operand of at most 16 bits splits as ``a = hi * 256 + lo`` with ``hi`` in
+# [-128, 128] and ``lo`` in [-128, 127] -- both exact in bf16 (a signed int8
+# split would overflow at hi = 128, i.e. for a >= 32640).  Each of the four
+# partial products is at most 2**14 in magnitude, so an f32 accumulation over
+# F < 1024 terms stays below 2**24 and is exact; the int32 recombination
+# wraps like the oracle's int32 matmul, so the result is the int32 dot bit
+# for bit.  ``_check_int_dot_widths`` enforces both bounds at trace time.
+_INT_DOT_MAX_BITS = 16
+_INT_DOT_MAX_F = 1024
+
+
+def _split8(a):
+    """Split int32 ``a`` (at most 16 bits) into exact bf16 ``(hi, lo)`` pieces
+    with ``a == hi * 256 + lo``."""
+    hi = (a + 128) >> 8
+    lo = a - (hi << 8)
+    return hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16)
 
 
 def _int_dot(a, b):
-    return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
+    """Exact int32 ``a @ b`` from ``_split8`` pieces of both operands: four
+    bf16 MXU passes accumulated in f32, recombined in int32."""
+    (a_hi, a_lo), (b_hi, b_lo) = a, b
+
+    def dot(x, y):
+        return jax.lax.dot_general(
+            x, y, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ).astype(jnp.int32)
+
+    return ((dot(a_hi, b_hi) << 16)
+            + ((dot(a_hi, b_lo) + dot(a_lo, b_hi)) << 8)
+            + dot(a_lo, b_lo))
+
+
+def _check_int_dot_widths(fmt_spec, F):
+    """Trace-time guard: ``_int_dot`` is exact only for operands of at most
+    16 bits contracted over F < 1024 terms.  The operands are each layer's
+    data-format integers (inputs, ``h`` and weights), whose width is static."""
+    for l, ((_, y_d), _) in enumerate(fmt_spec):
+        if y_d > _INT_DOT_MAX_BITS:
+            raise ValueError(
+                f"layer {l}: data format has {y_d} total bits; the fused "
+                f"kernel's exact MXU matmul takes at most {_INT_DOT_MAX_BITS}")
+    if F >= _INT_DOT_MAX_F:
+        raise ValueError(
+            f"contraction width F = {F} (input + hidden lanes) must be < "
+            f"{_INT_DOT_MAX_F} for the kernel's f32 accumulation to be exact")
 
 
 def _rnn_seq_fxp_kernel(
@@ -157,6 +212,7 @@ def _rnn_seq_fxp_kernel(
     w = w_ref[...]                      # (L*4, F, Hp) int32 — loaded once (C5)
     b = b_ref[...]                      # (L*4, Hp) int32
     F, Hp = w.shape[1], w.shape[2]
+    w_hi, w_lo = _split8(w)             # bf16 MXU pieces, split once per tile
     in_w = F - Hp                       # padded input width (= n_in for L=1)
 
     def sat(v, y):
@@ -180,17 +236,27 @@ def _rnn_seq_fxp_kernel(
     def gather(table, idx, depth):
         if mxu_onehot:
             # One-hot MXU contraction (exact: adding zeros to the hit entry).
+            # HIGHEST keeps the f32 table entries whole; the TPU default
+            # would round them to bf16 before the multiply.
             iota = jax.lax.broadcasted_iota(jnp.int32, (*idx.shape, depth), idx.ndim)
             onehot = (iota == idx[..., None]).astype(jnp.float32)
             return jax.lax.dot_general(
                 onehot, table, (((idx.ndim,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )
         return jnp.take(table, idx, axis=0)
 
     def lut_act(q, table, lo, step, depth, in_frac, out_x, out_y):
         x = q.astype(jnp.float32) * (2.0 ** (-in_frac))
-        idx = jnp.clip(jnp.floor((x - lo) / step).astype(jnp.int32), 0, depth - 1)
+        # A power-of-two bin width (every default range and depth) divides
+        # exactly as a multiply by its reciprocal, which leaves no rounding
+        # choice to the TPU's divide; other widths keep the oracle's divide.
+        if math.frexp(step)[0] == 0.5:
+            pos = (x - lo) * (1.0 / step)
+        else:
+            pos = (x - lo) / step
+        idx = jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, depth - 1)
         return quant(gather(table, idx, depth), out_x, out_y)
 
     if use_lut:
@@ -223,14 +289,15 @@ def _rnn_seq_fxp_kernel(
             # keeps bit-exactness; zero-padded rows x zero-padded inputs add
             # 0.  The accumulator carries 2*xd fractional bits; each gate's
             # rescale shift 2*xd - x_g lands directly in that gate's format.
-            def zgate(g, x_in):
-                return shift_rs(_int_dot(x_in, w[n_gates * l + g])
-                                + (b[n_gates * l + g][None, :] << xd),
+            def zgate(g, x_pieces):
+                k = n_gates * l + g
+                return shift_rs(_int_dot(x_pieces, (w_hi[k], w_lo[k]))
+                                + (b[k][None, :] << xd),
                                 2 * xd - gate_fmts[g][0], gate_fmts[g][1])
 
             if cell_kind == "lstm":
                 qc = cs[l]
-                qxh = jnp.concatenate([inp, qh], axis=-1)  # (bb, F)
+                qxh = _split8(jnp.concatenate([inp, qh], axis=-1))  # (bb, F)
                 z = [zgate(g, qxh) for g in range(4)]
                 i_t = act_sig(z[0], gate_fmts[0][0], xd, yd)
                 f_t = act_sig(z[1], gate_fmts[1][0], xd, yd)
@@ -241,12 +308,12 @@ def _rnn_seq_fxp_kernel(
                 qc_new = sat(fmul(f_t, qc) + fmul(i_t, g_t), yd)
                 qh_new = fmul(o_t, act_tanh(qc_new, xd, xd, yd))
             else:                                      # gru (see core.cell)
-                qxh = jnp.concatenate([inp, qh], axis=-1)
+                qxh = _split8(jnp.concatenate([inp, qh], axis=-1))
                 r_t = act_sig(zgate(0, qxh), gate_fmts[0][0], xd, yd)
                 z_t = act_sig(zgate(1, qxh), gate_fmts[1][0], xd, yd)
                 # Candidate gate's matmul runs over [x_t, r_t * h_{t-1}] —
                 # the reset is applied to the state ENTERING the matmul.
-                qxh2 = jnp.concatenate([inp, fmul(r_t, qh)], axis=-1)
+                qxh2 = _split8(jnp.concatenate([inp, fmul(r_t, qh)], axis=-1))
                 n_t = act_tanh(zgate(2, qxh2), gate_fmts[2][0], xd, yd)
                 # h' = (1 - z)*n + z*h with 1 exactly on-grid as 1 << xd.
                 one_minus_z = sat(jnp.int32(1 << xd) - z_t, yd)
@@ -316,6 +383,7 @@ def _rnn_seq_fxp_call(
     B, T, in_w = qxs.shape
     Lg, F, Hp = w4.shape
     L = Lg // n_gates
+    _check_int_dot_widths(fmt_spec, F)
     use_lut = sig_table.shape[0] > 1 or tanh_table.shape[0] > 1
     sig_depth = sig_table.shape[0]
     tanh_depth = tanh_table.shape[0]
@@ -382,7 +450,7 @@ def _rnn_seq_fxp_call(
         ],
         # Neither grid dimension is safely parallelisable: time chunks carry
         # the recurrence, and batch tiles re-initialise the shared scratch.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -449,7 +517,7 @@ def lstm_sequence_fxp_stack_pallas(
     tanh_lo: float = -4.0,
     tanh_hi: float = 4.0,
     return_sequence: bool = False,
-    block_b: int = 128,
+    block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
     mxu_onehot: bool = True,
     interpret: bool = False,
@@ -592,7 +660,7 @@ def gru_sequence_fxp_stack_pallas(
     tanh_lo: float = -4.0,
     tanh_hi: float = 4.0,
     return_sequence: bool = False,
-    block_b: int = 128,
+    block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
     mxu_onehot: bool = True,
     interpret: bool = False,
@@ -636,7 +704,7 @@ def gru_sequence_fxp_pallas(
     tanh_lo: float = -4.0,
     tanh_hi: float = 4.0,
     return_sequence: bool = False,
-    block_b: int = 128,
+    block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
     mxu_onehot: bool = True,
     interpret: bool = False,
@@ -679,7 +747,7 @@ def lstm_sequence_fxp_pallas(
     tanh_lo: float = -4.0,
     tanh_hi: float = 4.0,
     return_sequence: bool = False,
-    block_b: int = 128,
+    block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
     mxu_onehot: bool = True,
     interpret: bool = False,

@@ -25,12 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only compiler params; absent on CPU-only installs is fine.
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssd_chunk_scan_pallas"]
 
@@ -113,12 +108,10 @@ def ssd_chunk_scan_pallas(
     n_chunks = Tp // chunk
 
     kwargs = {}
-    if _HAS_PLTPU and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas TPU scratch unavailable in this install")
     scratch = [pltpu.VMEM((P, N), jnp.float32)]
 
     y, h_fin = pl.pallas_call(

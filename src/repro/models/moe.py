@@ -26,7 +26,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import axis_size
 
 __all__ = [
     "MoEWeights",
@@ -110,7 +109,7 @@ def moe_expert_parallel(
     """Expert-parallel MoE for use inside shard_map.  See module docstring."""
     t_loc, d = x.shape
     e_local = w.w_up.shape[0]
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     n_experts = e_local * n_shards
 
     # --- route (router weights are replicated across the axis) -------------
@@ -181,7 +180,7 @@ def moe_expert_parallel_gathered(
     results are psum-combined.  Communication = one psum of (T_local, d)."""
     t_loc, d = x.shape
     e_local = w.w_up.shape[0]
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
 
     top_w, top_e, aux = router_topk(x, w.router, top_k)

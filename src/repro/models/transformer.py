@@ -41,7 +41,6 @@ from repro.models.common import (
     rms_norm,
     softcap,
 )
-from repro.parallel import sharding
 from repro.parallel.sharding import RunContext, constrain
 
 __all__ = ["init_params", "forward", "init_cache", "loss_fn", "Model", "build"]
@@ -249,22 +248,18 @@ def _moe_apply(p: moe_mod.MoEWeights, x, cfg: ModelConfig, ctx: RunContext, mode
             # out_spec P() demands full replication).  The gathered decode
             # path computes the router identically on every model shard, so
             # aux is invarying over tp — pvary before the global pmean.
-            # (vma tracking only exists on newer jax; 0.4.x runs check-free.)
-            if hasattr(jax, "typeof"):
-                missing = tuple(a for a in all_axes
-                                if a not in jax.typeof(aux).vma)
-                if missing:
-                    aux = jax.lax.pvary(aux, missing)
+            missing = tuple(a for a in all_axes
+                            if a not in jax.typeof(aux).vma)
+            if missing:
+                aux = jax.lax.pvary(aux, missing)
             return yy, jax.lax.pmean(aux, all_axes)
 
-        # keep the vma checker on where it exists (the pvary block above
-        # satisfies it); 0.4.x's rep-tracker doesn't model these collectives
-        y2, aux = sharding.shard_map(
+        # the vma checker stays on: the pvary above satisfies it
+        y2, aux = jax.shard_map(
             body,
             mesh=ctx.mesh,
             in_specs=(tok_spec, wspec),
             out_specs=(tok_spec, P()),
-            check=hasattr(jax, "typeof"),
         )(x2, p)
     else:
         y2, aux = moe_mod.moe_dense_sort(x2, p, cfg.top_k, act)

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
 
 __all__ = ["pipeline_apply", "split_stages"]
 
@@ -99,11 +98,11 @@ def pipeline_apply(
             [((n_stages - 1 + i) % n_stages, i) for i in range(n_stages)])
         return outs[None]
 
-    stacked = shard_map(
+    stacked = jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name),
-        check=False,
+        check_vma=False,
     )(staged_params, x)
     return stacked[0]

@@ -27,44 +27,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["RunContext", "constrain", "param_pspec", "param_shardings",
-           "logical_rules", "fleet_slot_specs", "fleet_mesh", "shard_map",
-           "axis_size"]
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a manual mesh axis, inside ``shard_map``/``pmap``.
-
-    Newer jax spells this ``jax.lax.axis_size``; 0.4.x lacks it, but
-    ``lax.psum`` of the literal ``1`` constant-folds to the axis size as a
-    plain Python int on every version — so callers can keep using the result
-    in static shape arithmetic.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False,
-              auto: frozenset | None = None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  ``check``
-    maps onto whichever replication/varying-manual-axes checker the installed
-    jax has; it defaults off because every caller here writes explicit
-    out_specs and several (EP MoE, pipeline) trip the 0.4.x rep-tracker on
-    collectives it doesn't model.
-    """
-    kw = {}
-    if auto is not None:
-        kw["auto"] = auto
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, **kw)
+           "logical_rules", "fleet_slot_specs", "fleet_mesh"]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -115,9 +115,10 @@ from repro.core import fxp as fxp_mod
 from repro.core.cell import GRUParams, cell_spec
 from repro.core.fxp import FxpFormat, StackFormats
 from repro.core.lstm import LSTMParams, lstm_forward, recurrent_forward
+from repro.kernels.lstm_fxp_seq import DEFAULT_BLOCK_B
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.parallel.sharding import fleet_slot_specs, shard_map
+from repro.parallel.sharding import fleet_slot_specs
 
 __all__ = ["SensorStream", "SensorFleetEngine", "SlotShardingError"]
 
@@ -267,29 +268,26 @@ class SensorFleetEngine:
         m.declare_gauge("fleet/slot_occupancy")
         m.declare_gauge("fleet/admit_queue_depth")
 
+        # block_b defaults to the kernel's compile-friendly row tile: any slot
+        # count (per device, under shard_map) tiles over the batch grid
         fwd_kwargs = dict(
             backend=backend, fmt=fmt, luts=luts, return_sequence=True,
             return_state="all", interpret=interpret, time_tile=time_tile,
+            block_b=DEFAULT_BLOCK_B if block_b is None else block_b,
         )
 
         if self.cell == "gru":
             def step_fn(ws, bs, qx, qh, lane_mask):
                 params = [GRUParams(w, b) for w, b in zip(ws, bs)]
                 seq, hs = recurrent_forward(
-                    "gru", params, qx, h0=list(qh),
-                    block_b=qx.shape[0] if block_b is None else block_b,
-                    **fwd_kwargs)
+                    "gru", params, qx, h0=list(qh), **fwd_kwargs)
                 keep = lane_mask[None, :, None]
                 return seq, jnp.where(keep, jnp.stack(hs), qh)
         else:
             def step_fn(ws, bs, qx, qh, qc, lane_mask):
                 params = [LSTMParams(w, b) for w, b in zip(ws, bs)]
-                # block_b defaults to the batch this trace sees: all slots
-                # unsharded, the per-device slot block under shard_map
                 seq, (hs, cs) = lstm_forward(
-                    params, qx, h0=list(qh), c0=list(qc),
-                    block_b=qx.shape[0] if block_b is None else block_b,
-                    **fwd_kwargs)
+                    params, qx, h0=list(qh), c0=list(qc), **fwd_kwargs)
                 keep = lane_mask[None, :, None]
                 h = jnp.stack(hs)
                 c = jnp.stack(cs)
@@ -301,12 +299,12 @@ class SensorFleetEngine:
             # kernel on its own slot block — no collectives, identical bits
             specs = fleet_slot_specs(data_axis)
             n_state = self._arity      # (h,) for GRU, (h, c) for LSTM
-            step_fn = shard_map(
+            step_fn = jax.shard_map(
                 step_fn, mesh=mesh,
                 in_specs=(specs["params"], specs["params"], specs["x"],
                           *(specs["state"],) * n_state, specs["mask"]),
                 out_specs=(specs["seq"], *(specs["state"],) * n_state),
-                check=False)
+                check_vma=False)
             self._state_sharding = NamedSharding(mesh, specs["state"])
             self._qh = jax.device_put(self._qh, self._state_sharding)
             if self._qc is not None:
@@ -318,6 +316,15 @@ class SensorFleetEngine:
 
         # jit re-specialises per input shape, i.e. once per t_step bucket
         self._step = jax.jit(step_fn)
+
+    def lower_step(self, t_step: int):
+        """The jitted step lowered for one ``t_step`` bucket on the engine's
+        own buffers: ``.as_text()`` shows what the device runs (a compiled
+        kernel appears as a ``tpu_custom_call``), ``.compile()`` builds it."""
+        x = jnp.zeros((self.slots, t_step, self.n_in), jnp.int32)
+        mask = jnp.zeros((self.slots,), bool)
+        state = (self._qh,) if self._qc is None else (self._qh, self._qc)
+        return self._step.lower(self._ws, self._bs, x, *state, mask)
 
     # --- observability ------------------------------------------------------
 

@@ -19,7 +19,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import axis_size
 
 __all__ = ["compressed_pmean", "compressed_pmean_with_feedback"]
 
@@ -49,7 +48,7 @@ def compressed_pmean(grads: Any, axis_name: str) -> Any:
     all-reduce — a 4x saving at 2 pods), dequantise each pod's contribution
     with its OWN scale, and average locally.  The only error is each pod's
     quantisation noise (~0.4 % relative), zero-mean across pods."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def leaf(g):
         q, scale = _quantize_leaf(g)
@@ -66,7 +65,7 @@ def compressed_pmean_with_feedback(grads: Any, residuals: Any, axis_name: str):
     """Error-feedback variant: the local quantisation error is added to the
     next step's gradient (Karimireddy et al., 2019) — eliminates bias
     accumulation for long runs.  Returns (mean_grads, new_residuals)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def leaf(g, r):
         g_fb = g.astype(jnp.float32) + r

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import RunContext, shard_map
+from repro.parallel.sharding import RunContext
 from repro.training.compression import compressed_pmean
 from repro.training.optimizer import Optimizer, OptState, clip_by_global_norm
 
@@ -115,16 +115,15 @@ def make_train_step(
     mesh = ctx.mesh
     assert mesh is not None and "pod" in mesh.axis_names, \
         "grad_compression needs a multi-pod mesh"
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
 
     def sharded_step(state, batch):
-        return shard_map(
+        return jax.shard_map(
             step_body,
             mesh=mesh,
             in_specs=(P(), P("pod")),    # state replicated over pods, batch split
             out_specs=(P(), P()),
-            check=False,
-            auto=auto,
+            axis_names={"pod"},
+            check_vma=False,
         )(state, batch)
 
     return sharded_step

@@ -101,6 +101,55 @@ def test_lstm_sequence_fxp_kernel_vs_oracle(b, t, frac, total, mxu):
     np.testing.assert_array_equal(np.asarray(o1[1]), np.asarray(o2[1]))
 
 
+def _int_dot_pallas(a, b):
+    """The kernel's ``_int_dot`` on ``_split8`` pieces, inside a Pallas call
+    in interpret mode, exactly as the fused kernel runs it."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.lstm_fxp_seq import _int_dot, _split8
+
+    def kernel(a_ref, b_ref, o_ref):
+        o_ref[...] = _int_dot(_split8(a_ref[...]), _split8(b_ref[...]))
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((a.shape[0], b.shape[1]), jnp.int32),
+        interpret=True)(a, b)
+
+
+# 32640..32767 is where a signed int8 split would overflow (high piece 128);
+# F = 1023 is the largest contraction the f32 accumulation holds exactly.
+@pytest.mark.parametrize("F", [1, 21, 148, 1023])
+@pytest.mark.parametrize("values", ["edges", "int8_overflow_band", "random16"])
+def test_int_dot_equals_int32_dot(F, values):
+    edges = np.array([-32768, 32767, -32767, 32640, -32640, 128, -128, 127,
+                      -129, 255, 256, 0, 1, -1], np.int64)
+    pools = {"edges": edges,
+             "int8_overflow_band": np.concatenate([np.arange(32640, 32768),
+                                                   -np.arange(32640, 32769)]),
+             "random16": np.arange(-32768, 32768)}
+    pool = pools[values]
+    a = RNG.choice(pool, (8, F)).astype(np.int32)
+    b = RNG.choice(pool, (F, 128)).astype(np.int32)
+    a[0], b[:, 0] = -32768, -32768        # largest product, every term
+    a[1], b[:, 1] = 32767, -32768
+    want = jax.lax.dot_general(jnp.asarray(a), jnp.asarray(b),
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+    np.testing.assert_array_equal(np.asarray(_int_dot_pallas(a, b)),
+                                  np.asarray(want))
+
+
+@pytest.mark.parametrize("frac,total,n_in", [(8, 17, 1), (8, 16, 1004)],
+                         ids=["17_bit_format", "F_over_1023"])
+def test_fxp_kernel_refuses_inexact_widths(frac, total, n_in):
+    """The exact MXU matmul takes at most 16-bit operands over F < 1024
+    lanes; anything wider is refused at trace time, never computed wrong."""
+    qxs, qw, qb = _fxp_seq_inputs(2, 3, n_in, 20, 16)
+    with pytest.raises(ValueError, match="total bits|contraction width"):
+        ops.lstm_sequence_fxp(qxs, qw, qb, impl="interpret",
+                              frac_bits=frac, total_bits=total)
+
+
 def test_lstm_sequence_fxp_no_lut_and_seq_output():
     b, t, n_in, h = 3, 7, 1, 20
     qxs, qw, qb = _fxp_seq_inputs(b, t, n_in, h, 16)

@@ -53,3 +53,32 @@ def test_paper_pipeline_end_to_end():
                        capture_output=True, text=True, timeout=900, env=_ENV)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "n_total=5332" in r.stdout
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """The entry points' cache sits at the fixed ``<checkout>/.jax_cache``,
+    or wherever ``JAX_COMPILATION_CACHE_DIR`` says (checked in a child, so
+    this process keeps its cache settings)."""
+    env = {k: v for k, v in _ENV.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    r = subprocess.run(
+        [sys.executable, "-c", "from repro.compile_cache import "
+         "enable_compile_cache as e; print(e())"],
+        capture_output=True, text=True, timeout=300,
+        env=dict(env, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stderr[-2000:]
+    want = tmp_path / env_dir if env_dir else _ROOT / ".jax_cache"
+    assert r.stdout.strip() == str(want)
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py exits non-zero before any work when JAX finds no TPU,
+    names the platform it found, and prints no result line."""
+    r = subprocess.run([sys.executable, str(_ROOT / "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=300,
+                       env=dict(_ENV, JAX_PLATFORMS="cpu"))
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr and "'cpu'" in r.stderr, r.stderr[-2000:]
+    assert r.stdout == "", r.stdout[-2000:]

@@ -28,6 +28,58 @@ def test_series_shape_and_stats():
     assert day[:, 96:120].mean() < day[:, 12:48].mean()
 
 
+def _series_loop_reference(seed, n_points):
+    """The per-sensor, one-draw-per-step generator the vectorised fleet
+    generator replaced, kept as its reference."""
+    from repro.data.traffic import PEMS_POINTS_PER_DAY as day
+
+    rng = np.random.default_rng(seed)
+    t = np.arange(n_points)
+    tod, dow = (t % day) / day, (t // day) % 7
+    free_flow = 65.0 + 3.0 * np.sin(2 * np.pi * t / (day * 7))
+
+    def gauss(x, mu, sig):
+        return np.exp(-0.5 * ((x - mu) / sig) ** 2)
+
+    weekday = (dow < 5).astype(np.float64)
+    speed = (free_flow - weekday * (22.0 * gauss(tod, 8.0 / 24, 1.2 / 24)
+                                    + 28.0 * gauss(tod, 17.5 / 24, 1.6 / 24))
+             - 6.0 * gauss(tod, 13.0 / 24, 2.5 / 24) * (1.0 - weekday))
+    noise = np.zeros(n_points)
+    for i in range(1, n_points):
+        noise[i] = 0.85 * noise[i - 1] + rng.normal(0.0, 1.1)
+    speed = speed + noise
+    for _ in range(max(1, n_points // 2000)):
+        start = rng.integers(0, n_points - 60)
+        depth = rng.uniform(15.0, 35.0)
+        dur = rng.integers(6, 30)
+        speed[start : start + dur] -= depth * np.exp(-np.arange(dur) / (dur / 3.0))
+    return np.clip(speed, 3.0, 80.0)
+
+
+@pytest.mark.parametrize("n_points", [PEMS_TOTAL_POINTS, 500])
+def test_fleet_series_equal_per_sensor_loop(n_points):
+    from repro.data.traffic import make_pems_like_fleet
+
+    seeds = [0, 3, 17]
+    fleet = make_pems_like_fleet(seeds, n_points)
+    assert fleet.shape == (3, n_points)
+    for row, seed in zip(fleet, seeds):
+        np.testing.assert_array_equal(row, _series_loop_reference(seed, n_points))
+        np.testing.assert_array_equal(row, make_pems_like_series(seed, n_points))
+
+
+def test_fleet_windows_ragged_and_seeded():
+    from repro.data.traffic import make_fleet_windows
+
+    a, b = make_fleet_windows(5, seed=1, block=2), make_fleet_windows(5, seed=1)
+    assert [w.shape for w in a] == [w.shape for w in b]
+    for wa, wb in zip(a, b):
+        np.testing.assert_array_equal(wa, wb)
+        assert 6 <= len(wa) <= 18 and wa.shape[1:] == (1,)
+        assert wa.dtype == np.float32 and 0.0 <= wa.min() and wa.max() <= 1.0
+
+
 def test_windowing():
     s = np.arange(20, dtype=np.float64)
     x, y = make_windows(s, n_seq=6)
