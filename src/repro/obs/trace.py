@@ -1,18 +1,29 @@
-"""Nestable spans serialised as Chrome ``trace_event`` JSON (ISSUE 9).
+"""Nestable spans of the serving stack, with two sinks.
 
-``span("fleet/step")`` wraps any region of the serving stack; the collected
-events load directly into chrome://tracing or https://ui.perfetto.dev (drag
-the written file in, or File > Open).  Same zero-perturbation contract as
-``repro.obs.metrics``: the module-global tracer starts as the no-op
-``NULL_TRACER`` (``enable_tracing()`` swaps in a real one), and spans time
-Python-level regions only — they never read or synchronise traced jax
-values, so every golden fixture passes integer-exact with tracing fully on.
+``span("fleet/step")`` wraps any region of the serving stack.  Where the
+span goes depends on the installed tracer:
 
-Event format: one ``"ph": "X"`` (complete) event per span, ``ts``/``dur`` in
-microseconds relative to the tracer's epoch.  Besides the wall-clock fields,
-every span records a deterministic ``seq`` (global entry order) and
-``depth`` (per-thread nesting level) in ``args`` — tests assert nesting and
-ordering on those, not on timestamps.
+* ``Tracer`` (``enable_tracing()``) collects Chrome ``trace_event`` JSON;
+  the written file loads directly into chrome://tracing or
+  https://ui.perfetto.dev (drag it in, or File > Open).
+* ``ProfilerTracer`` (``enable_tracing(profiler=True)``) enters a
+  ``jax.profiler.TraceAnnotation`` per span.  While a profiler trace is
+  running, the span lands on the ``/host:CPU`` plane of its ``.xplane.pb``
+  with its args as event stats, on the same clock as the device's
+  ``XLA Ops`` events, so device idle time can be put down to the host span
+  open at that moment.
+
+Same zero-perturbation contract as ``repro.obs.metrics``: the module-global
+tracer starts as the no-op ``NULL_TRACER`` (``enable_tracing()`` swaps in a
+real one), and spans time Python-level regions only — they never read or
+synchronise traced jax values, so every golden fixture passes integer-exact
+with tracing fully on.
+
+Chrome-JSON event format: one ``"ph": "X"`` (complete) event per span,
+``ts``/``dur`` in microseconds relative to the tracer's epoch.  Besides the
+wall-clock fields, every span records a deterministic ``seq`` (global entry
+order) and ``depth`` (per-thread nesting level) in ``args`` — tests assert
+nesting and ordering on those, not on timestamps.
 """
 
 from __future__ import annotations
@@ -27,6 +38,7 @@ __all__ = [
     "Tracer",
     "NullTracer",
     "NULL_TRACER",
+    "ProfilerTracer",
     "get_tracer",
     "set_tracer",
     "enable_tracing",
@@ -95,21 +107,6 @@ class Tracer:
         with self._lock:
             self._events.append(event)
 
-    def instant(self, name: str, **args) -> None:
-        """A zero-duration marker event (``ph: "i"``)."""
-        with self._lock:
-            seq = self._seq
-            self._seq += 1
-            self._events.append({
-                "name": name,
-                "ph": "i",
-                "s": "p",
-                "ts": round((time.perf_counter() - self._epoch) * 1e6, 3),
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": {**args, "seq": seq},
-            })
-
     def events(self) -> list[dict]:
         with self._lock:
             return list(self._events)
@@ -141,9 +138,6 @@ class NullTracer:
     def span(self, name, **args):
         return _NULL_CM
 
-    def instant(self, name, **args):
-        pass
-
     def events(self):
         return []
 
@@ -158,11 +152,28 @@ class NullTracer:
         pass
 
 
+class ProfilerTracer(NullTracer):
+    """Spans as ``jax.profiler.TraceAnnotation``s.  Nothing is kept here
+    (``events()`` and ``save()`` are the null sink's): the profiler's own
+    trace holds the spans, and drops them while no trace runs.  ``args``
+    become the event's stats, so pass ints, floats or strings."""
+
+    enabled = True
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+
+    def span(self, name: str, **args):
+        return self._annotation(name, **args)
+
+
 NULL_TRACER = NullTracer()
-_TRACER: Tracer | NullTracer = NULL_TRACER
+_TRACER: Tracer | NullTracer | ProfilerTracer = NULL_TRACER
 
 
-def get_tracer() -> Tracer | NullTracer:
+def get_tracer() -> Tracer | NullTracer | ProfilerTracer:
     """Resolved at call time by every span site, so ``enable_tracing()``
     takes effect everywhere immediately."""
     return _TRACER
@@ -173,11 +184,14 @@ def set_tracer(tracer) -> None:
     _TRACER = tracer
 
 
-def enable_tracing(tracer: Tracer | None = None) -> Tracer:
-    """Switch tracing ON process-wide; returns the installed tracer."""
-    t = tracer if tracer is not None else Tracer()
-    set_tracer(t)
-    return t
+def enable_tracing(tracer: Tracer | None = None, *,
+                   profiler: bool = False) -> Tracer | ProfilerTracer:
+    """Switch tracing ON process-wide; returns the installed tracer: a new
+    Chrome-JSON ``Tracer``, or with ``profiler=True`` a ``ProfilerTracer``."""
+    if tracer is None:
+        tracer = ProfilerTracer() if profiler else Tracer()
+    set_tracer(tracer)
+    return tracer
 
 
 def disable_tracing() -> None:

@@ -55,7 +55,9 @@ Observability (all no-op while ``repro.obs`` is disabled):
   ``fleet/ingest_rejected_total`` (+ ``fleet/ingest_rejected/<Exc>``),
   ``fleet/ingest_dropped_total``, ``fleet/ingest_queue_full_total``,
   ``fleet/ingest_deadline_expired_total``, ``fleet/ingest_admit_rejected_total``.
-* ``fleet/ingest`` tracer spans around each drain.
+* tracer spans: ``fleet/enqueue`` (child ``fleet/validate``, both with the
+  stream's ``rid``) around each ``submit``, ``fleet/ingest`` around each
+  drain (the engine's ``fleet/submit`` spans nest inside it).
 """
 
 from __future__ import annotations
@@ -152,10 +154,13 @@ class IngestQueue:
         queue is full, which is that policy's documented trade.
         """
         m = self.obs
+        tr = obs_trace.get_tracer()
         m.inc("fleet/ingest_submit_total")
-        with m.time("fleet/ingest_submit_us"):
+        with m.time("fleet/ingest_submit_us"), \
+                tr.span("fleet/enqueue", rid=stream.rid):
             try:
-                qxs, _, _ = self.engine.validate_stream(stream)
+                with tr.span("fleet/validate", rid=stream.rid):
+                    qxs, _, _ = self.engine.validate_stream(stream)
             except (TypeError, ValueError) as e:
                 m.inc("fleet/ingest_rejected_total")
                 m.inc(f"fleet/ingest_rejected/{type(e).__name__}")

@@ -87,9 +87,14 @@ rest of the batch's integers are untouched (masked lanes never interact).
 
 Observability (ISSUE 9): the engine reports itself through ``repro.obs`` —
 submit latency (``fleet/submit_us``), admit-queue depth, slot occupancy,
-per-step kernel-dispatch time (``fleet/step_us``), ``t_step`` bucket usage,
-quarantine counts by reason kind, and checkpoint save/restore timings +
-payload bytes — under the zero-perturbation contract: metrics/spans time and
+whole-step time (``fleet/step_us``: assembly, dispatch, the wait for the
+device and the harvest), occupied slot-timesteps
+(``fleet/slot_timesteps_total``), ``t_step`` bucket usage, quarantine
+counts by reason kind, and checkpoint save/restore timings + payload bytes;
+and spans: ``fleet/submit`` (children ``fleet/validate``, ``fleet/claim``,
+``fleet/state_write``, each with the stream's ``rid``) and ``fleet/step``
+(children ``fleet/assemble``, ``fleet/dispatch``, ``fleet/wait``,
+``fleet/harvest``) — under the zero-perturbation contract: metrics/spans time and
 count Python-level events only and never touch traced values, so every
 bit-identity battery passes unchanged with observability fully enabled
 (``tests/test_obs.py``).  Off by default: instrumentation resolves the
@@ -150,6 +155,10 @@ class SensorStream:
     done: bool = False
     cursor: int = 0                     # timesteps consumed so far
     error: str | None = None            # set when rejected or quarantined
+    # time.perf_counter() at slot claim and when the final state reached the
+    # host: admission-to-done per request.  Not checkpointed.
+    t_admit: float | None = None
+    t_done: float | None = None
 
     @property
     def remaining(self) -> int:
@@ -265,6 +274,7 @@ class SensorFleetEngine:
         m.declare_counter("fleet/quarantined_total")
         m.declare_counter("fleet/steps_total")
         m.declare_counter("fleet/timesteps_total")
+        m.declare_counter("fleet/slot_timesteps_total")
         m.declare_gauge("fleet/slot_occupancy")
         m.declare_gauge("fleet/admit_queue_depth")
 
@@ -339,15 +349,16 @@ class SensorFleetEngine:
 
     def metrics(self) -> dict:
         """Snapshot of the engine's metrics registry (counters, gauges,
-        histograms with p50/p95/p99), plus a ``derived`` section with the
-        kernel-dispatch throughput when step timings exist.  ``{}``-shaped
-        (all maps empty) while observability is disabled."""
+        histograms with p50/p95/p99), plus a ``derived`` section when step
+        timings exist: ``timesteps_per_s``, the occupied slot-timesteps
+        served over the summed wall time of the steps.  ``{}``-shaped (all
+        maps empty) while observability is disabled."""
         snap = self.obs.snapshot()
         step_us = snap.get("histograms", {}).get("fleet/step_us")
         if step_us and step_us["sum"]:
             snap["derived"] = {
-                "timesteps_per_s": self.timesteps_run * self.slots
-                / (step_us["sum"] / 1e6),
+                "timesteps_per_s": snap["counters"].get(
+                    "fleet/slot_timesteps_total", 0) / (step_us["sum"] / 1e6),
             }
         return snap
 
@@ -433,7 +444,8 @@ class SensorFleetEngine:
         """
         m = self.obs
         m.inc("fleet/submit_total")
-        with m.time("fleet/submit_us"):
+        with m.time("fleet/submit_us"), \
+                obs_trace.get_tracer().span("fleet/submit", rid=stream.rid):
             try:
                 ok = self._submit_inner(stream)
             except (TypeError, ValueError) as e:
@@ -496,23 +508,29 @@ class SensorFleetEngine:
         return qxs, h0, c0
 
     def _submit_inner(self, stream: SensorStream) -> bool:
-        qxs, h0, c0 = self.validate_stream(stream)
-        free = self.free_slots()
+        tr = obs_trace.get_tracer()
+        rid = stream.rid
+        with tr.span("fleet/validate", rid=rid):
+            qxs, h0, c0 = self.validate_stream(stream)
+        with tr.span("fleet/claim", rid=rid):
+            free = self.free_slots()
         if not free:
             return False
         slot = free[0]
+        stream.t_admit = time.perf_counter()
         stream.qxs = qxs
         stream.cursor = 0
         stream.h_seq = np.zeros((len(qxs), self.n_h), np.int32)
-        self._qh = self._qh.at[:, slot].set(jnp.asarray(h0))
-        if c0 is not None:
-            self._qc = self._qc.at[:, slot].set(jnp.asarray(c0))
-        if self._state_sharding is not None:
-            # keep the carry pinned to the block partition so the joining
-            # stream's state lands on (and stays on) slot_to_shard(slot)
-            self._qh = jax.device_put(self._qh, self._state_sharding)
-            if self._qc is not None:
-                self._qc = jax.device_put(self._qc, self._state_sharding)
+        with tr.span("fleet/state_write", rid=rid):
+            self._qh = self._qh.at[:, slot].set(jnp.asarray(h0))
+            if c0 is not None:
+                self._qc = self._qc.at[:, slot].set(jnp.asarray(c0))
+            if self._state_sharding is not None:
+                # keep the carry pinned to the block partition so the joining
+                # stream's state lands on (and stays on) slot_to_shard(slot)
+                self._qh = jax.device_put(self._qh, self._state_sharding)
+                if self._qc is not None:
+                    self._qc = jax.device_put(self._qc, self._state_sharding)
         self.active[slot] = stream
         return True
 
@@ -580,33 +598,36 @@ class SensorFleetEngine:
         Instrumented (no-op while observability is disabled): counts/timers
         only — nothing here reads or converts the traced arrays, so the
         integers are identical with metrics and tracing fully enabled.
+        ``fleet/step_us`` and the ``fleet/step`` span cover the whole call,
+        through the wait for the device and the harvest.
         """
         m = self.obs
         tr = obs_trace.get_tracer()
-        with tr.span("fleet/step", active=len(self.active)):
-            for slot in list(self.active):
-                reason = self._poison_reason(self.active[slot])
-                if reason is not None:
-                    self._quarantine(slot, reason)
-            if not self.active:
-                return
-            t_step = self._pick_t_step()
-            m.gauge("fleet/slot_occupancy", len(self.active) / self.slots)
-            # t_step buckets are a deterministic function of the schedule —
-            # edges at the power-of-two buckets the jit specialises on
-            m.observe("fleet/t_step", t_step,
-                      edges=[float(b) for b in sorted(self._buckets)])
-            x = np.zeros((self.slots, t_step, self.n_in), np.int32)
-            mask = np.zeros((self.slots,), bool)
-            for slot, s in self.active.items():
-                x[slot] = s.qxs[s.cursor : s.cursor + t_step]
-                mask[slot] = True
+        with m.time("fleet/step_us"), \
+                tr.span("fleet/step", active=len(self.active)):
+            with tr.span("fleet/assemble"):
+                for slot in list(self.active):
+                    reason = self._poison_reason(self.active[slot])
+                    if reason is not None:
+                        self._quarantine(slot, reason)
+                if not self.active:
+                    return
+                t_step = self._pick_t_step()
+                occupied = len(self.active)
+                m.gauge("fleet/slot_occupancy", occupied / self.slots)
+                # t_step buckets are a deterministic function of the schedule —
+                # edges at the power-of-two buckets the jit specialises on
+                m.observe("fleet/t_step", t_step,
+                          edges=[float(b) for b in sorted(self._buckets)])
+                x = np.zeros((self.slots, t_step, self.n_in), np.int32)
+                mask = np.zeros((self.slots,), bool)
+                for slot, s in self.active.items():
+                    x[slot] = s.qxs[s.cursor : s.cursor + t_step]
+                    mask[slot] = True
 
-            # fleet/step_us times the dispatch only (jax is async; the
-            # np.asarray below is where the host blocks on the result)
-            with m.time("fleet/step_us"), \
-                    tr.span("fleet/kernel", t_step=t_step,
-                            backend=self.backend):
+            # jax is async: the dispatch returns at once, and the host
+            # blocks on the device in fleet/wait
+            with tr.span("fleet/dispatch", t_step=t_step, occupied=occupied):
                 if self._arity == 1:
                     seq, self._qh = self._step(
                         self._ws, self._bs, jnp.asarray(x), self._qh,
@@ -619,29 +640,34 @@ class SensorFleetEngine:
             self.timesteps_run += t_step
             m.inc("fleet/steps_total")
             m.inc("fleet/timesteps_total", t_step)
+            m.inc("fleet/slot_timesteps_total", occupied * t_step)
 
-        seq_np = np.asarray(seq)
-        finished = []
-        for slot, s in self.active.items():
-            s.h_seq[s.cursor : s.cursor + t_step] = seq_np[slot]
-            s.cursor += t_step
-            if s.remaining == 0:
-                finished.append(slot)
-        if finished:
-            qh_np = np.asarray(self._qh)
-            qc_np = None if self._qc is None else np.asarray(self._qc)
-            for slot in finished:
-                s = self.active.pop(slot)   # slot freed for the next submit
-                if self.n_layers == 1:      # back-compat: (H,) for one layer
-                    s.qh = qh_np[0, slot].copy()
-                    s.qc = None if qc_np is None else qc_np[0, slot].copy()
-                else:
-                    s.qh = qh_np[:, slot].copy()
-                    s.qc = None if qc_np is None else qc_np[:, slot].copy()
-                s.done = True
-            # freed slots must show immediately: between steps the gauge is
-            # the live occupancy, not the pre-kernel batch size
-            m.gauge("fleet/slot_occupancy", len(self.active) / self.slots)
+            with tr.span("fleet/wait"):
+                seq_np = np.asarray(seq)
+            with tr.span("fleet/harvest"):
+                finished = []
+                for slot, s in self.active.items():
+                    s.h_seq[s.cursor : s.cursor + t_step] = seq_np[slot]
+                    s.cursor += t_step
+                    if s.remaining == 0:
+                        finished.append(slot)
+                if finished:
+                    qh_np = np.asarray(self._qh)
+                    qc_np = None if self._qc is None else np.asarray(self._qc)
+                    t_done = time.perf_counter()
+                    for slot in finished:
+                        s = self.active.pop(slot)   # slot freed for the next
+                        if self.n_layers == 1:      # back-compat: (H,) for L=1
+                            s.qh = qh_np[0, slot].copy()
+                            s.qc = None if qc_np is None else qc_np[0, slot].copy()
+                        else:
+                            s.qh = qh_np[:, slot].copy()
+                            s.qc = None if qc_np is None else qc_np[:, slot].copy()
+                        s.t_done = t_done
+                        s.done = True
+                    # freed slots must show immediately: between steps the
+                    # gauge is the live occupancy, not the pre-kernel batch size
+                    m.gauge("fleet/slot_occupancy", len(self.active) / self.slots)
 
     def run(self, streams: list[SensorStream]) -> list[SensorStream]:
         """Drive ``streams`` to completion with continuous batching.

@@ -237,12 +237,12 @@ def test_span_nesting_and_ordering():
 def test_chrome_trace_format(tmp_path):
     tr = Tracer()
     with tr.span("fleet/step", t_step=8):
-        pass
-    tr.instant("marker", note="x")
+        with tr.span("fleet/wait"):
+            pass
     doc = tr.to_chrome_trace()
     assert doc["displayTimeUnit"] == "ms"
     phs = {e["name"]: e["ph"] for e in doc["traceEvents"]}
-    assert phs == {"fleet/step": "X", "marker": "i"}
+    assert phs == {"fleet/step": "X", "fleet/wait": "X"}
     for e in doc["traceEvents"]:
         assert {"name", "ph", "ts", "pid", "tid", "args"} <= set(e)
     path = tmp_path / "t.json"
@@ -324,12 +324,15 @@ def test_engine_bit_identical_with_and_without_obs():
     assert snap["counters"]["fleet/steps_total"] == eng.steps_run
     assert snap["histograms"]["fleet/submit_us"]["count"] == 4
     assert snap["histograms"]["fleet/step_us"]["count"] == eng.steps_run
-    assert snap["derived"]["timesteps_per_s"] > 0
+    # occupied slot-timesteps: each stream's timesteps, served once
+    assert snap["counters"]["fleet/slot_timesteps_total"] == 5 + 9 + 3 + 7
+    assert snap["derived"]["timesteps_per_s"] == pytest.approx(
+        24 / (snap["histograms"]["fleet/step_us"]["sum"] / 1e6))
     # the t_step histogram uses the engine's power-of-two bucket edges
     assert snap["histograms"]["fleet/t_step"]["edges"] == sorted(
         float(b) for b in eng._buckets)
     names = [e["name"] for e in obs.get_tracer().events()]
-    assert "fleet/step" in names and "fleet/kernel" in names
+    assert "fleet/step" in names and "fleet/dispatch" in names
 
 
 def test_engine_quarantine_counts_by_reason():
@@ -384,6 +387,168 @@ def test_slot_occupancy_gauge_updates_when_slots_free():
     eng.run([])                                # drain: all slots free
     assert long.done
     assert reg.snapshot()["gauges"]["fleet/slot_occupancy"] == 0.0
+
+
+# -- the profiler sink: spans in the jax.profiler trace ------------------------
+
+# every span of one request carries its rid
+REQUEST_SPANS = {"fleet/enqueue", "fleet/validate", "fleet/submit",
+                 "fleet/claim", "fleet/state_write"}
+PARENT = {"fleet/enqueue": {None}, "fleet/ingest": {None}, "fleet/step": {None},
+          "fleet/validate": {"fleet/enqueue", "fleet/submit"},
+          "fleet/submit": {"fleet/ingest"}, "fleet/claim": {"fleet/submit"},
+          "fleet/state_write": {"fleet/submit"},
+          "fleet/assemble": {"fleet/step"}, "fleet/dispatch": {"fleet/step"},
+          "fleet/wait": {"fleet/step"}, "fleet/harvest": {"fleet/step"}}
+
+
+def _profiled(tmp_path, serve):
+    """Run ``serve()`` with the profiler sink on, under a ``jax.profiler``
+    trace written to ``tmp_path``; returns the trace's ``fleet/`` spans as
+    ``(name, start_ns, end_ns, stats)``, parents before their children."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    obs.enable_tracing(profiler=True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        serve()
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable_tracing()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                spans.extend((e.name, e.start_ns, e.end_ns, dict(e.stats))
+                             for e in line.events if e.name.startswith("fleet/"))
+    return sorted(spans, key=lambda sp: (sp[1], -sp[2]))
+
+
+def _parents(spans):
+    """The innermost span enclosing each span (None at the top)."""
+    out, stack = [], []
+    for sp in spans:
+        while stack and stack[-1][2] <= sp[1]:
+            stack.pop()
+        out.append(stack[-1] if stack else None)
+        stack.append(sp)
+    return out
+
+
+def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
+    from repro.serving.ingest import IngestQueue
+
+    qps, luts = _qps(), make_lut_pair(64)
+    lens = [5, 9, 3, 7, 6]
+    streams = _streams(lens)
+    eng = _engine(qps, luts)                          # 4 slots, chunk 4
+    spans = _profiled(tmp_path,
+                      lambda: IngestQueue(eng, capacity=2).run(streams))
+    assert all(s.done for s in streams)
+    assert {sp[0] for sp in spans} == set(PARENT)
+    for sp, parent in zip(spans, _parents(spans)):
+        name, _, _, stats = sp
+        assert (parent[0] if parent else None) in PARENT[name], (name, parent)
+        if name in REQUEST_SPANS:
+            assert isinstance(stats.get("rid"), int), sp
+            if parent is not None and parent[0] in REQUEST_SPANS:
+                assert stats["rid"] == parent[3]["rid"], (sp, parent)
+    # one slot write per admitted stream, each under its own rid
+    writes = sorted(sp[3]["rid"] for sp in spans if sp[0] == "fleet/state_write")
+    assert writes == [s.rid for s in streams]
+    # the dispatch args count exactly the occupied slot-timesteps served
+    dispatch = [sp[3] for sp in spans if sp[0] == "fleet/dispatch"]
+    assert len(dispatch) == eng.steps_run
+    assert sum(d["occupied"] * d["t_step"] for d in dispatch) == sum(lens)
+    steps = [sp for sp in spans if sp[0] == "fleet/step"]
+    assert len(steps) == eng.steps_run
+
+
+def test_fleet_golden_integer_equal_with_profiler_spans(tmp_path):
+    """The committed slot-churn fleet golden (10 ragged 2-layer streams over
+    8 slots) replays integer-exact through the ingest queue while every
+    span goes into a running profiler trace."""
+    from repro.core.fxp import fmt_from_dict
+    from repro.core.lut import LutSpec
+    from repro.serving.ingest import IngestQueue
+
+    g = json.loads((pathlib.Path(__file__).parent / "golden"
+                    / "lstm_fleet_sharded_golden.json").read_text())
+    luts = {}
+    for name in ("sigmoid", "tanh"):
+        e = g["lut"][name]
+        luts[name] = (jnp.asarray(np.asarray(e["table"], np.float32)),
+                      LutSpec(name, g["lut"]["depth"], e["lo"], e["hi"]))
+    qps = [LSTMParams(w=jnp.asarray(w, jnp.int32), b=jnp.asarray(b, jnp.int32))
+           for w, b in zip(g["qw"], g["qb"])]
+    streams = [SensorStream(
+        rid=s["rid"], qxs=np.asarray(s["qxs"], np.int32),
+        qh0=None if s["qh0"] is None else np.asarray(s["qh0"], np.int32),
+        qc0=None if s["qc0"] is None else np.asarray(s["qc0"], np.int32),
+    ) for s in g["streams"]]
+    eng = SensorFleetEngine(qps, fmt_from_dict(g["fmt"]), luts,
+                            batch_slots=g["engine"]["batch_slots"],
+                            chunk=g["engine"]["chunk"], backend="fxp")
+    spans = _profiled(tmp_path,
+                      lambda: IngestQueue(eng, capacity=4).run(streams))
+    assert sum(sp[0] == "fleet/state_write" for sp in spans) == len(streams)
+    for s, out in zip(streams, g["outputs"]):
+        assert s.done
+        np.testing.assert_array_equal(s.h_seq, np.asarray(out["h_seq"]))
+        np.testing.assert_array_equal(s.qh, np.asarray(out["qh"]))
+        np.testing.assert_array_equal(s.qc, np.asarray(out["qc"]))
+
+
+def test_no_trace_annotation_is_built_while_tracing_is_off(monkeypatch):
+    from repro.serving.ingest import IngestQueue
+
+    built = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **args):
+        built.append(name)
+        return real(name, **args)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    qps, luts = _qps(), make_lut_pair(64)
+    IngestQueue(_engine(qps, luts), capacity=2).run(_streams([5, 3, 7]))
+    assert obs.get_tracer() is NULL_TRACER and built == []
+    # the control: the same serving with the profiler sink on builds them
+    obs.enable_tracing(profiler=True)
+    IngestQueue(_engine(qps, luts), capacity=2).run(_streams([5, 3, 7]))
+    assert {"fleet/enqueue", "fleet/state_write", "fleet/wait"} <= set(built)
+
+
+def test_stream_admit_and_done_times(tmp_path):
+    """``t_admit`` is set at the slot claim and ``t_done`` when the final
+    state reaches the host; neither rides the checkpoint, and a kill ->
+    restore -> resume still serves the same integers."""
+    qps, luts = _qps(), make_lut_pair(64)
+    eng = _engine(qps, luts)
+    long, short = _streams([12, 4])
+    assert long.t_admit is None and long.t_done is None
+    eng.admit([long, short])
+    assert long.t_admit is not None and long.t_done is None
+    eng.step()                                   # t_step 4: short finishes
+    assert short.done and short.t_admit <= short.t_done
+    mgr = CheckpointManager(tmp_path / "ck", keep=2)
+    eng.save(mgr, step=1)
+    tree, extra = eng.checkpoint_payload()
+    assert not any("t_admit" in str(k) or "t_done" in str(k)
+                   for k in (*tree["streams"]["0"], *extra["slot_table"]["0"]))
+    eng2 = SensorFleetEngine.restore(mgr, qps, FMT, luts)
+    (restored,) = eng2.active.values()
+    assert restored.t_admit is None              # not checkpointed
+    eng.run([])
+    eng2.run([])
+    assert long.t_admit <= long.t_done and restored.t_done is not None
+    np.testing.assert_array_equal(restored.h_seq, long.h_seq)
+    np.testing.assert_array_equal(restored.qh, long.qh)
+    np.testing.assert_array_equal(restored.qc, long.qc)
 
 
 # -- persistence: counters survive kill -> restore -> resume ------------------
