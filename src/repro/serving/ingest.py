@@ -57,7 +57,9 @@ Observability (all no-op while ``repro.obs`` is disabled):
   ``fleet/ingest_deadline_expired_total``, ``fleet/ingest_admit_rejected_total``.
 * tracer spans: ``fleet/enqueue`` (child ``fleet/validate``, both with the
   stream's ``rid``) around each ``submit``, ``fleet/ingest`` around each
-  drain (the engine's ``fleet/submit`` spans nest inside it).
+  drain: the engine's per-stream ``fleet/submit`` spans nest inside it,
+  then one ``fleet/admit_write`` (arg ``streams``) that writes every
+  admitted stream's initial state at once.
 """
 
 from __future__ import annotations
@@ -212,12 +214,14 @@ class IngestQueue:
     # --- serving side -------------------------------------------------------
 
     def pump(self) -> int:
-        """Drain the queue head into free slots, FIFO; returns the number of
-        streams admitted.  Stops at the first ``engine full``.  A stream
-        corrupted AFTER enqueue is rejected by the engine's own submit
-        boundary into ``engine.quarantined`` (counted there as
-        ``fleet/submit_rejected/*``, plus ``fleet/ingest_admit_rejected_total``
-        here) — it cannot block the streams behind it.
+        """Drain the queue head into free slots, FIFO, as one batch
+        (``engine.submit_many``: one state write for every stream admitted);
+        returns the number of streams admitted.  Stops at the first
+        ``engine full``.  A stream corrupted AFTER enqueue is rejected by the
+        engine's own submit boundary into ``engine.quarantined`` (counted
+        there as ``fleet/submit_rejected/*``, plus
+        ``fleet/ingest_admit_rejected_total`` here) — it cannot block the
+        streams behind it.
         """
         if not self._queue:
             return 0
@@ -225,22 +229,18 @@ class IngestQueue:
         tr = obs_trace.get_tracer()
         admitted = 0
         with tr.span("fleet/ingest", depth=len(self._queue)):
-            while self._queue:
-                s, t_enq = self._queue[0]
-                try:
-                    if not self.engine.submit(s):
-                        break                   # engine full: keep the rest
-                except (TypeError, ValueError) as e:
-                    self._queue.popleft()
-                    s.error = f"{type(e).__name__}: {e}"
+            outcomes = self.engine.submit_many(s for s, _ in self._queue)
+            now = self._clock()
+            for err in outcomes:
+                s, t_enq = self._queue.popleft()
+                if err is not None:
+                    s.error = f"{type(err).__name__}: {err}"
                     self.engine.quarantined.append(s)
                     m.inc("fleet/ingest_admit_rejected_total")
                     continue
-                self._queue.popleft()
                 admitted += 1
                 m.inc("fleet/ingest_admitted_total")
-                m.observe("fleet/ingest_wait_us",
-                          (self._clock() - t_enq) * 1e6)
+                m.observe("fleet/ingest_wait_us", (now - t_enq) * 1e6)
         self._gauge_depth()
         return admitted
 

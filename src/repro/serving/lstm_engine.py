@@ -62,10 +62,10 @@ host devices by ``tests/spmd_scripts/check_sharded_fleet.py``.
 
 **Slot→device placement invariant:** with ``S`` slots on ``D`` devices,
 slot ``s`` lives on device ``s * D // S`` (block partition) for the
-engine's whole lifetime.  ``submit`` hands a joining stream the lowest free
-slot and never migrates an active one, so a stream's ``h``/``c`` carry
-stays on one device across join/leave churn — occupancy can change *which*
-devices do useful work, never the bits they produce.
+engine's whole lifetime.  Admission hands joining streams the lowest free
+slots in order and never migrates an active one, so a stream's ``h``/``c``
+carry stays on one device across join/leave churn — occupancy can change
+*which* devices do useful work, never the bits they produce.
 
 Fault tolerance (ISSUE 6): ``save(manager)`` / ``restore(manager, ...)``
 snapshot and rebuild the WHOLE serving state — ``(L, slots, H)`` carry,
@@ -90,19 +90,23 @@ submit latency (``fleet/submit_us``), admit-queue depth, slot occupancy,
 whole-step time (``fleet/step_us``: assembly, dispatch, the wait for the
 device and the harvest), occupied slot-timesteps
 (``fleet/slot_timesteps_total``), ``t_step`` bucket usage, quarantine
-counts by reason kind, and checkpoint save/restore timings + payload bytes;
-and spans: ``fleet/submit`` (children ``fleet/validate``, ``fleet/claim``,
-``fleet/state_write``, each with the stream's ``rid``) and ``fleet/step``
-(children ``fleet/assemble``, ``fleet/dispatch``, ``fleet/wait``,
-``fleet/harvest``) — under the zero-perturbation contract: metrics/spans time and
-count Python-level events only and never touch traced values, so every
-bit-identity battery passes unchanged with observability fully enabled
-(``tests/test_obs.py``).  Off by default: instrumentation resolves the
-process-local registry/tracer at call time (no-op singletons unless
-``repro.obs.enable()`` / ``enable_tracing()`` ran, or a per-engine registry
-was passed via ``metrics=``).  ``engine.metrics()`` returns the snapshot;
-the full snapshot also rides the checkpoint side-car so counters survive
-kill -> restore (cumulative, not reset).
+counts by reason kind, admission writes (``fleet/admit_writes_total`` and
+the ``fleet/admit_batch`` histogram of streams per write), and checkpoint
+save/restore timings + payload bytes; and spans: ``fleet/submit`` per
+stream (children ``fleet/validate``, ``fleet/claim``, each with the
+stream's ``rid``), one ``fleet/admit_write`` per admitted batch (arg
+``streams``; under ``fleet/admit`` or the ingest queue's ``fleet/ingest``)
+and ``fleet/step`` (children ``fleet/assemble``, ``fleet/dispatch``,
+``fleet/wait``, ``fleet/harvest``) — under the zero-perturbation contract:
+metrics/spans time and count Python-level events only and never touch
+traced values, so every bit-identity battery passes unchanged with
+observability fully enabled (``tests/test_obs.py``).  Off by default:
+instrumentation resolves the process-local registry/tracer at call time
+(no-op singletons unless ``repro.obs.enable()`` / ``enable_tracing()`` ran,
+or a per-engine registry was passed via ``metrics=``).
+``engine.metrics()`` returns the snapshot; the full snapshot also rides
+the checkpoint side-car so counters survive kill -> restore (cumulative,
+not reset).
 """
 
 from __future__ import annotations
@@ -275,6 +279,7 @@ class SensorFleetEngine:
         m.declare_counter("fleet/steps_total")
         m.declare_counter("fleet/timesteps_total")
         m.declare_counter("fleet/slot_timesteps_total")
+        m.declare_counter("fleet/admit_writes_total")
         m.declare_gauge("fleet/slot_occupancy")
         m.declare_gauge("fleet/admit_queue_depth")
 
@@ -326,6 +331,26 @@ class SensorFleetEngine:
 
         # jit re-specialises per input shape, i.e. once per t_step bucket
         self._step = jax.jit(step_fn)
+
+        # admission: one merge of every joining stream's initial state, of
+        # the carry's fixed shape whatever the batch size (see
+        # _write_joined); the old carry is donated
+        def merge_fn(state, new, mask):
+            keep = mask[None, :, None]
+            return tuple(jnp.where(keep, n, o) for o, n in zip(state, new))
+
+        if self._state_sharding is None:
+            self._merge = jax.jit(merge_fn, donate_argnums=0)
+        else:
+            st = (self._state_sharding,) * self._arity
+            self._merge = jax.jit(
+                merge_fn, donate_argnums=0, out_shardings=st,
+                in_shardings=(st, st, NamedSharding(mesh, specs["mask"])))
+        # streams per admission write: power-of-two edges up to the slots
+        self._admit_edges = [float(1 << k)
+                             for k in range(batch_slots.bit_length())]
+        if self._admit_edges[-1] != batch_slots:
+            self._admit_edges.append(float(batch_slots))
 
     def lower_step(self, t_step: int):
         """The jitted step lowered for one ``t_step`` bucket on the engine's
@@ -440,24 +465,89 @@ class SensorFleetEngine:
         wrong dtype (TypeError), non-finite values, wrong ndim/feature
         width, empty streams and values outside the engine's fixed-point
         range all reject at this boundary instead of surfacing as an opaque
-        failure deep inside the Pallas kernel.
+        failure deep inside the Pallas kernel.  A batch of one through
+        ``submit_many``.
+        """
+        outcomes = self.submit_many([stream])
+        if outcomes and outcomes[0] is not None:
+            raise outcomes[0]
+        return bool(outcomes)
+
+    def submit_many(self, streams) -> list:
+        """Admit the head of ``streams`` (any iterable, taken in order) as
+        one batch, writing every joining stream's initial state in ONE
+        device call.
+
+        Each stream is validated at the submit boundary; a valid one takes
+        the lowest free slot left (the free slots are computed once), a
+        malformed one is rejected without blocking the streams behind it,
+        and the first valid stream that finds no free slot ends the batch
+        (engine full: it and the rest are not taken).  Returns one entry per
+        leading stream taken: ``None`` where it got a slot, else the
+        TypeError/ValueError that rejected it — the caller decides whether
+        to raise (``submit``) or quarantine (``admit``, ``IngestQueue.pump``).
         """
         m = self.obs
-        m.inc("fleet/submit_total")
-        with m.time("fleet/submit_us"), \
-                obs_trace.get_tracer().span("fleet/submit", rid=stream.rid):
-            try:
-                ok = self._submit_inner(stream)
-            except (TypeError, ValueError) as e:
-                m.inc("fleet/submit_rejected_total")
-                m.inc(f"fleet/submit_rejected/{type(e).__name__}")
-                raise
-        if ok:
-            m.inc("fleet/admitted_total")
-            m.gauge("fleet/slot_occupancy", len(self.active) / self.slots)
-        else:
-            m.inc("fleet/submit_full_total")
-        return ok
+        tr = obs_trace.get_tracer()
+        free = iter(self.free_slots())
+        outcomes: list = []
+        joined: list = []               # (slot, stream, (h0,) or (h0, c0))
+        for stream in streams:
+            rid = stream.rid
+            m.inc("fleet/submit_total")
+            with m.time("fleet/submit_us"), tr.span("fleet/submit", rid=rid):
+                try:
+                    with tr.span("fleet/validate", rid=rid):
+                        qxs, h0, c0 = self.validate_stream(stream)
+                except (TypeError, ValueError) as e:
+                    m.inc("fleet/submit_rejected_total")
+                    m.inc(f"fleet/submit_rejected/{type(e).__name__}")
+                    outcomes.append(e)
+                    continue
+                with tr.span("fleet/claim", rid=rid):
+                    slot = next(free, None)
+                if slot is None:
+                    m.inc("fleet/submit_full_total")
+                    break
+                stream.t_admit = time.perf_counter()
+                stream.qxs = qxs
+                stream.cursor = 0
+                stream.h_seq = np.zeros((len(qxs), self.n_h), np.int32)
+                init = (h0,) if c0 is None else (h0, c0)
+                joined.append((slot, stream, init))
+            outcomes.append(None)
+        if joined:
+            self._write_joined(joined)
+        return outcomes
+
+    def _write_joined(self, joined: list) -> None:
+        """Merge the joining streams' initial ``(L, H)`` states into the
+        carry and make them active.  The merge has the carry's fixed shape
+        (state-sized host arrays plus a slot mask) whatever the batch size,
+        so it compiles once per engine; on a sharded engine its inputs and
+        output keep the block partition (``slot_to_shard``)."""
+        m = self.obs
+        k = len(joined)
+        slots = [slot for slot, _, _ in joined]
+        with obs_trace.get_tracer().span("fleet/admit_write", streams=k):
+            mask = np.zeros((self.slots,), bool)
+            mask[slots] = True
+            new = []
+            for rows in zip(*(init for _, _, init in joined)):  # h0s, c0s
+                a = np.zeros((self.n_layers, self.slots, self.n_h), np.int32)
+                a[:, slots] = np.stack(rows, axis=1)
+                new.append(a)
+            state = (self._qh,) if self._qc is None else (self._qh, self._qc)
+            out = self._merge(state, tuple(new), mask)
+            self._qh = out[0]
+            if self._qc is not None:
+                self._qc = out[1]
+        for slot, stream, _ in joined:
+            self.active[slot] = stream
+        m.inc("fleet/admit_writes_total")
+        m.observe("fleet/admit_batch", k, edges=self._admit_edges)
+        m.inc("fleet/admitted_total", k)
+        m.gauge("fleet/slot_occupancy", len(self.active) / self.slots)
 
     def validate_stream(self, stream: SensorStream):
         """Validate ``stream`` at the submit boundary WITHOUT claiming a
@@ -507,33 +597,6 @@ class SensorFleetEngine:
             c0 = self._state_init(stream.rid, stream.qc0, "qc0")
         return qxs, h0, c0
 
-    def _submit_inner(self, stream: SensorStream) -> bool:
-        tr = obs_trace.get_tracer()
-        rid = stream.rid
-        with tr.span("fleet/validate", rid=rid):
-            qxs, h0, c0 = self.validate_stream(stream)
-        with tr.span("fleet/claim", rid=rid):
-            free = self.free_slots()
-        if not free:
-            return False
-        slot = free[0]
-        stream.t_admit = time.perf_counter()
-        stream.qxs = qxs
-        stream.cursor = 0
-        stream.h_seq = np.zeros((len(qxs), self.n_h), np.int32)
-        with tr.span("fleet/state_write", rid=rid):
-            self._qh = self._qh.at[:, slot].set(jnp.asarray(h0))
-            if c0 is not None:
-                self._qc = self._qc.at[:, slot].set(jnp.asarray(c0))
-            if self._state_sharding is not None:
-                # keep the carry pinned to the block partition so the joining
-                # stream's state lands on (and stays on) slot_to_shard(slot)
-                self._qh = jax.device_put(self._qh, self._state_sharding)
-                if self._qc is not None:
-                    self._qc = jax.device_put(self._qc, self._state_sharding)
-        self.active[slot] = stream
-        return True
-
     def _pick_t_step(self) -> int:
         shortest = min(s.remaining for s in self.active.values())
         for b in self._buckets:
@@ -574,21 +637,24 @@ class SensorFleetEngine:
         counters (``fleet/submit_rejected/*``); admit only adds
         ``fleet/admit_rejected_total`` (its own disposition count) and
         never touches the quarantine counters, which are reserved for
-        mid-flight corruption (see ``_count_quarantine``)."""
+        mid-flight corruption (see ``_count_quarantine``).  The head that
+        fits is admitted as one ``submit_many`` batch under a
+        ``fleet/admit`` span; an engine-full stop keeps the rest."""
         m = self.obs
         m.gauge("fleet/admit_queue_depth", len(pending))
         try:
-            while pending:
-                try:
-                    if not self.submit(pending[0]):
-                        return                  # engine full: keep the rest
-                except (TypeError, ValueError) as e:
-                    bad = pending.pop(0)
-                    bad.error = f"{type(e).__name__}: {e}"
-                    self.quarantined.append(bad)
+            if not pending:
+                return
+            tr = obs_trace.get_tracer()
+            with tr.span("fleet/admit", depth=len(pending)):
+                outcomes = self.submit_many(pending)
+            taken = pending[:len(outcomes)]
+            del pending[:len(outcomes)]
+            for s, err in zip(taken, outcomes):
+                if err is not None:
+                    s.error = f"{type(err).__name__}: {err}"
+                    self.quarantined.append(s)
                     m.inc("fleet/admit_rejected_total")
-                    continue
-                pending.pop(0)
         finally:
             m.gauge("fleet/admit_queue_depth", len(pending))
 

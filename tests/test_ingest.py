@@ -374,6 +374,40 @@ def test_ingest_metrics_and_spans(setup):
         obs.disable_all()
 
 
+def test_pump_admits_a_batch_in_one_state_write(setup):
+    """One pump of k streams writes their initial states once:
+    ``fleet/admit_writes_total`` +1 and one ``fleet/admit_batch``
+    observation of k, on power-of-two edges up to the slot count; a stream
+    corrupted after enqueue is quarantined without taking a slot."""
+    reg = MetricsRegistry()
+    eng = _engine(setup, batch_slots=8, metrics=reg)
+    q = IngestQueue(eng, capacity=8)
+    streams = _streams([6, 9, 4, 7, 5, 8])
+    for s in streams:
+        q.submit(s)
+    streams[2].qxs = streams[2].qxs.astype(np.float32)   # corrupted in queue
+    assert q.pump() == 5
+    snap = reg.snapshot()
+    c, batch = snap["counters"], snap["histograms"]["fleet/admit_batch"]
+    assert c["fleet/admit_writes_total"] == 1
+    assert (batch["count"], batch["sum"]) == (1, 5)
+    assert batch["edges"] == [1.0, 2.0, 4.0, 8.0]
+    assert c["fleet/ingest_admitted_total"] == 5
+    assert c["fleet/ingest_admit_rejected_total"] == 1
+    assert c["fleet/submit_rejected/TypeError"] == 1
+    assert eng.quarantined == [streams[2]] and q.depth == 0
+    assert [s.rid for s in eng.active.values()] == [0, 1, 3, 4, 5]
+    assert q.pump() == 0                       # empty queue: no write
+    assert reg.snapshot()["counters"]["fleet/admit_writes_total"] == 1
+    for s in _streams([3, 3, 3, 3], seed=5):
+        q.submit(s)
+    assert q.pump() == 3                       # 3 free slots, 1 left queued
+    snap = reg.snapshot()
+    assert snap["counters"]["fleet/admit_writes_total"] == 2
+    assert snap["histograms"]["fleet/admit_batch"]["sum"] == 8
+    assert q.depth == 1 and snap["counters"]["fleet/submit_full_total"] == 1
+
+
 def test_churn_benchmark_smoke():
     """The benchmark path itself (small N): emits a well-formed row with
     p50/p95/p99 submit latency and sustained throughput."""

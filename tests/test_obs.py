@@ -393,11 +393,14 @@ def test_slot_occupancy_gauge_updates_when_slots_free():
 
 # every span of one request carries its rid
 REQUEST_SPANS = {"fleet/enqueue", "fleet/validate", "fleet/submit",
-                 "fleet/claim", "fleet/state_write"}
-PARENT = {"fleet/enqueue": {None}, "fleet/ingest": {None}, "fleet/step": {None},
+                 "fleet/claim"}
+# a drain is fleet/ingest (through the queue) or fleet/admit (direct)
+DRAINS = {"fleet/ingest", "fleet/admit"}
+PARENT = {"fleet/enqueue": {None}, "fleet/ingest": {None},
+          "fleet/admit": {None}, "fleet/step": {None},
           "fleet/validate": {"fleet/enqueue", "fleet/submit"},
-          "fleet/submit": {"fleet/ingest"}, "fleet/claim": {"fleet/submit"},
-          "fleet/state_write": {"fleet/submit"},
+          "fleet/submit": DRAINS, "fleet/claim": {"fleet/submit"},
+          "fleet/admit_write": DRAINS,
           "fleet/assemble": {"fleet/step"}, "fleet/dispatch": {"fleet/step"},
           "fleet/wait": {"fleet/step"}, "fleet/harvest": {"fleet/step"}}
 
@@ -439,6 +442,35 @@ def _parents(spans):
     return out
 
 
+def _check_span_tree(spans, streams, drain):
+    """Every span under its expected parent, request spans under one rid;
+    one ``fleet/admit_write`` per ``drain`` span that admitted streams, its
+    ``streams`` arg the drain's claims that got a slot, summing to all."""
+    assert {sp[0] for sp in spans} == set(PARENT) - (DRAINS - {drain}) \
+        - ({"fleet/enqueue"} if drain == "fleet/admit" else set())
+    writes = {}
+    for sp, parent in zip(spans, parents := _parents(spans)):
+        name, _, _, stats = sp
+        assert (parent[0] if parent else None) in PARENT[name], (name, parent)
+        if name in REQUEST_SPANS:
+            assert isinstance(stats.get("rid"), int), sp
+            if parent is not None and parent[0] in REQUEST_SPANS:
+                assert stats["rid"] == parent[3]["rid"], (sp, parent)
+        if name == "fleet/admit_write":
+            assert parent[0] == drain and id(parent) not in writes
+            writes[id(parent)] = stats["streams"]
+    claims = {}
+    for sp, parent in zip(spans, parents):
+        if sp[0] == "fleet/claim":
+            drain_span = parents[spans.index(parent)]
+            claims[id(drain_span)] = claims.get(id(drain_span), 0) + 1
+    # a drain's write carries its claims, less the one that found the
+    # engine full where the drain stopped there
+    for drain_id, n in claims.items():
+        assert writes.get(drain_id, 0) in (n, n - 1), (writes, claims)
+    assert sum(writes.values()) == len(streams)
+
+
 def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
     from repro.serving.ingest import IngestQueue
 
@@ -449,23 +481,27 @@ def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
     spans = _profiled(tmp_path,
                       lambda: IngestQueue(eng, capacity=2).run(streams))
     assert all(s.done for s in streams)
-    assert {sp[0] for sp in spans} == set(PARENT)
-    for sp, parent in zip(spans, _parents(spans)):
-        name, _, _, stats = sp
-        assert (parent[0] if parent else None) in PARENT[name], (name, parent)
-        if name in REQUEST_SPANS:
-            assert isinstance(stats.get("rid"), int), sp
-            if parent is not None and parent[0] in REQUEST_SPANS:
-                assert stats["rid"] == parent[3]["rid"], (sp, parent)
-    # one slot write per admitted stream, each under its own rid
-    writes = sorted(sp[3]["rid"] for sp in spans if sp[0] == "fleet/state_write")
-    assert writes == [s.rid for s in streams]
+    _check_span_tree(spans, streams, "fleet/ingest")
     # the dispatch args count exactly the occupied slot-timesteps served
     dispatch = [sp[3] for sp in spans if sp[0] == "fleet/dispatch"]
     assert len(dispatch) == eng.steps_run
     assert sum(d["occupied"] * d["t_step"] for d in dispatch) == sum(lens)
     steps = [sp for sp in spans if sp[0] == "fleet/step"]
     assert len(steps) == eng.steps_run
+
+
+def test_profiler_spans_of_the_direct_admit_path(tmp_path):
+    """``engine.run`` without the queue: each ``admit`` drain is a
+    ``fleet/admit`` span holding the per-stream ``fleet/submit`` spans and
+    one ``fleet/admit_write``."""
+    qps, luts = _qps(), make_lut_pair(64)
+    streams = _streams([5, 9, 3, 7, 6, 4])
+    eng = _engine(qps, luts)                          # 4 slots, chunk 4
+    spans = _profiled(tmp_path, lambda: eng.run(streams))
+    assert all(s.done for s in streams)
+    _check_span_tree(spans, streams, "fleet/admit")
+    first = next(sp for sp in spans if sp[0] == "fleet/admit_write")
+    assert first[3]["streams"] == 4                   # the first drain fills
 
 
 def test_fleet_golden_integer_equal_with_profiler_spans(tmp_path):
@@ -495,7 +531,8 @@ def test_fleet_golden_integer_equal_with_profiler_spans(tmp_path):
                             chunk=g["engine"]["chunk"], backend="fxp")
     spans = _profiled(tmp_path,
                       lambda: IngestQueue(eng, capacity=4).run(streams))
-    assert sum(sp[0] == "fleet/state_write" for sp in spans) == len(streams)
+    assert sum(sp[3]["streams"] for sp in spans
+               if sp[0] == "fleet/admit_write") == len(streams)
     for s, out in zip(streams, g["outputs"]):
         assert s.done
         np.testing.assert_array_equal(s.h_seq, np.asarray(out["h_seq"]))
@@ -520,7 +557,7 @@ def test_no_trace_annotation_is_built_while_tracing_is_off(monkeypatch):
     # the control: the same serving with the profiler sink on builds them
     obs.enable_tracing(profiler=True)
     IngestQueue(_engine(qps, luts), capacity=2).run(_streams([5, 3, 7]))
-    assert {"fleet/enqueue", "fleet/state_write", "fleet/wait"} <= set(built)
+    assert {"fleet/enqueue", "fleet/admit_write", "fleet/wait"} <= set(built)
 
 
 def test_stream_admit_and_done_times(tmp_path):

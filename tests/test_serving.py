@@ -435,3 +435,175 @@ def test_fleet_mixed_precision_bit_identical():
                                            np.int64))
     with pytest.raises(ValueError, match="exceed"):
         eng.submit(bad)
+
+
+# --- batched admission: one state write per drain ---------------------------
+
+
+def _cell_stack(cell, n_layers, key=30):
+    """Quantised per-layer params of an L-layer LSTM or GRU stack."""
+    from repro.core.lstm import GRUParams, init_gru_params
+
+    init, cls = ((init_lstm_params, LSTMParams) if cell == "lstm"
+                 else (init_gru_params, GRUParams))
+    qps = []
+    for li in range(n_layers):
+        p = init(jax.random.PRNGKey(key + li), N_IN if li == 0 else N_H, N_H)
+        qps.append(cls(w=quantize(p.w, FMT), b=quantize(p.b, FMT)))
+    return qps, make_lut_pair(64)
+
+
+def _stateful_streams(cell, n_layers, lens, seed=21):
+    """Ragged streams; every other one starts from a nonzero (L, H) state."""
+    streams = _make_streams(lens, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    for s in streams[1::2]:
+        s.qh0 = rng.integers(-60, 60, (n_layers, N_H)).astype(np.int32)
+        if cell == "lstm":
+            s.qc0 = rng.integers(-60, 60, (n_layers, N_H)).astype(np.int32)
+    return streams
+
+
+def _slot_log(eng, log):
+    for slot, s in eng.active.items():
+        assert log.setdefault(s.rid, slot) == slot
+
+
+@pytest.mark.parametrize("cell,n_layers", [("lstm", 1), ("lstm", 2),
+                                           ("gru", 1), ("gru", 2)])
+def test_batched_admission_equals_one_at_a_time(cell, n_layers):
+    """``admit`` writes a drain's initial states in one merge; serving that
+    way is integer-equal, stream by stream and slot by slot, to admitting
+    each stream alone (a batch of one per ``submit``), and both equal the
+    stream run solo from its own initial state."""
+    from repro.core.lstm import recurrent_forward
+
+    qps, luts = _cell_stack(cell, n_layers)
+    lens = [5, 9, 3, 12, 7, 4, 10, 6]
+    kw = dict(batch_slots=4, chunk=4, backend="fxp")
+
+    batched = _stateful_streams(cell, n_layers, lens)
+    eng_b = SensorFleetEngine(qps, FMT, luts, **kw)
+    pending, slots_b = list(batched), {}
+    while pending or eng_b.active:
+        eng_b.admit(pending)
+        _slot_log(eng_b, slots_b)
+        eng_b.step()
+
+    single = _stateful_streams(cell, n_layers, lens)
+    eng_s = SensorFleetEngine(qps, FMT, luts, **kw)
+    pending, slots_s = list(single), {}
+    while pending or eng_s.active:
+        while pending and eng_s.submit(pending[0]):
+            pending.pop(0)
+        _slot_log(eng_s, slots_s)
+        eng_s.step()
+
+    assert slots_b == slots_s and len(slots_b) == len(lens)
+    for a, b in zip(batched, single):
+        assert a.done and b.done
+        np.testing.assert_array_equal(a.h_seq, b.h_seq, err_msg=f"{a.rid}")
+        np.testing.assert_array_equal(a.qh, b.qh)
+        if cell == "lstm":
+            np.testing.assert_array_equal(a.qc, b.qc)
+        else:
+            assert a.qc is None and b.qc is None
+        h0 = c0 = None
+        if a.qh0 is not None:
+            h0 = [jnp.asarray(a.qh0[li])[None] for li in range(n_layers)]
+            if cell == "lstm":
+                c0 = [jnp.asarray(a.qc0[li])[None] for li in range(n_layers)]
+        seq, state = recurrent_forward(
+            cell, qps, jnp.asarray(a.qxs)[None], backend="fxp", fmt=FMT,
+            luts=luts, h0=h0, c0=c0, return_sequence=True,
+            return_state="all")
+        hs = state[0] if cell == "lstm" else state
+        np.testing.assert_array_equal(a.h_seq, np.asarray(seq[0]))
+        np.testing.assert_array_equal(
+            a.qh.reshape(n_layers, N_H),
+            np.stack([np.asarray(h[0]) for h in hs]))
+        if cell == "lstm":
+            np.testing.assert_array_equal(
+                a.qc.reshape(n_layers, N_H),
+                np.stack([np.asarray(c[0]) for c in state[1]]))
+
+
+def test_batched_admission_slot_map_lowest_free_fifo():
+    """A drain gives the lowest free slots, ascending, to the streams in
+    FIFO order, and stops when the engine is full, keeping the rest."""
+    from repro.obs.metrics import MetricsRegistry
+
+    qp, luts = _fleet_setup()
+    reg = MetricsRegistry()
+    eng = SensorFleetEngine(qp, FMT, luts, batch_slots=6, chunk=4,
+                            backend="fxp", metrics=reg)
+    first = _make_streams([8, 4, 8, 4, 8], seed=2)
+    eng.admit(first)
+    assert {s.rid: slot for slot, s in eng.active.items()} == {
+        0: 0, 1: 1, 2: 2, 3: 3, 4: 4}
+    eng.step()                            # t_step 4: slots 1 and 3 free
+    assert eng.free_slots() == [1, 3, 5]
+    late = _make_streams([6, 5, 7, 3], seed=4)
+    for i, s in enumerate(late):
+        s.rid = 10 + i
+    pending = list(late)
+    eng.admit(pending)
+    assert [(s.rid, slot) for slot, s in eng.active.items()
+            if s.rid >= 10] == [(10, 1), (11, 3), (12, 5)]
+    assert pending == [late[3]] and late[3].h_seq is None
+    c = reg.snapshot()["counters"]
+    assert c["fleet/admit_writes_total"] == 2
+    assert c["fleet/admitted_total"] == 8
+    assert c["fleet/submit_full_total"] == 1
+    assert c["fleet/submit_total"] == 9
+
+
+def test_batched_admission_poison_mid_batch():
+    """A malformed stream inside a drain is quarantined; the streams behind
+    it still take the next free slots in the same single write, and their
+    integers equal a run without the poison."""
+    from repro.obs.metrics import MetricsRegistry
+
+    qp, luts = _fleet_setup()
+    reg = MetricsRegistry()
+    eng = SensorFleetEngine(qp, FMT, luts, batch_slots=4, chunk=4,
+                            backend="fxp", metrics=reg)
+    good = _make_streams([6, 9, 5], seed=8)
+    bad = SensorStream(rid=50, qxs=np.zeros((4, N_IN), np.float32))
+    pending = [good[0], bad, good[1], good[2]]
+    eng.admit(pending)
+    assert pending == [] and eng.quarantined == [bad]
+    assert bad.error.startswith("TypeError") and bad.h_seq is None
+    assert {s.rid: slot for slot, s in eng.active.items()} == {
+        0: 0, 1: 1, 2: 2}
+    snap = reg.snapshot()
+    assert snap["counters"]["fleet/admit_writes_total"] == 1
+    assert snap["histograms"]["fleet/admit_batch"]["sum"] == 3
+    assert snap["counters"]["fleet/submit_rejected/TypeError"] == 1
+    assert snap["counters"]["fleet/admit_rejected_total"] == 1
+    assert snap["histograms"]["fleet/submit_us"]["count"] == 4
+    eng.run([])
+    clean = _make_streams([6, 9, 5], seed=8)
+    SensorFleetEngine(qp, FMT, luts, batch_slots=4, chunk=4,
+                      backend="fxp").run(clean)
+    for a, b in zip(good, clean):
+        assert a.done
+        np.testing.assert_array_equal(a.h_seq, b.h_seq)
+        np.testing.assert_array_equal(a.qh, b.qh)
+        np.testing.assert_array_equal(a.qc, b.qc)
+
+
+def test_admission_merge_compiles_once():
+    """The merge has the carry's shape whatever the batch size: batches of
+    1, 3 and all the slots reuse one compiled program."""
+    qp, luts = _fleet_setup()
+    eng = SensorFleetEngine(qp, FMT, luts, batch_slots=8, chunk=4,
+                            backend="fxp")
+    assert eng._merge._cache_size() == 0
+    assert eng.submit(_make_streams([4])[0])
+    assert eng._merge._cache_size() == 1
+    eng.admit(_make_streams([4, 4, 4], seed=1))
+    assert len(eng.active) == 4 and eng._merge._cache_size() == 1
+    eng.run([])
+    eng.admit(_make_streams([4] * 8, seed=2))
+    assert len(eng.active) == 8 and eng._merge._cache_size() == 1
