@@ -229,7 +229,9 @@ class CheckpointManager:
     def save_async(self, step: int, tree: Any, extra: dict | None = None):
         """Snapshot to host now, write in the background."""
         self.wait()  # one in flight at a time
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+        # a copy, also of leaves already on the host: the caller goes on
+        # mutating its buffers (a serving fleet's h_seq) during the write
+        host_tree = jax.tree.map(lambda x: np.array(jax.device_get(x)), tree)
 
         def work():
             save_pytree(host_tree, self.root / f"step_{step}", extra=extra)

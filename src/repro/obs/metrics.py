@@ -91,6 +91,11 @@ class Histogram:
         self.min = v if self.min is None else min(self.min, v)
         self.max = v if self.max is None else max(self.max, v)
 
+    def observe_many(self, values) -> None:
+        """``observe`` each of ``values``: one call for a batch."""
+        for v in values:
+            self.observe(v)
+
     def quantile(self, q: float) -> float | None:
         if self.count == 0:
             return None
@@ -205,6 +210,16 @@ class MetricsRegistry:
                 h = self._hists[name] = Histogram(edges, timed=timed)
             h.observe(value)
 
+    def observe_many(self, name: str, values, *, edges=DEFAULT_US_EDGES,
+                     timed: bool = False) -> None:
+        """``observe`` each of ``values`` into one histogram, under one lock
+        (a drain's per-stream readings in one call)."""
+        with self._lock:
+            h = self._hists.get(name)
+            if h is None:
+                h = self._hists[name] = Histogram(edges, timed=timed)
+            h.observe_many(values)
+
     def time(self, name: str) -> _Timer:
         """``with reg.time("fleet/step_us"): ...`` — the ONLY sanctioned
         wall-clock read; the histogram it feeds is flagged ``timed``."""
@@ -310,6 +325,9 @@ class NullRegistry:
         pass
 
     def observe(self, name, value, *, edges=None, timed=False):
+        pass
+
+    def observe_many(self, name, values, *, edges=None, timed=False):
         pass
 
     def time(self, name):

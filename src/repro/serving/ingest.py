@@ -57,9 +57,10 @@ Observability (all no-op while ``repro.obs`` is disabled):
   ``fleet/ingest_deadline_expired_total``, ``fleet/ingest_admit_rejected_total``.
 * tracer spans: ``fleet/enqueue`` (child ``fleet/validate``, both with the
   stream's ``rid``) around each ``submit``, ``fleet/ingest`` around each
-  drain: the engine's per-stream ``fleet/submit`` spans nest inside it,
-  then one ``fleet/admit_write`` (arg ``streams``) that writes every
-  admitted stream's initial state at once.
+  drain: the engine's drain check ``fleet/validate`` and ``fleet/claim``
+  (arg ``streams`` each) nest inside it, then one ``fleet/admit_write``
+  (arg ``streams``) that writes every admitted stream's initial state at
+  once.
 """
 
 from __future__ import annotations
@@ -217,30 +218,42 @@ class IngestQueue:
         """Drain the queue head into free slots, FIFO, as one batch
         (``engine.submit_many``: one state write for every stream admitted);
         returns the number of streams admitted.  Stops at the first
-        ``engine full``.  A stream corrupted AFTER enqueue is rejected by the
-        engine's own submit boundary into ``engine.quarantined`` (counted
-        there as ``fleet/submit_rejected/*``, plus
-        ``fleet/ingest_admit_rejected_total`` here) — it cannot block the
-        streams behind it.
+        ``engine full``.
+
+        The streams were validated at enqueue; the engine checks the drain
+        again in one pass (O(1) attribute checks per stream, one range check
+        over all inputs) and runs the full per-stream ``validate_stream``
+        only where that check fails.  So a stream corrupted AFTER enqueue is
+        still rejected by the engine's own submit boundary into
+        ``engine.quarantined`` (counted there as ``fleet/submit_rejected/*``,
+        plus ``fleet/ingest_admit_rejected_total`` here), with the error it
+        would get alone — it cannot block the streams behind it.  The
+        drained head leaves the deque at once, the counters move by the
+        drain's counts, and ``fleet/ingest_wait_us`` takes one observation
+        per admitted stream in one call.
         """
         if not self._queue:
             return 0
         m = self.obs
         tr = obs_trace.get_tracer()
-        admitted = 0
         with tr.span("fleet/ingest", depth=len(self._queue)):
             outcomes = self.engine.submit_many(s for s, _ in self._queue)
             now = self._clock()
-            for err in outcomes:
-                s, t_enq = self._queue.popleft()
-                if err is not None:
-                    s.error = f"{type(err).__name__}: {err}"
-                    self.engine.quarantined.append(s)
-                    m.inc("fleet/ingest_admit_rejected_total")
-                    continue
-                admitted += 1
-                m.inc("fleet/ingest_admitted_total")
-                m.observe("fleet/ingest_wait_us", (now - t_enq) * 1e6)
+            popleft = self._queue.popleft
+            taken = [popleft() for _ in outcomes]
+            admitted = outcomes.count(None)
+            if admitted < len(taken):
+                for (s, _), err in zip(taken, outcomes):
+                    if err is not None:
+                        s.error = f"{type(err).__name__}: {err}"
+                        self.engine.quarantined.append(s)
+                        m.inc("fleet/ingest_admit_rejected_total")
+            if admitted:
+                m.inc("fleet/ingest_admitted_total", admitted)
+            if admitted and m.enabled:
+                m.observe_many("fleet/ingest_wait_us", [
+                    (now - t_enq) * 1e6
+                    for (_, t_enq), err in zip(taken, outcomes) if err is None])
         self._gauge_depth()
         return admitted
 

@@ -92,11 +92,13 @@ device and the harvest), occupied slot-timesteps
 (``fleet/slot_timesteps_total``), ``t_step`` bucket usage, quarantine
 counts by reason kind, admission writes (``fleet/admit_writes_total`` and
 the ``fleet/admit_batch`` histogram of streams per write), and checkpoint
-save/restore timings + payload bytes; and spans: ``fleet/submit`` per
-stream (children ``fleet/validate``, ``fleet/claim``, each with the
-stream's ``rid``), one ``fleet/admit_write`` per admitted batch (arg
-``streams``; under ``fleet/admit`` or the ingest queue's ``fleet/ingest``)
-and ``fleet/step`` (children ``fleet/assemble``, ``fleet/dispatch``,
+save/restore timings + payload bytes; and spans: per drain (under
+``fleet/admit``, the ingest queue's ``fleet/ingest``, or ``fleet/submit``
+with the ``rid`` of a direct ``submit``) one ``fleet/validate`` (arg
+``streams``; holding a per-stream ``fleet/validate`` with the ``rid`` of
+each stream that fails the drain check), one ``fleet/claim`` (arg
+``streams``) and one ``fleet/admit_write`` per admitted batch (arg
+``streams``); and ``fleet/step`` (children ``fleet/assemble``, ``fleet/dispatch``,
 ``fleet/wait``, ``fleet/harvest``) — under the zero-perturbation contract:
 metrics/spans time and count Python-level events only and never touch
 traced values, so every bit-identity battery passes unchanged with
@@ -113,6 +115,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import time
 
 import jax
@@ -130,6 +133,8 @@ from repro.obs import trace as obs_trace
 from repro.parallel.sharding import fleet_slot_specs
 
 __all__ = ["SensorStream", "SensorFleetEngine", "SlotShardingError"]
+
+_I32 = np.dtype(np.int32)
 
 
 class SlotShardingError(ValueError):
@@ -437,18 +442,21 @@ class SensorFleetEngine:
             raise ValueError(f"slot {slot} out of range [0, {self.slots})")
         return slot * self.n_shards // self.slots
 
-    def _state_init(self, rid: int, s0, name: str) -> np.ndarray:
-        """Normalise a stream's initial state to ``(L, H)`` (zeros default;
-        ``(H,)`` accepted as layer 0 of a single-layer engine)."""
+    def _state_init(self, rid: int, s0, name: str) -> np.ndarray | None:
+        """Normalise a stream's initial state to ``(L, H)`` int32 (``(H,)``
+        accepted as layer 0 of a single-layer engine); ``None`` stays
+        ``None``, the zero default, which the admission write leaves at the
+        zeros its merge arrays hold."""
         if s0 is None:
-            return np.zeros((self.n_layers, self.n_h), np.int32)
+            return None
         s0 = np.asarray(s0)
-        if not np.issubdtype(s0.dtype, np.integer):
+        if s0.dtype.kind not in "iu":
             # float state would smuggle NaN/rounding into the integer carry
             raise TypeError(
                 f"stream {rid}: {name} must be integer fixed point "
                 f"(quantise with repro.core.fxp.quantize first), got {s0.dtype}")
-        s0 = s0.astype(np.int32)
+        if s0.dtype != _I32:
+            s0 = s0.astype(np.int32)
         if s0.shape == (self.n_h,) and self.n_layers == 1:
             return s0[None]
         if s0.shape != (self.n_layers, self.n_h):
@@ -466,9 +474,10 @@ class SensorFleetEngine:
         width, empty streams and values outside the engine's fixed-point
         range all reject at this boundary instead of surfacing as an opaque
         failure deep inside the Pallas kernel.  A batch of one through
-        ``submit_many``.
+        ``submit_many``, under a ``fleet/submit`` span with the ``rid``.
         """
-        outcomes = self.submit_many([stream])
+        with obs_trace.get_tracer().span("fleet/submit", rid=stream.rid):
+            outcomes = self.submit_many([stream])
         if outcomes and outcomes[0] is not None:
             raise outcomes[0]
         return bool(outcomes)
@@ -478,72 +487,146 @@ class SensorFleetEngine:
         one batch, writing every joining stream's initial state in ONE
         device call.
 
-        Each stream is validated at the submit boundary; a valid one takes
-        the lowest free slot left (the free slots are computed once), a
-        malformed one is rejected without blocking the streams behind it,
-        and the first valid stream that finds no free slot ends the batch
-        (engine full: it and the rest are not taken).  Returns one entry per
-        leading stream taken: ``None`` where it got a slot, else the
-        TypeError/ValueError that rejected it — the caller decides whether
-        to raise (``submit``) or quarantine (``admit``, ``IngestQueue.pump``).
+        The head it may admit (one stream per free slot, plus the one that
+        would find the engine full, extended past any rejects) is checked
+        in one pass (``_check_drain``): O(1) attribute checks per stream and
+        one range check over all their inputs; only a stream that fails
+        them (or the whole head, when the range check fails) goes through
+        ``validate_stream``.  A valid stream takes the lowest free slot left
+        (the free slots are computed once), a malformed one is rejected
+        without blocking the streams behind it, and the first valid stream
+        that finds no free slot ends the batch (engine full: it and the rest
+        are not taken).  Returns one entry per leading stream taken:
+        ``None`` where it got a slot, else the TypeError/ValueError that
+        rejected it — the caller decides whether to raise (``submit``) or
+        quarantine (``admit``, ``IngestQueue.pump``).
+
+        Bookkeeping is per drain: one ``fleet/validate`` span per checked
+        head (arg ``streams``; a rejected stream's own ``validate_stream``
+        runs inside it under ``fleet/validate`` with its ``rid``), one
+        ``fleet/claim`` (arg ``streams``), counters incremented by the
+        drain's counts, and ``fleet/submit_us`` records, for each stream
+        examined (taken, or the one that found the engine full), the
+        drain's time over that count.
         """
         m = self.obs
         tr = obs_trace.get_tracer()
-        free = iter(self.free_slots())
+        t0 = time.perf_counter()
+        free = self.free_slots()
+        it = iter(streams)
         outcomes: list = []
-        joined: list = []               # (slot, stream, (h0,) or (h0, c0))
-        for stream in streams:
-            rid = stream.rid
-            m.inc("fleet/submit_total")
-            with m.time("fleet/submit_us"), tr.span("fleet/submit", rid=rid):
-                try:
-                    with tr.span("fleet/validate", rid=rid):
-                        qxs, h0, c0 = self.validate_stream(stream)
-                except (TypeError, ValueError) as e:
+        joined: list = []               # (stream, (qxs, h0, c0)), slot order
+        full = False
+        while not full:
+            head = list(itertools.islice(it, len(free) + 1 - len(joined)))
+            if not head:
+                break
+            with tr.span("fleet/validate", streams=len(head)):
+                checked = self._check_drain(head)
+            for stream, res in zip(head, checked):
+                if isinstance(res, Exception):
                     m.inc("fleet/submit_rejected_total")
-                    m.inc(f"fleet/submit_rejected/{type(e).__name__}")
-                    outcomes.append(e)
-                    continue
-                with tr.span("fleet/claim", rid=rid):
-                    slot = next(free, None)
-                if slot is None:
+                    m.inc(f"fleet/submit_rejected/{type(res).__name__}")
+                    outcomes.append(res)
+                elif len(joined) == len(free):
                     m.inc("fleet/submit_full_total")
-                    break
-                stream.t_admit = time.perf_counter()
+                    full = True
+                else:
+                    joined.append((stream, res))
+                    outcomes.append(None)
+        n = len(outcomes) + full        # streams examined
+        if not n:
+            return outcomes
+        k = len(joined)
+        with tr.span("fleet/claim", streams=k):
+            slots = free[:k]
+            t_admit = time.perf_counter()
+            n_h = self.n_h
+            for stream, (qxs, _, _) in joined:
+                stream.t_admit = t_admit
                 stream.qxs = qxs
                 stream.cursor = 0
-                stream.h_seq = np.zeros((len(qxs), self.n_h), np.int32)
-                init = (h0,) if c0 is None else (h0, c0)
-                joined.append((slot, stream, init))
-            outcomes.append(None)
+                stream.h_seq = np.zeros((len(qxs), n_h), np.int32)
+        m.inc("fleet/submit_total", n)
+        m.observe_many("fleet/submit_us",
+                       [(time.perf_counter() - t0) * 1e6 / n] * n, timed=True)
         if joined:
-            self._write_joined(joined)
+            self._write_joined(slots, joined)
         return outcomes
 
-    def _write_joined(self, joined: list) -> None:
+    def _check_drain(self, streams: list) -> list:
+        """Validate a drain's head in one pass; one entry per stream, the
+        normalised ``(qxs, h0, c0)`` or the TypeError/ValueError that
+        ``validate_stream`` raises for it.
+
+        A stream already in the form ``validate_stream`` returns (an int32
+        ``(T, n_in)`` ndarray with ``T >= 1``; each state ``None`` or an
+        int32 ``(L, H)`` ndarray; no ``qc0`` on a GRU engine) passes on
+        O(1) attribute checks, and all such streams share one range check
+        over their concatenated inputs.  Any other stream, and every stream
+        when that range check fails, goes through ``validate_stream``, so
+        each malformed stream gets exactly the error it would alone."""
+        n_in, lh, gru = self.n_in, (self.n_layers, self.n_h), self._arity == 1
+
+        def is_state(a) -> bool:
+            return a is None or (type(a) is np.ndarray and a.dtype == _I32
+                                 and a.shape == lh)
+
+        out: list = []
+        fast = []                       # indices that passed the O(1) checks
+        for i, s in enumerate(streams):
+            q = s.qxs
+            if (type(q) is np.ndarray and q.dtype == _I32 and q.ndim == 2
+                    and q.shape[1] == n_in and len(q) and is_state(s.qh0)
+                    and (s.qc0 is None if gru else is_state(s.qc0))):
+                fast.append(i)
+                out.append((q, s.qh0, s.qc0))
+            else:
+                out.append(self._validated(s))
+        if fast:
+            xs = np.concatenate([out[i][0] for i in fast])
+            if xs.min() < self.in_fmt.qmin or xs.max() > self.in_fmt.qmax:
+                for i in fast:
+                    out[i] = self._validated(streams[i])
+        return out
+
+    def _validated(self, stream: SensorStream):
+        """``validate_stream``'s result, or the error it raises, under a
+        per-stream ``fleet/validate`` span with the stream's ``rid``."""
+        try:
+            with obs_trace.get_tracer().span("fleet/validate", rid=stream.rid):
+                return self.validate_stream(stream)
+        except (TypeError, ValueError) as e:
+            return e
+
+    def _write_joined(self, slots: list, joined: list) -> None:
         """Merge the joining streams' initial ``(L, H)`` states into the
-        carry and make them active.  The merge has the carry's fixed shape
+        carry and make them active (``joined``: per slot in ``slots``, the
+        stream and its ``(qxs, h0, c0)``; a ``None`` state leaves the zeros
+        of the merge arrays).  The merge has the carry's fixed shape
         (state-sized host arrays plus a slot mask) whatever the batch size,
         so it compiles once per engine; on a sharded engine its inputs and
         output keep the block partition (``slot_to_shard``)."""
         m = self.obs
         k = len(joined)
-        slots = [slot for slot, _, _ in joined]
         with obs_trace.get_tracer().span("fleet/admit_write", streams=k):
             mask = np.zeros((self.slots,), bool)
             mask[slots] = True
             new = []
-            for rows in zip(*(init for _, _, init in joined)):  # h0s, c0s
+            for j in range(1, self._arity + 1):         # h0, then c0 (LSTM)
                 a = np.zeros((self.n_layers, self.slots, self.n_h), np.int32)
-                a[:, slots] = np.stack(rows, axis=1)
+                given = [(slot, res[j]) for slot, (_, res) in zip(slots, joined)
+                         if res[j] is not None]
+                if given:
+                    idx, rows = zip(*given)
+                    a[:, list(idx)] = np.stack(rows, axis=1)
                 new.append(a)
             state = (self._qh,) if self._qc is None else (self._qh, self._qc)
             out = self._merge(state, tuple(new), mask)
             self._qh = out[0]
             if self._qc is not None:
                 self._qc = out[1]
-        for slot, stream, _ in joined:
-            self.active[slot] = stream
+        self.active.update(zip(slots, (stream for stream, _ in joined)))
         m.inc("fleet/admit_writes_total")
         m.observe("fleet/admit_batch", k, edges=self._admit_edges)
         m.inc("fleet/admitted_total", k)
@@ -551,17 +634,21 @@ class SensorFleetEngine:
 
     def validate_stream(self, stream: SensorStream):
         """Validate ``stream`` at the submit boundary WITHOUT claiming a
-        slot, returning the normalised ``(qxs, h0, c0)`` arrays.
+        slot, returning the normalised ``(qxs, h0, c0)``: ``qxs`` int32
+        (the stream's own array when it already is), each state ``(L, H)``
+        int32 or ``None`` for the zero default.
 
         This is the O(validation) part of ``submit`` — dtype/shape/range
         checks plus state normalisation, no device work and no slot claim —
         factored out so the ingest layer (``repro.serving.ingest``) can
         reject malformed streams at enqueue time, long before a slot frees
-        up.  Raises TypeError/ValueError exactly like ``submit``; does not
-        mutate the stream.
+        up.  At the engine boundary ``submit_many`` checks a whole drain at
+        once and runs this only for a stream that fails that check, so each
+        error below is the one ``submit`` raises.  Raises TypeError/ValueError;
+        does not mutate the stream.
         """
         qxs = np.asarray(stream.qxs)
-        if not np.issubdtype(qxs.dtype, np.integer):
+        if qxs.dtype.kind not in "iu":
             if np.issubdtype(qxs.dtype, np.floating) \
                     and not np.isfinite(qxs).all():
                 raise ValueError(
@@ -585,7 +672,8 @@ class SensorFleetEngine:
                 f"stream {stream.rid}: inputs exceed the "
                 f"({in_fmt.frac_bits},{in_fmt.total_bits}) fixed-point "
                 f"range [{in_fmt.qmin}, {in_fmt.qmax}]")
-        qxs = qxs.astype(np.int32)
+        if qxs.dtype != _I32:
+            qxs = qxs.astype(np.int32)
         h0 = self._state_init(stream.rid, stream.qh0, "qh0")
         if self._arity == 1:
             if stream.qc0 is not None:

@@ -64,6 +64,20 @@ def test_async_save_equivalent(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_async_save_snapshots_host_leaves(tmp_path):
+    """An async save writes the tree as it was at the call, though the
+    caller writes into its host arrays while the save runs (a serving
+    fleet's ``h_seq`` between steps)."""
+    m = CheckpointManager(tmp_path, keep=3)
+    buf = np.arange(1 << 20, dtype=np.int32)
+    m.save_async(1, {"buf": buf})
+    buf[:] = -1
+    m.wait()
+    restored, _, _ = m.restore({"buf": np.zeros_like(buf)}, step=1)
+    np.testing.assert_array_equal(np.asarray(restored["buf"]),
+                                  np.arange(1 << 20, dtype=np.int32))
+
+
 def test_torn_write_recovery(tmp_path):
     """A crash mid-save leaves ``step_<N>.tmp/`` with payload but no
     manifest.  ``steps()`` must not list it, ``restore()`` must fall back to

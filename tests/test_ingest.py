@@ -133,10 +133,14 @@ def test_explicit_pump_step_loop_matches_engine_run(setup):
     _assert_streams_equal(ref, got)
 
 
-def test_golden_replay_through_ingest_queue():
+@pytest.mark.parametrize("check", ["drain", "per_stream"])
+def test_golden_replay_through_ingest_queue(check):
     """Acceptance: the committed golden fleet schedule replayed THROUGH the
-    ingest queue reproduces every stream's integers exactly."""
+    ingest queue reproduces every stream's integers exactly, with the
+    engine's one-pass drain check and with ``validate_stream`` on every
+    stream of every drain."""
     from test_golden import FLEET_PATH, _load, _stored_luts
+    from test_serving import per_stream_checks
 
     g = _load(FLEET_PATH)
     qps = [LSTMParams(w=jnp.asarray(w, jnp.int32), b=jnp.asarray(b, jnp.int32))
@@ -149,6 +153,8 @@ def test_golden_replay_through_ingest_queue():
     eng = SensorFleetEngine(qps, g["_fmt"], _stored_luts(g),
                             batch_slots=g["engine"]["batch_slots"],
                             chunk=g["engine"]["chunk"], backend="fxp")
+    if check == "per_stream":
+        per_stream_checks(eng)
     IngestQueue(eng, capacity=4, policy="reject").run(streams)
     assert all(s.done for s in streams)
     for s, out in zip(streams, g["outputs"]):
@@ -428,3 +434,52 @@ def test_churn_benchmark_smoke():
     assert row["n"] == 24 and row["p99_us"] >= row["p50_us"] > 0
     assert res["counts"]["completed"] > 0
     assert res["sustained_timesteps_per_s"] > 0
+
+
+# -- the engine's one-pass drain check behind the queue -----------------------
+
+
+@pytest.mark.parametrize("kind", ["none", "int64", "float", "nan",
+                                  "out_of_range", "shape", "empty", "qh0",
+                                  "qc0"])
+@pytest.mark.parametrize("cell,n_layers", [("lstm", 1), ("lstm", 2),
+                                           ("gru", 1)])
+def test_pump_drain_check_equals_per_stream_validation(cell, n_layers, kind):
+    """Seven valid streams enqueued, then stream 2 corrupted in the queue
+    (``kind``; out of range in place): one pump into 4 slots admits, rejects,
+    counts, quarantines and stops at engine full exactly as it does with
+    ``validate_stream`` on every stream, and only the corrupted stream (the
+    whole head, for the range check) is validated again."""
+    from test_serving import (CORRUPTIONS, _cell_stack, _stateful_streams,
+                              admission_record, assert_same_admission,
+                              corrupt, count_validations, per_stream_checks)
+
+    qps, luts = _cell_stack(cell, n_layers)
+    got = []
+    for reference in (False, True):
+        reg = MetricsRegistry()
+        eng = SensorFleetEngine(qps, FMT, luts, batch_slots=4, chunk=4,
+                                backend="fxp", metrics=reg)
+        if reference:
+            per_stream_checks(eng)
+        q = IngestQueue(eng, capacity=8)
+        streams = _stateful_streams(cell, n_layers, [5, 9, 3, 7, 6, 4, 8])
+        for s in streams:
+            s.qxs = np.array(s.qxs)
+            q.submit(s)
+        calls = count_validations(eng)
+        corrupt(streams[2], kind, cell, n_layers)
+        admitted = q.pump()
+        got.append(admission_record(eng, reg))
+        got[-1].update(admitted=admitted, calls=calls,
+                       queued=[s.rid for s in q.queued])
+    fast, ref = got
+    assert_same_admission(fast, ref, kind, n_layers)
+    assert fast["admitted"] == ref["admitted"] == 4
+    bad = CORRUPTIONS[kind] is not None
+    assert fast["queued"] == ref["queued"] == ([5, 6] if bad else [4, 5, 6])
+    assert fast["counters"]["fleet/ingest_admitted_total"] == 4
+    assert fast["counters"].get("fleet/ingest_admit_rejected_total", 0) == bad
+    assert fast["calls"] == {"none": [], "out_of_range": [0, 1, 2, 3, 4]
+                             }.get(kind, [2])
+    assert ref["calls"] == [0, 1, 2, 3, 4] + ([5] if bad else [])

@@ -95,6 +95,20 @@ def test_histogram_quantiles_deterministic():
     assert snap["p50"] == 1.0 and snap["p95"] == 2.0 and snap["p99"] == 10.0
 
 
+def test_observe_many_equals_one_observe_each():
+    """A batch of readings in one call (a drain's per-stream waits) leaves
+    the histogram exactly as one ``observe`` per reading."""
+    values = [0.5, 3.0, 3.0, 47.0, 2e6]
+    one, many = MetricsRegistry(), MetricsRegistry()
+    for v in values:
+        one.observe("fleet/ingest_wait_us", v)
+    many.observe_many("fleet/ingest_wait_us", values)
+    many.observe_many("fleet/ingest_wait_us", [])
+    assert many.snapshot() == one.snapshot()
+    NULL_REGISTRY.observe_many("fleet/ingest_wait_us", values)
+    assert NULL_REGISTRY.snapshot()["histograms"] == {}
+
+
 def test_histogram_rejects_bad_edges():
     with pytest.raises(ValueError):
         Histogram(edges=(5.0, 1.0))
@@ -391,16 +405,15 @@ def test_slot_occupancy_gauge_updates_when_slots_free():
 
 # -- the profiler sink: spans in the jax.profiler trace ------------------------
 
-# every span of one request carries its rid
-REQUEST_SPANS = {"fleet/enqueue", "fleet/validate", "fleet/submit",
-                 "fleet/claim"}
-# a drain is fleet/ingest (through the queue) or fleet/admit (direct)
-DRAINS = {"fleet/ingest", "fleet/admit"}
+# a drain is fleet/ingest (through the queue), fleet/admit (direct bulk) or
+# fleet/submit (one direct submit, with the rid)
+DRAINS = {"fleet/ingest", "fleet/admit", "fleet/submit"}
+# fleet/validate is the drain's check (arg streams), the enqueue check, or a
+# stream's own validation inside the drain's check (both with the rid)
 PARENT = {"fleet/enqueue": {None}, "fleet/ingest": {None},
-          "fleet/admit": {None}, "fleet/step": {None},
-          "fleet/validate": {"fleet/enqueue", "fleet/submit"},
-          "fleet/submit": DRAINS, "fleet/claim": {"fleet/submit"},
-          "fleet/admit_write": DRAINS,
+          "fleet/admit": {None}, "fleet/submit": {None}, "fleet/step": {None},
+          "fleet/validate": {"fleet/enqueue", "fleet/validate"} | DRAINS,
+          "fleet/claim": DRAINS, "fleet/admit_write": DRAINS,
           "fleet/assemble": {"fleet/step"}, "fleet/dispatch": {"fleet/step"},
           "fleet/wait": {"fleet/step"}, "fleet/harvest": {"fleet/step"}}
 
@@ -443,32 +456,39 @@ def _parents(spans):
 
 
 def _check_span_tree(spans, streams, drain):
-    """Every span under its expected parent, request spans under one rid;
-    one ``fleet/admit_write`` per ``drain`` span that admitted streams, its
-    ``streams`` arg the drain's claims that got a slot, summing to all."""
+    """Every span under its expected parent; request spans carry their rid,
+    drain spans their stream counts: per ``drain`` span one ``fleet/claim``
+    and, when it admitted streams, one ``fleet/admit_write`` with the same
+    ``streams``, no more than its ``fleet/validate`` checked; the writes sum
+    to all the streams."""
     assert {sp[0] for sp in spans} == set(PARENT) - (DRAINS - {drain}) \
-        - ({"fleet/enqueue"} if drain == "fleet/admit" else set())
-    writes = {}
-    for sp, parent in zip(spans, parents := _parents(spans)):
+        - ({"fleet/enqueue"} if drain != "fleet/ingest" else set())
+    per_drain = {}
+    for sp, parent in zip(spans, _parents(spans)):
         name, _, _, stats = sp
-        assert (parent[0] if parent else None) in PARENT[name], (name, parent)
-        if name in REQUEST_SPANS:
+        pname = parent[0] if parent else None
+        assert pname in PARENT[name], (name, parent)
+        if name in ("fleet/enqueue", "fleet/submit") or (
+                name == "fleet/validate" and pname not in DRAINS):
             assert isinstance(stats.get("rid"), int), sp
-            if parent is not None and parent[0] in REQUEST_SPANS:
+            if pname == "fleet/enqueue":
                 assert stats["rid"] == parent[3]["rid"], (sp, parent)
-        if name == "fleet/admit_write":
-            assert parent[0] == drain and id(parent) not in writes
-            writes[id(parent)] = stats["streams"]
-    claims = {}
-    for sp, parent in zip(spans, parents):
-        if sp[0] == "fleet/claim":
-            drain_span = parents[spans.index(parent)]
-            claims[id(drain_span)] = claims.get(id(drain_span), 0) + 1
-    # a drain's write carries its claims, less the one that found the
-    # engine full where the drain stopped there
-    for drain_id, n in claims.items():
-        assert writes.get(drain_id, 0) in (n, n - 1), (writes, claims)
-    assert sum(writes.values()) == len(streams)
+            if pname == "fleet/validate":
+                assert isinstance(parent[3].get("streams"), int), (sp, parent)
+        if pname in DRAINS:
+            assert pname == drain, (sp, parent)
+            assert isinstance(stats.get("streams"), int), sp
+            seen = per_drain.setdefault(id(parent), {})
+            if name == "fleet/validate":
+                seen[name] = seen.get(name, 0) + stats["streams"]
+            else:
+                assert name not in seen, (sp, parent)
+                seen[name] = stats["streams"]
+    for seen in per_drain.values():
+        assert seen.get("fleet/admit_write", 0) == seen["fleet/claim"], seen
+        assert seen["fleet/claim"] <= seen["fleet/validate"], seen
+    assert sum(seen.get("fleet/admit_write", 0)
+               for seen in per_drain.values()) == len(streams)
 
 
 def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
@@ -492,8 +512,8 @@ def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
 
 def test_profiler_spans_of_the_direct_admit_path(tmp_path):
     """``engine.run`` without the queue: each ``admit`` drain is a
-    ``fleet/admit`` span holding the per-stream ``fleet/submit`` spans and
-    one ``fleet/admit_write``."""
+    ``fleet/admit`` span holding one ``fleet/validate``, one ``fleet/claim``
+    and one ``fleet/admit_write``."""
     qps, luts = _qps(), make_lut_pair(64)
     streams = _streams([5, 9, 3, 7, 6, 4])
     eng = _engine(qps, luts)                          # 4 slots, chunk 4
@@ -502,6 +522,30 @@ def test_profiler_spans_of_the_direct_admit_path(tmp_path):
     _check_span_tree(spans, streams, "fleet/admit")
     first = next(sp for sp in spans if sp[0] == "fleet/admit_write")
     assert first[3]["streams"] == 4                   # the first drain fills
+
+
+def test_profiler_spans_of_direct_submit_and_a_rejected_stream(tmp_path):
+    """A direct ``submit`` is a drain of one, a ``fleet/submit`` span with
+    the ``rid``; a stream the drain check refuses is validated again under
+    its own ``fleet/validate`` with its ``rid``, inside the drain's."""
+    qps, luts = _qps(), make_lut_pair(64)
+    streams = _streams([5, 3, 7])
+    bad = SensorStream(rid=99, qxs=np.zeros((3, N_IN), np.float64))
+    eng = _engine(qps, luts)                          # 4 slots
+
+    def serve():
+        for s in streams:
+            assert eng.submit(s)
+        with pytest.raises(TypeError):
+            eng.submit(bad)
+        eng.run([])
+
+    spans = _profiled(tmp_path, serve)
+    assert all(s.done for s in streams)
+    _check_span_tree(spans, streams, "fleet/submit")
+    own = [sp[3]["rid"] for sp, parent in zip(spans, _parents(spans))
+           if sp[0] == "fleet/validate" and parent[0] == "fleet/validate"]
+    assert own == [99]
 
 
 def test_fleet_golden_integer_equal_with_profiler_spans(tmp_path):

@@ -476,8 +476,6 @@ def test_batched_admission_equals_one_at_a_time(cell, n_layers):
     way is integer-equal, stream by stream and slot by slot, to admitting
     each stream alone (a batch of one per ``submit``), and both equal the
     stream run solo from its own initial state."""
-    from repro.core.lstm import recurrent_forward
-
     qps, luts = _cell_stack(cell, n_layers)
     lens = [5, 9, 3, 12, 7, 4, 10, 6]
     kw = dict(batch_slots=4, chunk=4, backend="fxp")
@@ -508,24 +506,31 @@ def test_batched_admission_equals_one_at_a_time(cell, n_layers):
             np.testing.assert_array_equal(a.qc, b.qc)
         else:
             assert a.qc is None and b.qc is None
-        h0 = c0 = None
-        if a.qh0 is not None:
-            h0 = [jnp.asarray(a.qh0[li])[None] for li in range(n_layers)]
-            if cell == "lstm":
-                c0 = [jnp.asarray(a.qc0[li])[None] for li in range(n_layers)]
-        seq, state = recurrent_forward(
-            cell, qps, jnp.asarray(a.qxs)[None], backend="fxp", fmt=FMT,
-            luts=luts, h0=h0, c0=c0, return_sequence=True,
-            return_state="all")
-        hs = state[0] if cell == "lstm" else state
-        np.testing.assert_array_equal(a.h_seq, np.asarray(seq[0]))
-        np.testing.assert_array_equal(
-            a.qh.reshape(n_layers, N_H),
-            np.stack([np.asarray(h[0]) for h in hs]))
+        _assert_equals_solo(cell, qps, luts, a)
+
+
+def _assert_equals_solo(cell, qps, luts, s):
+    """``s`` as served equals the stream run alone from its initial state."""
+    from repro.core.lstm import recurrent_forward
+
+    n_layers = len(qps)
+    h0 = c0 = None
+    if s.qh0 is not None:
+        h0 = [jnp.asarray(s.qh0[li])[None] for li in range(n_layers)]
         if cell == "lstm":
-            np.testing.assert_array_equal(
-                a.qc.reshape(n_layers, N_H),
-                np.stack([np.asarray(c[0]) for c in state[1]]))
+            c0 = [jnp.asarray(s.qc0[li])[None] for li in range(n_layers)]
+    seq, state = recurrent_forward(
+        cell, qps, jnp.asarray(s.qxs)[None], backend="fxp", fmt=FMT,
+        luts=luts, h0=h0, c0=c0, return_sequence=True, return_state="all")
+    hs = state[0] if cell == "lstm" else state
+    np.testing.assert_array_equal(s.h_seq, np.asarray(seq[0]))
+    np.testing.assert_array_equal(
+        s.qh.reshape(n_layers, N_H),
+        np.stack([np.asarray(h[0]) for h in hs]))
+    if cell == "lstm":
+        np.testing.assert_array_equal(
+            s.qc.reshape(n_layers, N_H),
+            np.stack([np.asarray(c[0]) for c in state[1]]))
 
 
 def test_batched_admission_slot_map_lowest_free_fifo():
@@ -607,3 +612,169 @@ def test_admission_merge_compiles_once():
     eng.run([])
     eng.admit(_make_streams([4] * 8, seed=2))
     assert len(eng.active) == 8 and eng._merge._cache_size() == 1
+
+
+# --- the drain check: one pass over a drain at the engine boundary ----------
+
+EQUIV_CELLS = [("lstm", 1), ("lstm", 2), ("gru", 1)]
+# each way a stream can be corrupted after it passed enqueue, with the error
+# class the engine boundary rejects it with; None: still valid, but not in
+# the form enqueue leaves it, so validate_stream normalises it
+CORRUPTIONS = {"none": None, "int64": None, "float": TypeError,
+               "nan": ValueError, "out_of_range": ValueError,
+               "shape": ValueError, "empty": ValueError, "qh0": TypeError,
+               "qc0": ValueError}
+
+
+def corrupt(s, kind, cell, n_layers):
+    """Corrupt stream ``s`` one way of ``CORRUPTIONS``; ``out_of_range``
+    writes into ``s.qxs`` in place (it must be writable)."""
+    if kind == "int64":
+        s.qxs = s.qxs.astype(np.int64)
+    elif kind == "float":
+        s.qxs = s.qxs.astype(np.float32)
+    elif kind == "nan":
+        s.qxs = np.full(s.qxs.shape, np.nan, np.float32)
+    elif kind == "out_of_range":
+        s.qxs[1, 0] = FMT.qmax + 1
+    elif kind == "shape":
+        s.qxs = s.qxs.reshape(-1)
+    elif kind == "empty":
+        s.qxs = s.qxs[:0]
+    elif kind == "qh0":
+        s.qh0 = np.zeros((n_layers, N_H), np.float32)
+    elif kind == "qc0":               # the GRU takes no qc0 at all
+        s.qc0 = np.zeros((n_layers, N_H + (cell == "lstm")), np.int32)
+    else:
+        assert kind == "none", kind
+
+
+def per_stream_checks(eng):
+    """Make ``eng`` check every stream of a drain with ``validate_stream``:
+    the reference the one-pass drain check must equal."""
+    eng._check_drain = lambda head: [eng._validated(s) for s in head]
+    return eng
+
+
+def count_validations(eng) -> list:
+    """The rids ``eng.validate_stream`` is called with from now on."""
+    calls, real = [], eng.validate_stream
+    eng.validate_stream = lambda s: calls.append(s.rid) or real(s)
+    return calls
+
+
+def admission_record(eng, reg) -> dict:
+    """What an admission decided: slot map, quarantine (rid and error:
+    class and message), counters and the carry."""
+    def init(s0):
+        return np.zeros((eng.n_layers, N_H)) if s0 is None else s0
+
+    return {"slots": {s.rid: slot for slot, s in eng.active.items()},
+            "init": {s.rid: [init(s.qh0), init(s.qc0)]
+                     for s in eng.active.values()},
+            "quarantined": [(s.rid, s.error) for s in eng.quarantined],
+            "counters": reg.snapshot()["counters"],
+            "carry": [np.asarray(a) for a in (eng._qh, eng._qc)
+                      if a is not None]}
+
+
+def assert_same_admission(fast, ref, kind, n_layers):
+    """``fast`` (the drain check) and ``ref`` (per-stream validation) of a
+    drain of streams 0..6 over 4 slots with stream 2 corrupted by ``kind``
+    decided alike; the carry holds each admitted stream's initial state."""
+    for key in ("slots", "quarantined", "counters"):
+        assert fast[key] == ref[key], key
+    for a, b in zip(fast["carry"], ref["carry"]):
+        np.testing.assert_array_equal(a, b)
+    exc = CORRUPTIONS[kind]
+    if exc is None:
+        assert fast["quarantined"] == [] and list(fast["slots"]) == [0, 1, 2, 3]
+    else:
+        ((rid, err),) = fast["quarantined"]
+        assert rid == 2 and err.startswith(f"{exc.__name__}: stream 2:"), err
+        assert fast["counters"][f"fleet/submit_rejected/{exc.__name__}"] == 1
+        assert list(fast["slots"]) == [0, 1, 3, 4]
+    assert fast["counters"]["fleet/submit_full_total"] == 1
+    assert fast["counters"]["fleet/admitted_total"] == 4
+    # the merge wrote each admitted stream's initial state (zeros default)
+    for rid, slot in fast["slots"].items():
+        for carry, s0 in zip(fast["carry"], fast["init"][rid]):
+            np.testing.assert_array_equal(carry[:, slot],
+                                          np.reshape(s0, (n_layers, N_H)))
+
+
+@pytest.mark.parametrize("kind", list(CORRUPTIONS))
+@pytest.mark.parametrize("cell,n_layers", EQUIV_CELLS)
+def test_drain_check_equals_per_stream_validation(cell, n_layers, kind):
+    """``admit`` of 7 streams over 4 slots, stream 2 corrupted: the one-pass
+    drain check decides exactly as ``validate_stream`` on every stream
+    (outcomes, errors, slots, engine-full stop, counters, quarantine,
+    carry), and runs ``validate_stream`` only for a stream its O(1) checks
+    refuse, or for the whole head when the range check fails."""
+    from repro.obs.metrics import MetricsRegistry
+
+    qps, luts = _cell_stack(cell, n_layers)
+    got = []
+    for reference in (False, True):
+        reg = MetricsRegistry()
+        eng = SensorFleetEngine(qps, FMT, luts, batch_slots=4, chunk=4,
+                                backend="fxp", metrics=reg)
+        if reference:
+            per_stream_checks(eng)
+        calls = count_validations(eng)
+        streams = _stateful_streams(cell, n_layers, [5, 9, 3, 7, 6, 4, 8])
+        streams[2].qxs = np.array(streams[2].qxs)
+        corrupt(streams[2], kind, cell, n_layers)
+        pending = list(streams)
+        eng.admit(pending)
+        got.append(admission_record(eng, reg))
+        got[-1]["pending"] = [s.rid for s in pending]
+        got[-1]["calls"] = calls
+    fast, ref = got
+    assert_same_admission(fast, ref, kind, n_layers)
+    assert fast["pending"] == ref["pending"] == (
+        [4, 5, 6] if CORRUPTIONS[kind] is None else [5, 6])
+    assert ref["calls"] == [0, 1, 2, 3, 4] + (
+        [] if CORRUPTIONS[kind] is None else [5])
+    assert fast["calls"] == {"none": [], "out_of_range": [0, 1, 2, 3, 4]
+                             }.get(kind, [2])
+
+
+@pytest.mark.parametrize("cell,n_layers", EQUIV_CELLS)
+def test_drain_check_serves_the_same_integers(cell, n_layers):
+    """Drains with one stream of every corrupt kind among good ones, served
+    to the end: the one-pass check and per-stream validation give the same
+    slots, quarantine and integers, and each stream served equals its run
+    alone."""
+    qps, luts = _cell_stack(cell, n_layers)
+    kinds = ["int64"] + [k for k, e in CORRUPTIONS.items() if e is not None]
+    runs = []
+    for reference in (False, True):
+        eng = SensorFleetEngine(qps, FMT, luts, batch_slots=4, chunk=4,
+                                backend="fxp")
+        if reference:
+            per_stream_checks(eng)
+        streams = _stateful_streams(cell, n_layers, [5, 9, 3, 12, 7, 4, 10,
+                                                     6, 8, 5, 3, 7, 9, 4,
+                                                     6, 5])
+        for s, kind in zip(streams[1::2], kinds):
+            s.qxs = np.array(s.qxs)
+            corrupt(s, kind, cell, n_layers)
+        pending, slots = list(streams), {}
+        while pending or eng.active:
+            eng.admit(pending)
+            _slot_log(eng, slots)
+            eng.step()
+        runs.append((streams, slots,
+                     [(s.rid, s.error) for s in eng.quarantined]))
+    (fast, slots_f, quar_f), (ref, slots_r, quar_r) = runs
+    assert slots_f == slots_r and quar_f == quar_r
+    assert [rid for rid, _ in quar_f] == [3, 5, 7, 9, 11, 13, 15]
+    for a, b in zip(fast, ref):
+        assert a.done == b.done
+        if a.done:
+            np.testing.assert_array_equal(a.h_seq, b.h_seq)
+            np.testing.assert_array_equal(a.qh, b.qh)
+            if cell == "lstm":
+                np.testing.assert_array_equal(a.qc, b.qc)
+            _assert_equals_solo(cell, qps, luts, a)
