@@ -26,8 +26,8 @@ Quick start::
     obs.disable_all()
 
 Instrumented layers: ``serving/lstm_engine.py`` (submit latency, admit-queue
-depth, slot occupancy, whole-step time, quarantine counts; spans of
-admission and of every part of the step), ``serving/ingest.py`` (enqueue
+depth, slot occupancy, whole-step time, admission rejections, staged
+timesteps; spans of admission and of every part of the step), ``serving/ingest.py`` (enqueue
 and admission latency, queue depth; spans of enqueue and of each drain),
 ``checkpoint/checkpoint.py`` (save/restore duration, payload bytes, torn
 sweeps), ``serving/faults.py::retry_io`` (retry counts),

@@ -26,8 +26,10 @@ Injectable faults:
 * **poison input** — ``poison_stream(kind, ...)`` builds every malformed
   ``SensorStream`` the ``submit`` boundary must reject (NaN/Inf, wrong
   dtype/ndim/feature-width, empty, fixed-point overflow), and
-  ``poison_mid_flight`` corrupts an *admitted* stream so the engine's
-  per-step quarantine path has something to catch.
+  ``poison_mid_flight`` corrupts an *admitted* stream's ``qxs``: the engine
+  serves the copy it staged at the claim, so the corruption must show
+  isolation (the stream completes with its claim-time integers, the other
+  lanes are untouched), not a quarantine.
 * **ingest queue overflow** — ``IngestFaultPlan(overflow_at=N,
   overflow_burst=B)`` floods the ``IngestQueue`` with B extra arrivals
   just before serving step N (an arrival storm): the queue's backpressure
@@ -208,9 +210,10 @@ def poison_stream(kind: str, n_in: int, fmt, *, rid: int = 666, t: int = 4):
 
 
 def poison_mid_flight(stream, n_in: int) -> None:
-    """Corrupt an ADMITTED stream in place (a buggy caller mutating ``qxs``
-    under the engine): the per-step quarantine path must isolate it without
-    touching any other lane's integers."""
+    """Corrupt an ADMITTED stream (a buggy caller swapping its ``qxs`` for
+    a wrong-shape array under the engine).  The engine reads only its own
+    staging after the claim, so the stream must still complete with the
+    integers of its claim-time input, and no other lane's may change."""
     stream.qxs = np.zeros((max(1, stream.cursor), n_in + 3), np.int32)
 
 

@@ -80,25 +80,49 @@ blocks — every surviving stream continues bit-identically (battery:
 ``tests/spmd_scripts/check_fleet_restore.py``).  Input faults degrade
 gracefully instead of crashing the fleet: ``submit`` validates
 dtype/ndim/feature-width/finiteness/fixed-point range at the boundary
-(reject, don't crash), ``admit`` turns those rejections into per-stream
-quarantine for bulk serving, and ``step`` quarantines a stream whose
-buffers were corrupted mid-flight — one poison stream fails alone, the
-rest of the batch's integers are untouched (masked lanes never interact).
+(reject, don't crash), and ``admit`` turns those rejections into
+per-stream quarantine for bulk serving — one poison stream fails alone.
+
+Staging: the engine owns every admitted stream's inputs and outputs as
+host arrays, ``(slots, cap, n_in)`` inputs and ``(slots, cap, H)`` top-layer
+outputs plus a per-slot cursor, length and occupancy; ``cap`` is the
+longest stream admitted so far rounded up to a power of two (at least
+``chunk``), and the arrays grow when a longer stream joins.  A claim copies
+the joining streams' inputs in, one write per distinct length; a step
+gathers its ``(slots, t_step, n_in)`` input in one indexed read and writes
+the kernel's outputs back in one indexed write.  The kernel never reads the
+caller's arrays after the claim, so a caller that rewrites or replaces a
+stream's ``qxs``, ``h_seq`` or ``cursor`` mid-flight cannot reach the kernel
+or another lane: the stream still completes with the integers of its
+claim-time input.  Quarantine is therefore an admission outcome only.
+
+Rejection counters (pinned by ``tests/test_obs.py``): a stream failure is
+counted once, under the boundary where it happened — validation failures at
+the engine's submit boundary (a direct ``submit`` or an ``admit`` drain) as
+``fleet/submit_rejected_total`` + ``fleet/submit_rejected/<Exc>``; the
+ingest queue's enqueue-time rejections as ``fleet/ingest_rejected/*``
+instead (the stream never reaches the engine).  ``fleet/admit_rejected_total``
+counts how many streams ``admit()`` dropped from its pending list: the same
+event seen from admission, overlapping ``fleet/submit_rejected_total`` by
+design.
 
 Observability (ISSUE 9): the engine reports itself through ``repro.obs`` —
 submit latency (``fleet/submit_us``), admit-queue depth, slot occupancy,
 whole-step time (``fleet/step_us``: assembly, dispatch, the wait for the
 device and the harvest), occupied slot-timesteps
-(``fleet/slot_timesteps_total``), ``t_step`` bucket usage, quarantine
-counts by reason kind, admission writes (``fleet/admit_writes_total`` and
+(``fleet/slot_timesteps_total``), ``t_step`` bucket usage, admission
+rejections, admission writes (``fleet/admit_writes_total`` and
 the ``fleet/admit_batch`` histogram of streams per write), and checkpoint
-save/restore timings + payload bytes; and spans: per drain (under
-``fleet/admit``, the ingest queue's ``fleet/ingest``, or ``fleet/submit``
-with the ``rid`` of a direct ``submit``) one ``fleet/validate`` (arg
-``streams``; holding a per-stream ``fleet/validate`` with the ``rid`` of
-each stream that fails the drain check), one ``fleet/claim`` (arg
-``streams``) and one ``fleet/admit_write`` per admitted batch (arg
-``streams``); and ``fleet/step`` (children ``fleet/assemble``, ``fleet/dispatch``,
+save/restore timings + payload bytes, the timesteps copied into the
+staging at claim (``fleet/staged_timesteps_total``) and its ``cap``
+(``fleet/stage_capacity``); and spans: per drain (under ``fleet/admit``, the
+ingest queue's ``fleet/ingest``, or ``fleet/submit`` with the ``rid`` of a
+direct ``submit``) one ``fleet/validate`` (arg ``streams``; holding a
+per-stream ``fleet/validate`` with the ``rid`` of each stream that fails the
+drain check), one ``fleet/claim`` (arg ``streams``; holding one
+``fleet/stage``, arg ``streams``, when it admitted streams) and one
+``fleet/admit_write`` per admitted batch (arg ``streams``); and
+``fleet/step`` (children ``fleet/assemble``, ``fleet/dispatch``,
 ``fleet/wait``, ``fleet/harvest``) — under the zero-perturbation contract:
 metrics/spans time and count Python-level events only and never touch
 traced values, so every bit-identity battery passes unchanged with
@@ -151,27 +175,27 @@ class SensorStream:
 
     For an ``L``-layer engine, ``qh0``/``qc0``/``qh``/``qc`` are ``(L, H)``
     (single-layer engines keep the ``(H,)`` form for backward compatibility);
-    ``h_seq`` is always the top layer's ``(T, H)``.
+    ``h_seq`` is always the top layer's ``(T, H)``.  Once admitted, the
+    engine serves the copy of ``qxs`` it staged at the claim; ``h_seq`` and
+    ``cursor`` are views of the engine's state for the caller to read.
     """
 
     rid: int
     qxs: np.ndarray                     # (T, n_in) int32, quantised to fmt
     qh0: np.ndarray | None = None       # (H,) or (L, H) int32 initial state (default 0)
     qc0: np.ndarray | None = None       # LSTM only; must stay None on a GRU engine
-    h_seq: np.ndarray | None = None     # (T, H) int32 top layer, filled as chunks land
+    # (T, H) int32 top layer: from the claim a view of the engine's output
+    # staging, filled as chunks land; once done, an array of its own
+    h_seq: np.ndarray | None = None
     qh: np.ndarray | None = None        # (H,) or (L, H) int32 final hidden state
     qc: np.ndarray | None = None        # (H,) or (L, H) int32 final cell state (None for GRU)
     done: bool = False
-    cursor: int = 0                     # timesteps consumed so far
-    error: str | None = None            # set when rejected or quarantined
+    cursor: int = 0                     # timesteps served: mirrors the engine's
+    error: str | None = None            # set when rejected at admission
     # time.perf_counter() at slot claim and when the final state reached the
     # host: admission-to-done per request.  Not checkpointed.
     t_admit: float | None = None
     t_done: float | None = None
-
-    @property
-    def remaining(self) -> int:
-        return len(self.qxs) - self.cursor
 
 
 class SensorFleetEngine:
@@ -261,7 +285,16 @@ class SensorFleetEngine:
         self._qc = (jnp.zeros((self.n_layers, batch_slots, self.n_h), jnp.int32)
                     if self._arity == 2 else None)
         self.active: dict[int, SensorStream] = {}
-        self.quarantined: list[SensorStream] = []   # rejected/poisoned streams
+        self.quarantined: list[SensorStream] = []   # rejected at admission
+        # the host staging of every slot's inputs and top-layer outputs (see
+        # the module docstring), with its cursor, length and occupancy
+        self._cap = 1 << (chunk - 1).bit_length()
+        self._x_stage = np.zeros((batch_slots, self._cap, self.n_in), np.int32)
+        self._h_stage = np.zeros((batch_slots, self._cap, self.n_h), np.int32)
+        self._cur = np.zeros((batch_slots,), np.int64)
+        self._len = np.zeros((batch_slots,), np.int64)
+        self._on = np.zeros((batch_slots,), bool)
+        self._offsets = np.arange(chunk)    # timestep offsets within a step
         self.steps_run = 0              # batched kernel invocations so far
         self.timesteps_run = 0          # sum of t_step over those invocations
 
@@ -270,7 +303,7 @@ class SensorFleetEngine:
         # so a fleet built before enable() still starts reporting after it;
         # pass an explicit MetricsRegistry for per-engine isolation.  The
         # declares below make every snapshot carry the serving surface —
-        # submit latency, occupancy, quarantine, checkpoint I/O — even before
+        # submit latency, occupancy, staging, checkpoint I/O — even before
         # the first event (and they no-op on the disabled registry).
         self._metrics_override = metrics
         m = self.obs
@@ -280,12 +313,13 @@ class SensorFleetEngine:
         m.declare_hist("ckpt/restore_us", timed=True)
         m.declare_hist("fleet/ckpt_save_us", timed=True)
         m.declare_hist("fleet/ckpt_restore_us", timed=True)
-        m.declare_counter("fleet/quarantined_total")
         m.declare_counter("fleet/steps_total")
         m.declare_counter("fleet/timesteps_total")
         m.declare_counter("fleet/slot_timesteps_total")
         m.declare_counter("fleet/admit_writes_total")
+        m.declare_counter("fleet/staged_timesteps_total")
         m.declare_gauge("fleet/slot_occupancy")
+        m.declare_gauge("fleet/stage_capacity")
         m.declare_gauge("fleet/admit_queue_depth")
 
         # block_b defaults to the kernel's compile-friendly row tile: any slot
@@ -392,43 +426,6 @@ class SensorFleetEngine:
             }
         return snap
 
-    def _count_quarantine(self, kind: str) -> None:
-        """Count a MID-FLIGHT quarantine (an admitted stream whose buffers
-        were corrupted under us).
-
-        Metric contract (pinned by tests/test_obs.py): a stream failure is
-        counted exactly once, under the boundary where it happened —
-
-        * ``fleet/submit_rejected_total`` + ``fleet/submit_rejected/<Exc>``:
-          validation failures at the engine's submit boundary (direct
-          ``submit`` and ``admit`` drains route here, once; the ingest
-          queue's enqueue-time rejections count under
-          ``fleet/ingest_rejected/*`` instead — the stream never reaches
-          the engine).
-        * ``fleet/quarantined_total`` + ``fleet/quarantined/<kind>``: ONLY
-          streams evicted mid-flight by ``_poison_reason`` — never
-          boundary rejections.
-        * ``fleet/admit_rejected_total``: how many streams ``admit()``
-          dropped from its pending list — a disposition count that overlaps
-          ``fleet/submit_rejected_total`` by design (same event, admission
-          view), NOT the quarantine counters.
-        """
-        m = self.obs
-        m.inc("fleet/quarantined_total")
-        m.inc(f"fleet/quarantined/{kind}")
-
-    @staticmethod
-    def _reason_kind(reason: str) -> str:
-        """Collapse a free-text quarantine reason (``_poison_reason`` embeds
-        shapes/dtypes) to a stable metric-key slug."""
-        for prefix, kind in (("qxs dtype", "qxs_dtype"),
-                             ("qxs shape", "qxs_shape"),
-                             ("cursor", "cursor"),
-                             ("h_seq", "h_seq")):
-            if reason.startswith(prefix):
-                return kind
-        return "other"
-
     # --- scheduling ---------------------------------------------------------
 
     def free_slots(self) -> list[int]:
@@ -490,24 +487,27 @@ class SensorFleetEngine:
         The head it may admit (one stream per free slot, plus the one that
         would find the engine full, extended past any rejects) is checked
         in one pass (``_check_drain``): O(1) attribute checks per stream and
-        one range check over all their inputs; only a stream that fails
-        them (or the whole head, when the range check fails) goes through
-        ``validate_stream``.  A valid stream takes the lowest free slot left
-        (the free slots are computed once), a malformed one is rejected
-        without blocking the streams behind it, and the first valid stream
-        that finds no free slot ends the batch (engine full: it and the rest
-        are not taken).  Returns one entry per leading stream taken:
-        ``None`` where it got a slot, else the TypeError/ValueError that
-        rejected it — the caller decides whether to raise (``submit``) or
-        quarantine (``admit``, ``IngestQueue.pump``).
+        one range check per distinct length over their inputs; only a
+        stream that fails them (or the whole head, when the range check
+        fails) goes through ``validate_stream``.  A valid stream takes the
+        lowest free slot left (the free slots are computed once), a
+        malformed one is rejected without blocking the streams behind it,
+        and the first valid stream that finds no free slot ends the batch
+        (engine full: it and the rest are not taken).  The joining streams'
+        inputs are copied into the engine's staging (``_stage``), one write
+        per distinct length, and each gets its ``h_seq`` view.  Returns one
+        entry per leading stream taken: ``None`` where it got a slot, else
+        the TypeError/ValueError that rejected it — the caller decides
+        whether to raise (``submit``) or quarantine (``admit``,
+        ``IngestQueue.pump``).
 
         Bookkeeping is per drain: one ``fleet/validate`` span per checked
         head (arg ``streams``; a rejected stream's own ``validate_stream``
         runs inside it under ``fleet/validate`` with its ``rid``), one
-        ``fleet/claim`` (arg ``streams``), counters incremented by the
-        drain's counts, and ``fleet/submit_us`` records, for each stream
-        examined (taken, or the one that found the engine full), the
-        drain's time over that count.
+        ``fleet/claim`` (arg ``streams``) holding the ``fleet/stage`` write,
+        counters incremented by the drain's counts, and ``fleet/submit_us``
+        records, for each stream examined (taken, or the one that found the
+        engine full), the drain's time over that count.
         """
         m = self.obs
         tr = obs_trace.get_tracer()
@@ -516,14 +516,16 @@ class SensorFleetEngine:
         it = iter(streams)
         outcomes: list = []
         joined: list = []               # (stream, (qxs, h0, c0)), slot order
+        stage: list = []                # (slots, their (k, T, n_in) inputs)
         full = False
         while not full:
             head = list(itertools.islice(it, len(free) + 1 - len(joined)))
             if not head:
                 break
             with tr.span("fleet/validate", streams=len(head)):
-                checked = self._check_drain(head)
-            for stream, res in zip(head, checked):
+                checked, blocks = self._check_drain(head)
+            at = [None] * len(head)     # the slot each stream of the head takes
+            for i, (stream, res) in enumerate(zip(head, checked)):
                 if isinstance(res, Exception):
                     m.inc("fleet/submit_rejected_total")
                     m.inc(f"fleet/submit_rejected/{type(res).__name__}")
@@ -532,21 +534,31 @@ class SensorFleetEngine:
                     m.inc("fleet/submit_full_total")
                     full = True
                 else:
+                    at[i] = free[len(joined)]
                     joined.append((stream, res))
                     outcomes.append(None)
+            for idx, x in blocks:
+                # only the head's last stream can be valid and left without
+                # a slot: it found the engine full
+                if at[idx[-1]] is None:
+                    idx, x = idx[:-1], x[:-1]
+                if idx:
+                    stage.append(([at[i] for i in idx], x))
         n = len(outcomes) + full        # streams examined
         if not n:
             return outcomes
         k = len(joined)
         with tr.span("fleet/claim", streams=k):
             slots = free[:k]
+            if stage:
+                self._stage(stage, k)
             t_admit = time.perf_counter()
-            n_h = self.n_h
-            for stream, (qxs, _, _) in joined:
+            h = self._h_stage
+            for slot, (stream, (qxs, _, _)) in zip(slots, joined):
                 stream.t_admit = t_admit
                 stream.qxs = qxs
                 stream.cursor = 0
-                stream.h_seq = np.zeros((len(qxs), n_h), np.int32)
+                stream.h_seq = h[slot, :len(qxs)]
         m.inc("fleet/submit_total", n)
         m.observe_many("fleet/submit_us",
                        [(time.perf_counter() - t0) * 1e6 / n] * n, timed=True)
@@ -554,18 +566,20 @@ class SensorFleetEngine:
             self._write_joined(slots, joined)
         return outcomes
 
-    def _check_drain(self, streams: list) -> list:
-        """Validate a drain's head in one pass; one entry per stream, the
+    def _check_drain(self, streams: list) -> tuple[list, list]:
+        """Validate a drain's head in one pass: one entry per stream, the
         normalised ``(qxs, h0, c0)`` or the TypeError/ValueError that
-        ``validate_stream`` raises for it.
+        ``validate_stream`` raises for it; and the valid streams' inputs
+        grouped by length, ``(indices, (k, T, n_in) inputs)`` per distinct
+        length ``T``, for the staging write.
 
         A stream already in the form ``validate_stream`` returns (an int32
         ``(T, n_in)`` ndarray with ``T >= 1``; each state ``None`` or an
         int32 ``(L, H)`` ndarray; no ``qc0`` on a GRU engine) passes on
-        O(1) attribute checks, and all such streams share one range check
-        over their concatenated inputs.  Any other stream, and every stream
-        when that range check fails, goes through ``validate_stream``, so
-        each malformed stream gets exactly the error it would alone."""
+        O(1) attribute checks, and the grouped inputs take one range check
+        per length.  Any other stream, and every such stream when that
+        range check fails, goes through ``validate_stream``, so each
+        malformed stream gets exactly the error it would alone."""
         n_in, lh, gru = self.n_in, (self.n_layers, self.n_h), self._arity == 1
 
         def is_state(a) -> bool:
@@ -583,12 +597,60 @@ class SensorFleetEngine:
                 out.append((q, s.qh0, s.qc0))
             else:
                 out.append(self._validated(s))
-        if fast:
-            xs = np.concatenate([out[i][0] for i in fast])
-            if xs.min() < self.in_fmt.qmin or xs.max() > self.in_fmt.qmax:
-                for i in fast:
-                    out[i] = self._validated(streams[i])
-        return out
+        blocks = self._by_length(out)
+        lo, hi = self.in_fmt.qmin, self.in_fmt.qmax
+        if fast and any(x.min() < lo or x.max() > hi for _, x in blocks):
+            for i in fast:
+                out[i] = self._validated(streams[i])
+            blocks = self._by_length(out)
+        return out, blocks
+
+    def _by_length(self, checked: list) -> list:
+        """The valid entries of ``checked`` grouped by input length: per
+        distinct length ``T``, their indices and their inputs stacked into
+        one ``(k, T, n_in)`` array."""
+        groups: dict = {}
+        for i, res in enumerate(checked):
+            if type(res) is tuple:
+                groups.setdefault(len(res[0]), []).append(i)
+        return [(idx, np.concatenate([checked[i][0] for i in idx])
+                 .reshape(len(idx), t, self.n_in))
+                for t, idx in groups.items()]
+
+    def _stage(self, stage: list, k: int) -> None:
+        """Copy ``k`` joining streams' inputs into the staging and open
+        their lanes at cursor 0, with their output rows zeroed: ``stage``
+        holds, per write, the slots and their ``(n, T, n_in)`` inputs.  The
+        staging grows first if a stream is longer than ``cap``."""
+        with obs_trace.get_tracer().span("fleet/stage", streams=k):
+            self._grow(max(x.shape[1] for _, x in stage))
+            for slots, x in stage:
+                slots = np.asarray(slots)
+                t = x.shape[1]
+                self._x_stage[slots, :t] = x
+                self._h_stage[slots] = 0
+                self._cur[slots] = 0
+                self._len[slots] = t
+                self._on[slots] = True
+        m = self.obs
+        m.inc("fleet/staged_timesteps_total",
+              sum(x.shape[0] * x.shape[1] for _, x in stage))
+        m.gauge("fleet/stage_capacity", self._cap)
+
+    def _grow(self, longest: int) -> None:
+        """Reallocate the staging so a stream of ``longest`` timesteps fits
+        (``cap`` becomes the next power of two), keeping every lane's
+        contents and re-pointing the active streams' ``h_seq`` views."""
+        if longest <= self._cap:
+            return
+        cap = 1 << (longest - 1).bit_length()
+        x = np.zeros((self.slots, cap, self.n_in), np.int32)
+        h = np.zeros((self.slots, cap, self.n_h), np.int32)
+        x[:, :self._cap] = self._x_stage
+        h[:, :self._cap] = self._h_stage
+        self._x_stage, self._h_stage, self._cap = x, h, cap
+        for slot, s in self.active.items():
+            s.h_seq = h[slot, :self._len[slot]]
 
     def _validated(self, stream: SensorStream):
         """``validate_stream``'s result, or the error it raises, under a
@@ -685,37 +747,6 @@ class SensorFleetEngine:
             c0 = self._state_init(stream.rid, stream.qc0, "qc0")
         return qxs, h0, c0
 
-    def _pick_t_step(self) -> int:
-        shortest = min(s.remaining for s in self.active.values())
-        for b in self._buckets:
-            if b <= shortest:
-                return b
-        return 1  # unreachable: buckets always contain 1
-
-    def _poison_reason(self, s: SensorStream) -> str | None:
-        """Did the caller corrupt an admitted stream's buffers under us?
-        (Value corruption can't crash the integer datapath; shape/dtype
-        corruption would crash the whole batch — catch it per stream.)"""
-        qxs = np.asarray(s.qxs)
-        if not np.issubdtype(qxs.dtype, np.integer):
-            return f"qxs dtype corrupted to {qxs.dtype}"
-        if qxs.ndim != 2 or qxs.shape[1] != self.n_in:
-            return f"qxs shape corrupted to {qxs.shape}"
-        if not 0 <= s.cursor < len(qxs):
-            return f"cursor {s.cursor} outside stream of {len(qxs)} steps"
-        if s.h_seq is None or s.h_seq.shape != (len(qxs), self.n_h):
-            return "h_seq output buffer corrupted"
-        return None
-
-    def _quarantine(self, slot: int, reason: str) -> None:
-        """Fail ONE stream without touching the rest of the batch: its lane
-        just goes back to masked (masked lanes never influence occupied
-        lanes' bits, so the survivors' integers are untouched)."""
-        s = self.active.pop(slot)
-        s.error = reason
-        self.quarantined.append(s)
-        self._count_quarantine(self._reason_kind(reason))
-
     def admit(self, pending: list) -> None:
         """Drain ``pending`` (in place) into free slots, quarantining
         malformed streams instead of raising — the graceful bulk-admission
@@ -723,11 +754,10 @@ class SensorFleetEngine:
 
         A rejected stream is counted ONCE, by ``submit``'s boundary
         counters (``fleet/submit_rejected/*``); admit only adds
-        ``fleet/admit_rejected_total`` (its own disposition count) and
-        never touches the quarantine counters, which are reserved for
-        mid-flight corruption (see ``_count_quarantine``).  The head that
-        fits is admitted as one ``submit_many`` batch under a
-        ``fleet/admit`` span; an engine-full stop keeps the rest."""
+        ``fleet/admit_rejected_total``, its own disposition count (see the
+        module docstring's rejection counters).  The head that fits is
+        admitted as one ``submit_many`` batch under a ``fleet/admit`` span;
+        an engine-full stop keeps the rest."""
         m = self.obs
         m.gauge("fleet/admit_queue_depth", len(pending))
         try:
@@ -749,6 +779,11 @@ class SensorFleetEngine:
     def step(self) -> None:
         """One batched kernel call: advance every active slot ``t_step``.
 
+        The input is one gather from the staging at each lane's cursor
+        (masked lanes run on zeros) and the top-layer output goes back in
+        one scatter; a finished stream then gets its own copies of its
+        outputs and final state, and its slot is freed.
+
         Instrumented (no-op while observability is disabled): counts/timers
         only — nothing here reads or converts the traced arrays, so the
         integers are identical with metrics and tracing fully enabled.
@@ -760,24 +795,21 @@ class SensorFleetEngine:
         with m.time("fleet/step_us"), \
                 tr.span("fleet/step", active=len(self.active)):
             with tr.span("fleet/assemble"):
-                for slot in list(self.active):
-                    reason = self._poison_reason(self.active[slot])
-                    if reason is not None:
-                        self._quarantine(slot, reason)
                 if not self.active:
                     return
-                t_step = self._pick_t_step()
-                occupied = len(self.active)
+                lanes = np.flatnonzero(self._on)
+                cur = self._cur[lanes]
+                shortest = int((self._len[lanes] - cur).min())
+                t_step = next(b for b in self._buckets if b <= shortest)
+                occupied = len(lanes)
                 m.gauge("fleet/slot_occupancy", occupied / self.slots)
                 # t_step buckets are a deterministic function of the schedule —
                 # edges at the power-of-two buckets the jit specialises on
                 m.observe("fleet/t_step", t_step,
                           edges=[float(b) for b in sorted(self._buckets)])
+                cols = cur[:, None] + self._offsets[:t_step]
                 x = np.zeros((self.slots, t_step, self.n_in), np.int32)
-                mask = np.zeros((self.slots,), bool)
-                for slot, s in self.active.items():
-                    x[slot] = s.qxs[s.cursor : s.cursor + t_step]
-                    mask[slot] = True
+                x[lanes] = self._x_stage[lanes[:, None], cols]
 
             # jax is async: the dispatch returns at once, and the host
             # blocks on the device in fleet/wait
@@ -785,11 +817,11 @@ class SensorFleetEngine:
                 if self._arity == 1:
                     seq, self._qh = self._step(
                         self._ws, self._bs, jnp.asarray(x), self._qh,
-                        jnp.asarray(mask))
+                        jnp.asarray(self._on))
                 else:
                     seq, self._qh, self._qc = self._step(
                         self._ws, self._bs, jnp.asarray(x), self._qh, self._qc,
-                        jnp.asarray(mask))
+                        jnp.asarray(self._on))
             self.steps_run += 1
             self.timesteps_run += t_step
             m.inc("fleet/steps_total")
@@ -799,24 +831,32 @@ class SensorFleetEngine:
             with tr.span("fleet/wait"):
                 seq_np = np.asarray(seq)
             with tr.span("fleet/harvest"):
-                finished = []
+                self._h_stage[lanes[:, None], cols] = seq_np[lanes]
+                cur += t_step
+                self._cur[lanes] = cur
+                curs = self._cur.tolist()
                 for slot, s in self.active.items():
-                    s.h_seq[s.cursor : s.cursor + t_step] = seq_np[slot]
-                    s.cursor += t_step
-                    if s.remaining == 0:
-                        finished.append(slot)
-                if finished:
-                    qh_np = np.asarray(self._qh)
-                    qc_np = None if self._qc is None else np.asarray(self._qc)
+                    s.cursor = curs[slot]
+                fin = lanes[cur == self._len[lanes]]
+                if len(fin):
+                    self._on[fin] = False
+                    # one gather each, into fresh arrays: the next stream in
+                    # the slot reuses the staging
+                    h_seqs = self._h_stage[fin]
+                    qh = np.asarray(self._qh)[:, fin].swapaxes(0, 1)
+                    qc = (itertools.repeat(None) if self._qc is None
+                          else np.asarray(self._qc)[:, fin].swapaxes(0, 1))
+                    if self.n_layers == 1:      # back-compat: (H,) for L=1
+                        qh = qh[:, 0]
+                        if self._qc is not None:
+                            qc = qc[:, 0]
                     t_done = time.perf_counter()
-                    for slot in finished:
+                    for j, (slot, n, s_qh, s_qc) in enumerate(zip(
+                            fin.tolist(), self._len[fin].tolist(), qh, qc)):
                         s = self.active.pop(slot)   # slot freed for the next
-                        if self.n_layers == 1:      # back-compat: (H,) for L=1
-                            s.qh = qh_np[0, slot].copy()
-                            s.qc = None if qc_np is None else qc_np[0, slot].copy()
-                        else:
-                            s.qh = qh_np[:, slot].copy()
-                            s.qc = None if qc_np is None else qc_np[:, slot].copy()
+                        s.h_seq = h_seqs[j, :n]
+                        s.qh = s_qh
+                        s.qc = s_qc
                         s.t_done = t_done
                         s.done = True
                     # freed slots must show immediately: between steps the
@@ -852,18 +892,22 @@ class SensorFleetEngine:
     def checkpoint_payload(self) -> tuple[dict, dict]:
         """``(tree, extra)`` for ``repro.checkpoint``: the array pytree
         (state carry + per-stream buffers, see checkpoint.py's serving-state
-        layout) and the JSON side-car (slot table, geometry, counters)."""
+        layout; each active stream's inputs and outputs so far copied from
+        the staging) and the JSON side-car (slot table, geometry,
+        counters)."""
         streams: dict[str, dict] = {}
         table: dict[str, dict] = {}
         for slot, s in self.active.items():
-            leaf = {"qxs": np.asarray(s.qxs, np.int32),
-                    "h_seq": np.asarray(s.h_seq, np.int32)}
+            # the integers the kernel reads and writes: the staging's
+            n = int(self._len[slot])
+            leaf = {"qxs": self._x_stage[slot, :n].copy(),
+                    "h_seq": self._h_stage[slot, :n].copy()}
             if s.qh0 is not None:
                 leaf["qh0"] = np.asarray(s.qh0, np.int32)
             if s.qc0 is not None:
                 leaf["qc0"] = np.asarray(s.qc0, np.int32)
             streams[str(slot)] = leaf
-            table[str(slot)] = {"rid": s.rid, "cursor": s.cursor}
+            table[str(slot)] = {"rid": s.rid, "cursor": int(self._cur[slot])}
         tree = {"qh": self._qh, "streams": streams}
         if self._qc is not None:
             tree["qc"] = self._qc
@@ -1037,19 +1081,26 @@ class SensorFleetEngine:
             eng._qh = jax.device_put(eng._qh, eng._state_sharding)
             if eng._qc is not None:
                 eng._qc = jax.device_put(eng._qc, eng._state_sharding)
+        leaves = tree.get("streams", {})
+        eng._grow(max((len(leaf["qxs"]) for leaf in leaves.values()),
+                      default=0))
         for slot_str, meta in extra["slot_table"].items():
-            leaf = tree.get("streams", {})[slot_str]
-            # np.array (not asarray): npz-restored buffers arrive read-only
-            # and h_seq keeps being written as chunks land
+            leaf = leaves[slot_str]
+            slot, n = int(slot_str), len(leaf["qxs"])
+            eng._x_stage[slot, :n] = leaf["qxs"]
+            eng._h_stage[slot, :n] = leaf["h_seq"]
+            eng._cur[slot] = int(meta["cursor"])
+            eng._len[slot] = n
+            eng._on[slot] = True
             s = SensorStream(rid=int(meta["rid"]),
                              qxs=np.array(leaf["qxs"], np.int32))
             s.cursor = int(meta["cursor"])
-            s.h_seq = np.array(leaf["h_seq"], np.int32)
+            s.h_seq = eng._h_stage[slot, :n]
             if "qh0" in leaf:
                 s.qh0 = np.array(leaf["qh0"], np.int32)
             if "qc0" in leaf:
                 s.qc0 = np.array(leaf["qc0"], np.int32)
-            eng.active[int(slot_str)] = s
+            eng.active[slot] = s
         counters = extra.get("counters", {})
         eng.steps_run = int(counters.get("steps_run", 0))
         eng.timesteps_run = int(counters.get("timesteps_run", 0))

@@ -1,12 +1,14 @@
 """Fault tolerance (ISSUE 6): every injected failure — kill between steps,
-torn checkpoint write, flaky checkpoint I/O, poison input at submit or
-mid-flight — must either recover bit-identically or fail exactly one
-stream, never the fleet.
+torn checkpoint write, flaky checkpoint I/O, poison input at submit — must
+either recover bit-identically or fail exactly one stream, never the
+fleet; corruption of an admitted stream's arrays must not reach the kernel
+at all.
 
 The multi-device half (restore onto D′ ≠ D devices) lives in
 ``tests/spmd_scripts/check_fleet_restore.py`` via ``test_spmd.py``; this
-module is the single-process battery: boundary validation, quarantine,
-retry-with-backoff, torn-write fallback, and kill→restore bit-identity.
+module is the single-process battery: boundary validation, quarantine at
+admission, isolation of admitted streams, retry-with-backoff, torn-write
+fallback, and kill→restore bit-identity.
 """
 
 import numpy as np
@@ -85,52 +87,29 @@ def _assert_matches_golden(got_by_rid, golden, *, require_all=False):
 
 
 # ---------------------------------------------------------------------------
-# Submit-boundary validation: one unit test per rejection reason
+# Submit-boundary validation: one case per rejection reason
 # ---------------------------------------------------------------------------
 
+# each poison kind with the error submit raises for it; an overflow's codes
+# were quantised to a different format, and int32 would wrap where the
+# datapath saturates, so it is rejected at the door
+REJECTIONS = {
+    "nan": (ValueError, "non-finite"),
+    "inf": (ValueError, "non-finite"),
+    "float": (TypeError, "quantise"),
+    "wrong_width": (ValueError, rf"want \(T, {N_IN}\)"),
+    "wrong_ndim": (ValueError, "want"),
+    "empty": (ValueError, "empty"),
+    "overflow": (ValueError, "fixed-point range"),
+}
 
-def test_submit_rejects_nan_input():
+
+@pytest.mark.parametrize("kind", POISON_KINDS)
+def test_submit_rejects_poison(kind):
+    exc, match = REJECTIONS[kind]
     eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match="non-finite"):
-        eng.submit(poison_stream("nan", N_IN, FMT))
-
-
-def test_submit_rejects_inf_input():
-    eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match="non-finite"):
-        eng.submit(poison_stream("inf", N_IN, FMT))
-
-
-def test_submit_rejects_unquantised_float():
-    eng = _engine(*_stack_setup())
-    with pytest.raises(TypeError, match="quantise"):
-        eng.submit(poison_stream("float", N_IN, FMT))
-
-
-def test_submit_rejects_wrong_feature_width():
-    eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match=rf"want \(T, {N_IN}\)"):
-        eng.submit(poison_stream("wrong_width", N_IN, FMT))
-
-
-def test_submit_rejects_wrong_ndim():
-    eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match="want"):
-        eng.submit(poison_stream("wrong_ndim", N_IN, FMT))
-
-
-def test_submit_rejects_empty_stream():
-    eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match="empty"):
-        eng.submit(poison_stream("empty", N_IN, FMT))
-
-
-def test_submit_rejects_fixed_point_overflow():
-    """Codes beyond the (x, y) range were quantised to a different format —
-    int32 would wrap where the datapath saturates, so reject at the door."""
-    eng = _engine(*_stack_setup())
-    with pytest.raises(ValueError, match="fixed-point range"):
-        eng.submit(poison_stream("overflow", N_IN, FMT))
+    with pytest.raises(exc, match=match):
+        eng.submit(poison_stream(kind, N_IN, FMT))
 
 
 def test_submit_rejects_float_initial_state():
@@ -151,7 +130,8 @@ def test_rejection_happens_before_slot_allocation():
 
 
 # ---------------------------------------------------------------------------
-# Quarantine: one poison stream fails alone
+# Isolation: a poison stream fails alone at admission; once admitted, a
+# stream is served from the engine's staging, out of the caller's reach
 # ---------------------------------------------------------------------------
 
 
@@ -181,24 +161,78 @@ def test_admission_quarantines_poison_keeps_healthy_streams_exact(tmp_path):
 
 
 def test_mid_flight_poison_quarantined_without_touching_other_lanes():
-    """A caller corrupting an ADMITTED stream's buffers under the engine:
-    that stream alone is quarantined; every other stream's integers are
-    unchanged."""
+    """A caller corrupting an ADMITTED stream under the engine
+    (``poison_mid_flight`` swaps its ``qxs`` for a wrong-shape array; here
+    its ``h_seq`` and ``cursor`` are clobbered too) cannot reach the kernel:
+    the engine serves the copy it staged at the claim, so the stream
+    completes integer-equal to the golden of its claim-time input, every
+    other stream is unchanged, and nothing is quarantined or counted."""
+    from repro.obs.metrics import MetricsRegistry
+
     qps, luts = _stack_setup()
     lens = [12, 14, 10, 16]
     golden = _golden(qps, luts, lens)
     streams = _make_streams(lens, n_layers=1, with_state=(1,))
-    eng = _engine(qps, luts)
+    reg = MetricsRegistry()
+    eng = _engine(qps, luts, metrics=reg)
     for s in streams:
         assert eng.submit(s)
     eng.step()
     poison_mid_flight(streams[2], N_IN)      # corrupt qxs shape mid-flight
+    streams[2].h_seq = None
+    streams[2].cursor = -7
     while eng.active:
         eng.step()
-    assert streams[2] in eng.quarantined
-    assert "corrupted" in streams[2].error and not streams[2].done
-    survivors = {s.rid: s for s in streams if s.rid != 2}
-    assert _assert_matches_golden(survivors, golden) == len(lens) - 1
+    assert eng.quarantined == []
+    assert all(s.done and s.error is None for s in streams)
+    assert streams[2].cursor == lens[2]      # the mirror, restored by a step
+    assert _assert_matches_golden({s.rid: s for s in streams}, golden,
+                                  require_all=True) == len(lens)
+    assert not any(k.startswith("fleet/quarantined")
+                   for k in reg.snapshot()["counters"])
+
+
+def test_in_place_input_overwrite_after_claim_serves_claim_time_input():
+    """A caller that writes into the very ``qxs`` array it submitted, after
+    the claim and again mid-flight, still gets the integers of the input
+    as it was at the claim: the claim copied it into the staging."""
+    qps, luts = _stack_setup(2)
+    lens = [9, 13, 6]
+    golden = _golden(qps, luts, lens)
+    streams = _make_streams(lens, n_layers=2, with_state=(1,))
+    for s in streams:
+        s.qxs = np.array(s.qxs)              # writable, and passed as is
+    eng = _engine(qps, luts)
+    eng.admit(list(streams))
+    assert all(s.qxs is not None and s.h_seq is not None for s in streams)
+    for s in streams:
+        s.qxs[:] = FMT.qmax
+    eng.step()
+    for s in streams:
+        s.qxs[:] = FMT.qmin
+    eng.run([])
+    assert _assert_matches_golden({s.rid: s for s in streams}, golden,
+                                  require_all=True) == len(lens)
+
+
+@pytest.mark.parametrize("n_layers", [1, 2])
+def test_finished_results_do_not_alias_the_staging(n_layers):
+    """A finished stream's ``h_seq``/``qh``/``qc`` are its own arrays: on a
+    one-slot engine every later stream reuses the slot's staging, and the
+    earlier results still equal the golden afterwards."""
+    qps, luts = _stack_setup(n_layers)
+    lens = [4, 6, 8, 5]
+    golden = _golden(qps, luts, lens)
+    streams = _make_streams(lens, n_layers=n_layers, with_state=(1,))
+    eng = _engine(qps, luts, batch_slots=1)
+    eng.run(streams)
+    assert all(s.done for s in streams)
+    for s in streams:
+        for a in (s.h_seq, s.qh, s.qc):
+            assert not np.shares_memory(a, eng._h_stage)
+            assert not np.shares_memory(a, eng._x_stage)
+    assert _assert_matches_golden({s.rid: s for s in streams}, golden,
+                                  require_all=True) == len(lens)
 
 
 # ---------------------------------------------------------------------------

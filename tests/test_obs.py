@@ -350,11 +350,12 @@ def test_engine_bit_identical_with_and_without_obs():
 
 
 def test_engine_quarantine_counts_by_reason():
-    """The single-count rejection contract (see ``_count_quarantine``):
-    a stream malformed at the submit boundary counts ONCE under
-    ``fleet/submit_rejected/*`` — admit() adds its own disposition count
-    but never inflates the quarantine counters, which are reserved for
-    mid-flight corruption."""
+    """The single-count rejection contract (see the ``lstm_engine`` module
+    docstring): a stream malformed at the submit boundary counts ONCE
+    under ``fleet/submit_rejected/*`` — admit() adds its own disposition
+    count; and corruption of an admitted stream moves no counter at all,
+    because the engine serves its staged copy: the victim completes with
+    the integers of its claim-time input."""
     qps, luts = _qps(), make_lut_pair(64)
     reg = MetricsRegistry()
     eng = _engine(qps, luts, metrics=reg)
@@ -371,19 +372,24 @@ def test_engine_quarantine_counts_by_reason():
     assert good[0].done
     assert eng.quarantined == [bad] and bad.error
 
-    # mid-flight corruption: quarantine counters only (by reason kind)
+    # mid-flight corruption: out of the kernel's reach, nothing counted
     from repro.serving.faults import poison_mid_flight
     eng2 = _engine(qps, luts, metrics=(reg2 := MetricsRegistry()))
     victim, survivor = _streams([8, 8], seed=1)
+    clean = _streams([8, 8], seed=1)
+    _engine(qps, luts).run(clean)
     eng2.admit([victim, survivor])
     eng2.step()
     poison_mid_flight(victim, N_IN)
     eng2.run([])
     snap2 = reg2.snapshot()["counters"]
-    assert snap2["fleet/quarantined_total"] == 1
-    assert snap2["fleet/quarantined/qxs_shape"] == 1
+    assert not any(k.startswith("fleet/quarantined") for k in snap2)
     assert snap2.get("fleet/submit_rejected_total", 0) == 0
-    assert survivor.done
+    assert eng2.quarantined == [] and victim.done and survivor.done
+    for a, b in zip((victim, survivor), clean):
+        np.testing.assert_array_equal(a.h_seq, b.h_seq)
+        np.testing.assert_array_equal(a.qh, b.qh)
+        np.testing.assert_array_equal(a.qc, b.qc)
 
 
 def test_slot_occupancy_gauge_updates_when_slots_free():
@@ -414,6 +420,7 @@ PARENT = {"fleet/enqueue": {None}, "fleet/ingest": {None},
           "fleet/admit": {None}, "fleet/submit": {None}, "fleet/step": {None},
           "fleet/validate": {"fleet/enqueue", "fleet/validate"} | DRAINS,
           "fleet/claim": DRAINS, "fleet/admit_write": DRAINS,
+          "fleet/stage": {"fleet/claim"},
           "fleet/assemble": {"fleet/step"}, "fleet/dispatch": {"fleet/step"},
           "fleet/wait": {"fleet/step"}, "fleet/harvest": {"fleet/step"}}
 
@@ -458,12 +465,12 @@ def _parents(spans):
 def _check_span_tree(spans, streams, drain):
     """Every span under its expected parent; request spans carry their rid,
     drain spans their stream counts: per ``drain`` span one ``fleet/claim``
-    and, when it admitted streams, one ``fleet/admit_write`` with the same
-    ``streams``, no more than its ``fleet/validate`` checked; the writes sum
-    to all the streams."""
+    and, when it admitted streams, one ``fleet/admit_write`` and, inside the
+    claim, one ``fleet/stage`` with the same ``streams``, no more than its
+    ``fleet/validate`` checked; the writes sum to all the streams."""
     assert {sp[0] for sp in spans} == set(PARENT) - (DRAINS - {drain}) \
         - ({"fleet/enqueue"} if drain != "fleet/ingest" else set())
-    per_drain = {}
+    per_drain, staged = {}, {}          # staged: claim -> its stage's streams
     for sp, parent in zip(spans, _parents(spans)):
         name, _, _, stats = sp
         pname = parent[0] if parent else None
@@ -475,6 +482,9 @@ def _check_span_tree(spans, streams, drain):
                 assert stats["rid"] == parent[3]["rid"], (sp, parent)
             if pname == "fleet/validate":
                 assert isinstance(parent[3].get("streams"), int), (sp, parent)
+        if name == "fleet/stage":
+            assert id(parent) not in staged, (sp, parent)
+            staged[id(parent)] = stats["streams"]
         if pname in DRAINS:
             assert pname == drain, (sp, parent)
             assert isinstance(stats.get("streams"), int), sp
@@ -489,6 +499,9 @@ def _check_span_tree(spans, streams, drain):
         assert seen["fleet/claim"] <= seen["fleet/validate"], seen
     assert sum(seen.get("fleet/admit_write", 0)
                for seen in per_drain.values()) == len(streams)
+    claims = [sp for sp in spans if sp[0] == "fleet/claim"]
+    assert [staged.get(id(sp), 0) for sp in claims] == [
+        sp[3]["streams"] for sp in claims]
 
 
 def test_profiler_spans_form_the_request_and_step_tree(tmp_path):
@@ -546,6 +559,30 @@ def test_profiler_spans_of_direct_submit_and_a_rejected_stream(tmp_path):
     own = [sp[3]["rid"] for sp, parent in zip(spans, _parents(spans))
            if sp[0] == "fleet/validate" and parent[0] == "fleet/validate"]
     assert own == [99]
+
+
+def test_staging_counter_gauge_and_one_stage_span_per_drain(tmp_path):
+    """``fleet/staged_timesteps_total`` counts the timesteps the claims
+    copied into the staging (every admitted stream's length, once),
+    ``fleet/stage_capacity`` holds ``cap`` (17 steps: 32), and each drain
+    that admitted streams holds one ``fleet/stage`` inside its
+    ``fleet/claim``, with the streams of its ``fleet/admit_write``."""
+    qps, luts = _qps(), make_lut_pair(64)
+    lens = [5, 9, 3, 7, 6, 4, 17]
+    streams = _streams(lens)
+    reg = MetricsRegistry()
+    eng = _engine(qps, luts, metrics=reg)             # 4 slots, chunk 4
+    spans = _profiled(tmp_path, lambda: eng.run(streams))
+    assert all(s.done for s in streams)
+    snap = reg.snapshot()
+    assert snap["counters"]["fleet/staged_timesteps_total"] == sum(lens)
+    assert snap["gauges"]["fleet/stage_capacity"] == 32 == eng._cap
+    stages = [(sp, parent) for sp, parent in zip(spans, _parents(spans))
+              if sp[0] == "fleet/stage"]
+    writes = [sp[3]["streams"] for sp in spans if sp[0] == "fleet/admit_write"]
+    assert [sp[3]["streams"] for sp, _ in stages] == writes
+    assert all(parent[0] == "fleet/claim" for _, parent in stages)
+    assert len(stages) == snap["counters"]["fleet/admit_writes_total"] > 1
 
 
 def test_fleet_golden_integer_equal_with_profiler_spans(tmp_path):

@@ -652,7 +652,11 @@ def corrupt(s, kind, cell, n_layers):
 def per_stream_checks(eng):
     """Make ``eng`` check every stream of a drain with ``validate_stream``:
     the reference the one-pass drain check must equal."""
-    eng._check_drain = lambda head: [eng._validated(s) for s in head]
+    def check(head):
+        out = [eng._validated(s) for s in head]
+        return out, eng._by_length(out)
+
+    eng._check_drain = check
     return eng
 
 
@@ -738,6 +742,33 @@ def test_drain_check_equals_per_stream_validation(cell, n_layers, kind):
         [] if CORRUPTIONS[kind] is None else [5])
     assert fast["calls"] == {"none": [], "out_of_range": [0, 1, 2, 3, 4]
                              }.get(kind, [2])
+
+
+@pytest.mark.parametrize("cell,n_layers", EQUIV_CELLS)
+def test_staging_grows_mid_flight(cell, n_layers):
+    """Ragged streams, longer ones joining while others are in flight: the
+    staging grows (cap 4 -> 8 at the first drain, then 16 and 32 with
+    streams mid-flight) without disturbing the streams it holds, whose
+    ``h_seq`` views follow it, and every stream equals its run alone."""
+    qps, luts = _cell_stack(cell, n_layers)
+    eng = SensorFleetEngine(qps, FMT, luts, batch_slots=3, chunk=4,
+                            backend="fxp")
+    assert eng._cap == 4
+    streams = _stateful_streams(cell, n_layers, [7, 3, 5, 11, 26, 9])
+    pending, grown = list(streams), []  # (new cap, a stream was mid-flight)
+    while pending or eng.active:
+        cap = eng._cap
+        eng.admit(pending)
+        if eng._cap != cap:
+            grown.append((eng._cap,
+                          any(s.cursor for s in eng.active.values())))
+        for s in eng.active.values():
+            assert np.shares_memory(s.h_seq, eng._h_stage)
+        eng.step()
+    assert grown == [(8, False), (16, True), (32, True)]
+    for s in streams:
+        assert s.done and s.cursor == len(s.qxs)
+        _assert_equals_solo(cell, qps, luts, s)
 
 
 @pytest.mark.parametrize("cell,n_layers", EQUIV_CELLS)
