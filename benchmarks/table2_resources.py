@@ -33,12 +33,9 @@ def run():
     # (b) TPU: Pallas kernel VMEM working sets (paper model + LM tiles)
     f32 = 4
     lstm_seq = (6 * 1 + 2 * 21 * 20 + 4 * 21 * 20 + 4 * 20) * f32 * 128  # block_b=128
-    lut = (256 + 256 * 128) * f32
-    fxp_mm = (128 * 512 + 512 * 128 + 128 * 128) * 4
     ssd = (128 * 64 + 128 * 128 + 2 * 128 * 128 + 64 * 128) * f32
     budget = 64 * 2 ** 20
-    for name, bytes_ in [("lstm_sequence", lstm_seq), ("lut_act", lut),
-                         ("fxp_matmul", fxp_mm), ("ssd_scan_tile", ssd)]:
+    for name, bytes_ in [("lstm_sequence", lstm_seq), ("ssd_scan_tile", ssd)]:
         rows.append({
             "name": f"table2/vmem_{name}", "us_per_call": 0.0,
             "derived": f"working_set={bytes_/1024:.1f}KiB "

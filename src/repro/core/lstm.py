@@ -17,8 +17,7 @@ Three functionally-identical cell implementations live here:
 * ``lstm_cell_fused`` — the paper's optimisation C1+C2 adapted to TPU: the
   four gate weight matrices are stacked into one ``(n_i+n_h, 4 n_h)`` operand
   so a single MXU matmul computes all four gates "in parallel", and the
-  elementwise state update (3.4)/(3.5) fuses behind it (one kernel, no HBM
-  round-trip; see ``repro.kernels.lstm_step`` for the Pallas version).
+  elementwise state update (3.4)/(3.5) fuses behind it.
 * ``lstm_cell_fxp`` — the full quantised inference path: ``(x, y)`` fixed
   point (C4) + shared LUT activations (C3), exactly the arithmetic the
   bitstream executes.
@@ -34,46 +33,36 @@ Backend matrix
 ``recurrent_forward(spec, params, xs, backend=...)`` is the single
 cell-generic entry point every workload (models, examples, benchmarks)
 selects a datapath through; ``lstm_forward`` / ``gru_forward`` are its
-per-cell faces (``lstm_forward`` keeps the historical signature exactly).
-The backend registry ``RECURRENT_BACKENDS`` (== ``LSTM_BACKENDS``) is shared
-by every cell; per row below, "cells" says which cell kinds the backend
-serves:
+per-cell faces.  The backend registry ``RECURRENT_BACKENDS`` (==
+``LSTM_BACKENDS``) is shared by every cell, and every backend serves both
+cell kinds (LSTM and GRU).  One implementation per role:
 
-======================  ==============================  =======  =========================
-backend                 executes                        cells    exactness contract
-======================  ==============================  =======  =========================
-``"sequential"``        separate gate mat-vecs,         both     numerical oracle for the
-                        ``lax.scan`` over t                      float path (Fig. 3
-                                                                 baseline schedule)
-``"fused"``             1 stacked matmul/step (C1+C2),  both     allclose to sequential
-                        ``lax.scan`` over t                      (same float ops, fused)
-``"pallas"``            ``lstm_step_pallas`` per step   LSTM     allclose to ``"fused"``;
-                        inside ``lax.scan`` (per-step            per-step HBM round-trip —
-                        HBM traffic: the bottleneck)             kept as the profiling foil
-``"pallas_seq"``        ``lstm_sequence_pallas`` — one  LSTM     allclose to ``"fused"``
-                        kernel, weights+state in VMEM            (``ref.lstm_sequence_ref``)
-                        for all n_seq steps (C5)
-``"fxp"``               ``lstm_layer_fxp`` /            both     THE bitstream spec:
-                        ``gru_layer_fxp`` — bit-level            quantised arithmetic,
-                        ``(x, y)`` simulator,                    LUT activations
-                        ``lax.scan`` over t
-``"pallas_fxp"``        ``lstm_sequence_fxp_pallas`` /  both     *integer-equal* to
-                        ``gru_sequence_fxp_pallas`` —            ``"fxp"`` (and to the
-                        C1–C5 in one kernel, int32               ``ref.*_sequence_fxp_ref``
-                        state resident in VMEM                   oracles)
-======================  ==============================  =======  =========================
+======================  ===============  ==============================  =========================
+backend                 role             executes                        exactness contract
+======================  ===============  ==============================  =========================
+``"sequential"``        float oracle     separate gate mat-vecs,         numerical oracle for the
+                                         ``lax.scan`` over t             float path (Fig. 3
+                                                                         baseline schedule)
+``"fused"``             float train      1 stacked matmul/step (C1+C2),  allclose to sequential
+                        path             ``lax.scan`` over t             (same float ops, fused)
+``"fxp"``               fixed-point      ``lstm_layer_fxp`` /            THE bitstream spec:
+                        oracle           ``gru_layer_fxp`` — bit-level   quantised arithmetic,
+                                         ``(x, y)`` simulator,           LUT activations
+                                         ``lax.scan`` over t
+``"pallas_fxp"``        served kernel    ``_rnn_seq_fxp_call`` — C1–C5   *integer-equal* to
+                                         for all layers in one kernel,   ``"fxp"`` (and to the
+                                         int32 state resident in VMEM    ``ref.*_sequence_fxp_ref``
+                                                                         oracles)
+======================  ===============  ==============================  =========================
 
 When to use which: train with ``"fused"`` (differentiable, fast on any
-backend); validate quantisation with ``"fxp"`` (the readable spec); serve the
-quantised model with ``"pallas_fxp"`` (the paper's actual measured datapath —
-throughput path, O(1) HBM traffic in sequence length); use ``"sequential"``
-and ``"pallas"`` only as baselines/foils when reproducing the Fig. 3/Fig. 5
-bottleneck story.  Float backends take float ``xs``; fxp backends take int32
-``xs`` already quantised to ``fmt`` (plus optional ``luts`` from
-``repro.core.lut.make_lut_pair``).  The float Pallas kernels
-(``"pallas"``/``"pallas_seq"``) are LSTM-only: they bake in the ``(h, c)``
-tail, and their role (the per-step-HBM foil and its float C5 fix) is already
-told by the LSTM — arity-1 cells raise ``NotImplementedError`` there.
+backend) and check it against ``"sequential"`` (the Fig. 3 schedule);
+validate quantisation with ``"fxp"`` (the readable spec); serve the quantised
+model with ``"pallas_fxp"`` (the paper's measured datapath — throughput path,
+O(1) HBM traffic in sequence length).  Float backends take float ``xs``; fxp
+backends take int32 ``xs`` already quantised to ``fmt`` (plus optional
+``luts`` from ``repro.core.lut.make_lut_pair``).  Any other backend name
+raises ``ValueError``.
 
 ``time_tile`` (``"pallas_fxp"`` only): by default the kernel stages the whole
 ``(block_b, n_seq, n_in)`` input in one VMEM block, which bounds ``n_seq``.
@@ -89,7 +78,7 @@ return_state="all")`` returns EVERY layer's final ``(h, c)`` as per-layer
 lists (default ``"top"`` keeps the historical top-layer pair), and
 ``h0``/``c0`` accept per-layer lists or a stacked ``(L, ...)`` array — so a
 chunked continuation of a *stacked* LSTM is exact on every backend.  On
-``"pallas_fxp"``, EVERY multi-layer stack fuses into ONE kernel
+``"pallas_fxp"``, EVERY call — one layer or a stack — runs ONE kernel
 (``lstm_sequence_fxp_stack_pallas``) — heterogeneous hidden sizes are padded
 to ``max_l H_l`` with in-kernel lane masking, so there is no layer-by-layer
 fallback: the per-step loop chains the layers, keeping the inter-layer
@@ -570,25 +559,15 @@ def gru_layer_fxp(
 
 
 # ---------------------------------------------------------------------------
-# Unified dispatcher: one API, six datapaths (see module docstring matrix)
+# Unified dispatcher: one API, four datapaths (see module docstring matrix)
 # ---------------------------------------------------------------------------
 
-LSTM_BACKENDS = ("sequential", "fused", "pallas", "pallas_seq", "fxp", "pallas_fxp")
+LSTM_BACKENDS = ("sequential", "fused", "fxp", "pallas_fxp")
 
-# The dispatcher is cell-generic; the backend registry is shared.  Arity-1
-# cells (GRU) support every backend except the float Pallas LSTM kernels
-# ("pallas"/"pallas_seq") — recurrent_forward enforces this.
+# The dispatcher is cell-generic; every cell kind serves every backend.
 RECURRENT_BACKENDS = LSTM_BACKENDS
 
 _FXP_BACKENDS = ("fxp", "pallas_fxp")
-_PALLAS_BACKENDS = ("pallas", "pallas_seq", "pallas_fxp")
-
-
-def _gate_major(params: LSTMParams) -> tuple[jax.Array, jax.Array]:
-    """Stacked ``(F, 4H)`` -> gate-major ``(4, F, H)`` (the Pallas layout)."""
-    F, h4 = params.w.shape
-    h = h4 // 4
-    return params.w.reshape(F, 4, h).transpose(1, 0, 2), params.b.reshape(4, h)
 
 
 def _lut_kernel_args(luts: dict | None) -> dict:
@@ -604,89 +583,26 @@ def _lut_kernel_args(luts: dict | None) -> dict:
     )
 
 
-def _forward_one_layer(spec, p, xs, h0, c0, need_seq, backend, fmt, luts,
-                       interpret, block_b, block_h, time_tile):
-    """One layer of one cell kind through one backend.  Returns
+def _forward_one_layer(spec, p, xs, h0, c0, need_seq, backend, fmt, luts):
+    """One layer of one cell kind through one of the ``lax.scan`` backends
+    (``"sequential"``, ``"fused"``, ``"fxp"``).  Returns
     ``(h_seq | None, h_T, c_T)`` — ``c_T`` is ``None`` for arity-1 cells."""
     if spec.kind == "gru":
-        if backend == "sequential" or backend == "fused":
-            cell = gru_cell_sequential if backend == "sequential" else gru_cell_fused
-            out = gru_layer(p, xs, h0, cell=cell, return_sequence=need_seq)
-            return (out[0], out[1], None) if need_seq else (None, out, None)
-
         if backend == "fxp":
             out = gru_layer_fxp(p, xs, fmt, luts, qh0=h0,
                                 return_sequence=need_seq)
-            return (out[0], out[1], None) if need_seq else (None, out, None)
-
-        # pallas_fxp (the float Pallas kernels are LSTM-only; recurrent_forward
-        # rejects them for GRU before we get here).
-        from repro.kernels.lstm_fxp_seq import gru_sequence_fxp_pallas
-
-        B, _, _ = xs.shape
-        h = h0 if h0 is not None else jnp.zeros((B, p.hidden_size), jnp.int32)
-        out = gru_sequence_fxp_pallas(
-            xs, p.w, p.b, h,
-            formats=fmt,
-            return_sequence=need_seq, block_b=block_b, time_tile=time_tile,
-            interpret=interpret,
-            **_lut_kernel_args(luts),
-        )
+        else:
+            cell = gru_cell_sequential if backend == "sequential" else gru_cell_fused
+            out = gru_layer(p, xs, h0, cell=cell, return_sequence=need_seq)
         return (out[0], out[1], None) if need_seq else (None, out, None)
-
-    if backend == "sequential" or backend == "fused":
-        cell = lstm_cell_sequential if backend == "sequential" else lstm_cell_fused
-        out = lstm_layer(p, xs, h0, c0, cell=cell, return_sequence=need_seq)
-        return (out[0], *out[1]) if need_seq else (None, *out)
 
     if backend == "fxp":
         out = lstm_layer_fxp(p, xs, fmt, luts, qh0=h0, qc0=c0,
                              return_sequence=need_seq)
-        return (out[0], *out[1]) if need_seq else (None, *out)
-
-    # Pallas backends operate on (B, T, n_in); kernels are imported lazily so
-    # repro.core stays importable where jax.experimental.pallas is absent.
-    B, _, _ = xs.shape
-    n_h = p.hidden_size
-    zeros = lambda: jnp.zeros(
-        (B, n_h), jnp.int32 if backend == "pallas_fxp" else xs.dtype)
-    h = h0 if h0 is not None else zeros()
-    c = c0 if c0 is not None else zeros()
-
-    if backend == "pallas":
-        from repro.kernels.lstm_step import lstm_step_pallas
-
-        w4, b4 = _gate_major(p)
-
-        def step(carry, x_t):
-            h, c = carry
-            xh = jnp.concatenate([x_t, h], axis=-1)
-            h, c = lstm_step_pallas(xh, w4, b4, c, block_b=block_b,
-                                    block_h=block_h, interpret=interpret)
-            return (h, c), (h if need_seq else None)
-
-        (h, c), seq = jax.lax.scan(step, (h, c), jnp.moveaxis(xs, 1, 0))
-        return (jnp.moveaxis(seq, 0, 1) if need_seq else None), h, c
-
-    if backend == "pallas_seq":
-        from repro.kernels.lstm_step import lstm_sequence_pallas
-
-        w4, b4 = _gate_major(p)
-        out = lstm_sequence_pallas(xs, w4, b4, h, c, block_b=block_b,
-                                   return_sequence=need_seq, interpret=interpret)
-        return out if need_seq else (None, *out)
-
-    # pallas_fxp
-    from repro.kernels.lstm_fxp_seq import lstm_sequence_fxp_pallas
-
-    out = lstm_sequence_fxp_pallas(
-        xs, p.w, p.b, h, c,
-        formats=fmt,
-        return_sequence=need_seq, block_b=block_b, time_tile=time_tile,
-        interpret=interpret,
-        **_lut_kernel_args(luts),
-    )
-    return out if need_seq else (None, *out)
+    else:
+        cell = lstm_cell_sequential if backend == "sequential" else lstm_cell_fused
+        out = lstm_layer(p, xs, h0, c0, cell=cell, return_sequence=need_seq)
+    return (out[0], *out[1]) if need_seq else (None, *out)
 
 
 def recurrent_forward(
@@ -704,7 +620,6 @@ def recurrent_forward(
     num_layers: int | None = None,
     interpret: bool | None = None,
     block_b: int = 128,
-    block_h: int = 128,
     time_tile: int | None = None,
 ):
     """Run a (stacked) gated recurrence of cell kind ``spec`` through one of
@@ -717,17 +632,16 @@ def recurrent_forward(
     params : the spec's param class (``LSTMParams`` / ``GRUParams``) or a
         list of them (one per stacked layer; layer ``l``'s ``input_size``
         must equal layer ``l-1``'s ``hidden_size`` — hidden sizes may differ
-        between layers).  EVERY multi-layer stack on ``"pallas_fxp"`` runs as
-        ONE kernel with the inter-layer hidden sequence resident in VMEM
-        (``*_sequence_fxp_stack_pallas``, which pads heterogeneous ``H``
-        in-kernel); the other backends run layer by layer, where inter-layer
-        traffic is the full hidden-state sequence.
+        between layers).  Every ``"pallas_fxp"`` call, one layer or a
+        stack, runs as ONE kernel with the inter-layer hidden sequence
+        resident in VMEM (``*_sequence_fxp_stack_pallas``, which pads
+        heterogeneous ``H`` in-kernel); the other backends run layer by
+        layer, where inter-layer traffic is the full hidden-state sequence.
     xs : ``(B, n_seq, n_in)`` or ``(n_seq, n_in)``.  Float for the float
         backends; int32 fixed point (already quantised to layer 0's data
         format) for ``"fxp"``/``"pallas_fxp"``.
     backend : one of ``RECURRENT_BACKENDS`` — see the module docstring
-        matrix.  The float Pallas kernels (``"pallas"``/``"pallas_seq"``)
-        are LSTM-only; arity-1 cells raise ``NotImplementedError`` there.
+        matrix; any other name raises ``ValueError``.
     fmt, luts : fixed-point format — ``FxpFormat`` (global), ``LayerFormats``
         (per-gate) or ``StackFormats`` (per-layer + per-gate) — plus optional
         ``make_lut_pair`` tables (fxp backends only).
@@ -744,9 +658,10 @@ def recurrent_forward(
         lists back as ``h0``/``c0`` of the next chunk and the integers match
         one long call.
     num_layers : optional cross-check against ``len(params)``.
-    interpret : Pallas interpret mode; ``None`` = auto (compiled on TPU,
-        interpret elsewhere so every backend runs everywhere).
-    block_b, block_h : Pallas tile sizes.
+    interpret : ``"pallas_fxp"`` only — Pallas interpret mode; ``None`` =
+        auto (compiled on TPU, interpret elsewhere so every backend runs
+        everywhere).
+    block_b : ``"pallas_fxp"`` only — batch rows per kernel grid step.
     time_tile : ``"pallas_fxp"`` only — stream the sequence through VMEM in
         double-buffered ``time_tile``-step chunks (``None`` = whole sequence
         in one block); integer-equal either way.  See the module docstring.
@@ -761,16 +676,10 @@ def recurrent_forward(
     if return_state not in ("top", "all"):
         raise ValueError(
             f"return_state must be 'top' or 'all', got {return_state!r}")
-    if spec.state_arity == 1:
-        if backend in ("pallas", "pallas_seq"):
-            raise NotImplementedError(
-                f"backend {backend!r} (float Pallas LSTM kernels) does not "
-                f"support cell kind {spec.kind!r}; use 'sequential', "
-                "'fused', 'fxp' or 'pallas_fxp'")
-        if c0 is not None:
-            raise ValueError(
-                f"cell kind {spec.kind!r} carries a single hidden state; "
-                "c0 must be None")
+    if spec.state_arity == 1 and c0 is not None:
+        raise ValueError(
+            f"cell kind {spec.kind!r} carries a single hidden state; "
+            "c0 must be None")
 
     layers = list(params) if isinstance(params, (list, tuple)) else [params]
     if num_layers is not None and num_layers != len(layers):
@@ -783,9 +692,9 @@ def recurrent_forward(
     if _m.enabled:
         _m.inc("kernel/dispatch_total")
         _m.inc(f"kernel/dispatch/{spec.kind}/{backend}")
-        if backend in _PALLAS_BACKENDS:
+        if backend == "pallas_fxp":
             _m.inc(f"kernel/blocks/{backend}/"
-                   f"L{len(layers)}_bb{block_b}_bh{block_h}_tt{time_tile}")
+                   f"L{len(layers)}_bb{block_b}_tt{time_tile}")
 
     is_fxp = backend in _FXP_BACKENDS
     stack_fmt = None
@@ -800,16 +709,13 @@ def recurrent_forward(
         # view; the uniform case is bit-identical to the historical path.
         stack_fmt = fxp_mod.as_stack_formats(fmt, len(layers))
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    # The Pallas kernels take a single (B, T, n_in) batch axis; fold extra
-    # leading dims into it (and unfold on the way out) so every backend
-    # accepts the same (..., n_seq, n_in) inputs.
+    # The kernel takes a single (B, T, n_in) batch axis; fold extra leading
+    # dims into it (and unfold on the way out) so every backend accepts the
+    # same (..., n_seq, n_in) inputs.
     xs_ndim = jnp.asarray(xs).ndim      # pre-fold, for state_for's rank check
     squeeze_batch = False
     lead_shape = None
-    if backend in _PALLAS_BACKENDS:
+    if backend == "pallas_fxp":
         if xs.ndim == 2:
             xs, squeeze_batch = xs[None], True
         elif xs.ndim > 3:
@@ -843,11 +749,11 @@ def recurrent_forward(
             return s.reshape(-1, s.shape[-1])
         return s
 
-    # EVERY multi-layer stack on pallas_fxp fuses into ONE kernel — uniform
+    # EVERY pallas_fxp call runs ONE kernel — one layer or a stack, uniform
     # or heterogeneous H, uniform or per-gate/per-layer formats: the per-step
     # loop chains the layers, so the inter-layer hidden-state sequence never
     # bounces through HBM between layers (see kernels/lstm_fxp_seq.py).
-    if backend == "pallas_fxp" and len(layers) > 1:
+    if backend == "pallas_fxp":
         def stacked_state(s):
             if s is None:
                 return None
@@ -856,7 +762,9 @@ def recurrent_forward(
         kernel_kwargs = dict(
             formats=stack_fmt,
             return_sequence=return_sequence, block_b=block_b,
-            time_tile=time_tile, interpret=interpret,
+            time_tile=time_tile,
+            interpret=(jax.default_backend() != "tpu" if interpret is None
+                       else interpret),
             **_lut_kernel_args(luts),
         )
         ws, bs = [p.w for p in layers], [p.b for p in layers]
@@ -888,8 +796,7 @@ def recurrent_forward(
             need_seq = return_sequence or li < len(layers) - 1
             seq, h, c = _forward_one_layer(
                 spec, p, xs, state_for(li, h0), state_for(li, c0), need_seq,
-                backend, None if stack_fmt is None else stack_fmt[li],
-                luts, interpret, block_b, block_h, time_tile)
+                backend, None if stack_fmt is None else stack_fmt[li], luts)
             hs.append(h)
             cs.append(c)
             if need_seq:
@@ -934,15 +841,13 @@ def lstm_forward(
     num_layers: int | None = None,
     interpret: bool | None = None,
     block_b: int = 128,
-    block_h: int = 128,
     time_tile: int | None = None,
 ):
-    """Run a (stacked) LSTM through one of the six backends.
+    """Run a (stacked) LSTM through one of the four backends.
 
-    The LSTM face of :func:`recurrent_forward` — exact signature and
-    behaviour of the historical entry point; see ``recurrent_forward`` for
-    the parameter documentation (with ``spec=LSTM_CELL``, states are
-    ``(h, c)`` pairs and all six backends are available).
+    The LSTM face of :func:`recurrent_forward`; see ``recurrent_forward``
+    for the parameter documentation (with ``spec=LSTM_CELL``, states are
+    ``(h, c)`` pairs).
 
     Returns ``(h_T, c_T)`` (top layer, or per-layer lists with
     ``return_state="all"``), or ``(h_seq, (h_T, c_T))`` when
@@ -953,7 +858,7 @@ def lstm_forward(
         backend=backend, fmt=fmt, luts=luts, h0=h0, c0=c0,
         return_sequence=return_sequence, return_state=return_state,
         num_layers=num_layers, interpret=interpret,
-        block_b=block_b, block_h=block_h, time_tile=time_tile,
+        block_b=block_b, time_tile=time_tile,
     )
 
 
@@ -970,20 +875,18 @@ def gru_forward(
     num_layers: int | None = None,
     interpret: bool | None = None,
     block_b: int = 128,
-    block_h: int = 128,
     time_tile: int | None = None,
 ):
     """Run a (stacked) GRU — the arity-1 face of :func:`recurrent_forward`.
 
     Same conventions as ``lstm_forward`` except the state is a bare ``h``
     (``h_T``, or a per-layer ``[h_T^l...]`` list with ``return_state="all"``)
-    and there is no ``c0``; backends ``"pallas"``/``"pallas_seq"`` (float
-    Pallas LSTM kernels) are not available.
+    and there is no ``c0``.
     """
     return recurrent_forward(
         GRU_CELL, params, xs,
         backend=backend, fmt=fmt, luts=luts, h0=h0,
         return_sequence=return_sequence, return_state=return_state,
         num_layers=num_layers, interpret=interpret,
-        block_b=block_b, block_h=block_h, time_tile=time_tile,
+        block_b=block_b, time_tile=time_tile,
     )

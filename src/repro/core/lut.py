@@ -4,8 +4,9 @@ The paper replaces full-precision ``sigmoid``/``tanh`` with lookup tables of
 depth 64/128/256, instantiated once per function and shared by every
 consumer.  Table 1 of the paper shows depth 256 recovers the full-precision
 MSE.  This module is the pure-jnp reference implementation (also the
-quantisation-simulator path); ``repro.kernels.lut_act`` is the Pallas TPU
-kernel with the table resident in VMEM.
+quantisation-simulator path); the served kernel
+(``repro.kernels.lstm_fxp_seq``) holds both tables in VMEM and gathers from
+them in-kernel with the same index scheme.
 
 Index scheme (matches a BRAM-addressed LUT): the input range ``[lo, hi)`` is
 split into ``depth`` equal bins; an input is clamped into range and mapped to

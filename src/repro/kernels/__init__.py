@@ -2,15 +2,14 @@
 
 kernel            | paper idea            | oracle
 ------------------|-----------------------|---------------------------
-lstm_step.py      | C1+C2 fused cell      | ref.lstm_step_ref
-lstm_step.py(seq) | C5 VMEM-resident scan | ref.lstm_sequence_ref
-lstm_fxp_seq.py   | C1–C5 fused fxp seq   | ref.lstm_sequence_fxp_ref
-lut_act.py        | C3 shared LUT         | ref.lut_act_ref
-fxp_matmul.py     | C4 fixed-point ALU    | ref.fxp_matmul_ref
+lstm_fxp_seq.py   | C1–C5 fused fxp seq   | ref.lstm_sequence_fxp_ref,
+                  | (LSTM and GRU, L >= 1)| ref.gru_sequence_fxp_ref
 ssd_scan.py       | C1/C2/C5 for SSD      | ref.ssd_chunk_scan_ref
 
-All kernels validate in interpret mode on CPU; ``ops.py`` is the public
-dispatch layer.
+The fused fixed-point kernel is the served path
+(``recurrent_forward(..., backend="pallas_fxp")``); C3 (the shared LUT) and
+C4 (the fixed-point ALU) run inside it.  ``ops.py`` dispatches the SSD scan.
+Both kernels validate in interpret mode on CPU.
 """
 
 from repro.kernels import ops, ref  # noqa: F401

@@ -22,7 +22,7 @@ One ``pallas_call`` performs all ``n_seq`` steps of all ``L`` layers:
   contraction), and the fused elementwise tail (C2) — all against
   VMEM-resident tiles;
 * ``h``/``c`` of **every** layer are carried as int32 in VMEM, so HBM traffic
-  for state is O(1) in sequence length, matching ``lstm_sequence_pallas``.
+  for state is O(1) in sequence length.
 
 Multi-layer stacking (``lstm_sequence_fxp_stack_pallas``): a stacked LSTM's
 dataflow lets layer ``l`` consume layer ``l-1``'s hidden state *of the same
@@ -181,7 +181,6 @@ def _rnn_seq_fxp_kernel(
     tanh_step: float,
     tanh_depth: int,
     use_lut: bool,
-    mxu_onehot: bool,
     return_sequence: bool,
 ):
     spec = cell_spec(cell_kind)
@@ -234,18 +233,16 @@ def _rnn_seq_fxp_kernel(
         return sat(jnp.floor(yf * (1 << x_bits) + 0.5).astype(jnp.int32), y_bits)
 
     def gather(table, idx, depth):
-        if mxu_onehot:
-            # One-hot MXU contraction (exact: adding zeros to the hit entry).
-            # HIGHEST keeps the f32 table entries whole; the TPU default
-            # would round them to bf16 before the multiply.
-            iota = jax.lax.broadcasted_iota(jnp.int32, (*idx.shape, depth), idx.ndim)
-            onehot = (iota == idx[..., None]).astype(jnp.float32)
-            return jax.lax.dot_general(
-                onehot, table, (((idx.ndim,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32,
-            )
-        return jnp.take(table, idx, axis=0)
+        # One-hot MXU contraction (exact: adding zeros to the hit entry).
+        # HIGHEST keeps the f32 table entries whole; the TPU default would
+        # round them to bf16 before the multiply.
+        iota = jax.lax.broadcasted_iota(jnp.int32, (*idx.shape, depth), idx.ndim)
+        onehot = (iota == idx[..., None]).astype(jnp.float32)
+        return jax.lax.dot_general(
+            onehot, table, (((idx.ndim,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
 
     def lut_act(q, table, lo, step, depth, in_frac, out_x, out_y):
         x = q.astype(jnp.float32) * (2.0 ** (-in_frac))
@@ -369,14 +366,13 @@ def _rnn_seq_fxp_kernel(
     jax.jit,
     static_argnames=(
         "cell_kind", "fmt_spec", "h_sizes", "sig_lo", "sig_hi", "tanh_lo",
-        "tanh_hi", "return_sequence", "block_b", "time_tile", "mxu_onehot",
-        "interpret",
+        "tanh_hi", "return_sequence", "block_b", "time_tile", "interpret",
     ),
 )
 def _rnn_seq_fxp_call(
     qxs, w4, b4, sig_table, tanh_table, qh0, qc0, *,
     cell_kind, fmt_spec, h_sizes, sig_lo, sig_hi, tanh_lo, tanh_hi,
-    return_sequence, block_b, time_tile, mxu_onehot, interpret,
+    return_sequence, block_b, time_tile, interpret,
 ):
     spec = cell_spec(cell_kind)
     arity, n_gates = spec.state_arity, spec.n_gates
@@ -412,7 +408,7 @@ def _rnn_seq_fxp_call(
         sig_lo=sig_lo, sig_step=(sig_hi - sig_lo) / sig_depth, sig_depth=sig_depth,
         tanh_lo=tanh_lo, tanh_step=(tanh_hi - tanh_lo) / tanh_depth,
         tanh_depth=tanh_depth,
-        use_lut=use_lut, mxu_onehot=mxu_onehot, return_sequence=return_sequence,
+        use_lut=use_lut, return_sequence=return_sequence,
     )
 
     state_spec = lambda: pl.BlockSpec((L, bb, Hp), lambda i, t: (0, i, 0))
@@ -519,7 +515,6 @@ def lstm_sequence_fxp_stack_pallas(
     return_sequence: bool = False,
     block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
-    mxu_onehot: bool = True,
     interpret: bool = False,
 ):
     """Run an ``L``-layer quantised stack in ONE Pallas kernel.
@@ -543,14 +538,14 @@ def lstm_sequence_fxp_stack_pallas(
         formats=formats, frac_bits=frac_bits, total_bits=total_bits,
         sig_lo=sig_lo, sig_hi=sig_hi, tanh_lo=tanh_lo, tanh_hi=tanh_hi,
         return_sequence=return_sequence, block_b=block_b, time_tile=time_tile,
-        mxu_onehot=mxu_onehot, interpret=interpret,
+        interpret=interpret,
     )
 
 
 def _stack_fxp_pallas(
     cell_kind, qxs, qws, qbs, qh0, qc0, sig_table, tanh_table, *,
     formats, frac_bits, total_bits, sig_lo, sig_hi, tanh_lo, tanh_hi,
-    return_sequence, block_b, time_tile, mxu_onehot, interpret,
+    return_sequence, block_b, time_tile, interpret,
 ):
     """Shared cell-generic body of the ``*_sequence_fxp_stack_pallas``
     faces: validate, pack the gate-major layout, pad/stack the state and
@@ -622,7 +617,7 @@ def _stack_fxp_pallas(
         fmt_spec=_fmt_spec(formats, n_gates), h_sizes=tuple(hs_l),
         sig_lo=sig_lo, sig_hi=sig_hi, tanh_lo=tanh_lo, tanh_hi=tanh_hi,
         return_sequence=return_sequence, block_b=block_b, time_tile=time_tile,
-        mxu_onehot=mxu_onehot, interpret=interpret,
+        interpret=interpret,
     )
     if arity == 1:
         if return_sequence:
@@ -662,7 +657,6 @@ def gru_sequence_fxp_stack_pallas(
     return_sequence: bool = False,
     block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
-    mxu_onehot: bool = True,
     interpret: bool = False,
 ):
     """Run an ``L``-layer quantised GRU stack in ONE Pallas kernel — the
@@ -684,7 +678,7 @@ def gru_sequence_fxp_stack_pallas(
         formats=formats, frac_bits=frac_bits, total_bits=total_bits,
         sig_lo=sig_lo, sig_hi=sig_hi, tanh_lo=tanh_lo, tanh_hi=tanh_hi,
         return_sequence=return_sequence, block_b=block_b, time_tile=time_tile,
-        mxu_onehot=mxu_onehot, interpret=interpret,
+        interpret=interpret,
     )
 
 
@@ -706,7 +700,6 @@ def gru_sequence_fxp_pallas(
     return_sequence: bool = False,
     block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
-    mxu_onehot: bool = True,
     interpret: bool = False,
 ):
     """Run the whole quantised GRU recurrence in one Pallas kernel (one
@@ -722,7 +715,7 @@ def gru_sequence_fxp_pallas(
         formats=formats, frac_bits=frac_bits, total_bits=total_bits,
         sig_lo=sig_lo, sig_hi=sig_hi, tanh_lo=tanh_lo, tanh_hi=tanh_hi,
         return_sequence=return_sequence, block_b=block_b, time_tile=time_tile,
-        mxu_onehot=mxu_onehot, interpret=interpret,
+        interpret=interpret,
     )
     if return_sequence:
         h_seq, h = out
@@ -749,7 +742,6 @@ def lstm_sequence_fxp_pallas(
     return_sequence: bool = False,
     block_b: int = DEFAULT_BLOCK_B,
     time_tile: int | None = None,
-    mxu_onehot: bool = True,
     interpret: bool = False,
 ):
     """Run the whole quantised recurrence in one Pallas kernel (one layer).
@@ -777,7 +769,7 @@ def lstm_sequence_fxp_pallas(
         formats=formats, frac_bits=frac_bits, total_bits=total_bits,
         sig_lo=sig_lo, sig_hi=sig_hi, tanh_lo=tanh_lo, tanh_hi=tanh_hi,
         return_sequence=return_sequence, block_b=block_b, time_tile=time_tile,
-        mxu_onehot=mxu_onehot, interpret=interpret,
+        interpret=interpret,
     )
     if return_sequence:
         h_seq, h, c = out

@@ -1,9 +1,10 @@
-"""Public jit'd wrappers over the Pallas kernels.
+"""Public jit'd wrapper over the SSD Pallas kernel.
 
-Every op dispatches between the Pallas kernel (TPU target; interpret mode on
-CPU for validation) and the pure-jnp oracle in ``ref.py``.  The default
-backend policy: on TPU run the compiled kernel, anywhere else run the oracle
-— so models can call these unconditionally and dry-runs lower the jnp path.
+``ssd_chunk_scan`` dispatches between the Pallas kernel (TPU target;
+interpret mode on CPU for validation) and the pure-jnp oracle in ``ref.py``.
+The default backend policy: on TPU run the compiled kernel, anywhere else run
+the oracle — so models can call it unconditionally and dry-runs lower the jnp
+path.
 
 ``impl`` overrides: "pallas" (compiled), "interpret" (kernel body on CPU),
 "ref" (oracle).
@@ -11,74 +12,18 @@ backend policy: on TPU run the compiled kernel, anywhere else run the oracle
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
-from repro.kernels.fxp_matmul import fxp_matmul_pallas
-from repro.kernels.lstm_fxp_seq import lstm_sequence_fxp_pallas
-from repro.kernels.lstm_step import lstm_sequence_pallas, lstm_step_pallas
-from repro.kernels.lut_act import lut_act_pallas
 from repro.kernels.ssd_scan import ssd_chunk_scan_pallas
 
-__all__ = ["lstm_step", "lstm_sequence", "lstm_sequence_fxp", "lut_act",
-           "fxp_matmul", "ssd_chunk_scan"]
+__all__ = ["ssd_chunk_scan"]
 
 
 def _auto_impl(impl: str | None) -> str:
     if impl is not None:
         return impl
     return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
-def lstm_step(xh, w, b, c, impl: str | None = None, **kw):
-    impl = _auto_impl(impl)
-    if impl == "ref":
-        return _ref.lstm_step_ref(xh, w, b, c)
-    return lstm_step_pallas(xh, w, b, c, interpret=(impl == "interpret"), **kw)
-
-
-def lstm_sequence(xs, w, b, h0, c0, impl: str | None = None, **kw):
-    impl = _auto_impl(impl)
-    if impl == "ref":
-        return _ref.lstm_sequence_ref(xs, w, b, h0, c0)
-    return lstm_sequence_pallas(xs, w, b, h0, c0, interpret=(impl == "interpret"), **kw)
-
-
-def lstm_sequence_fxp(qxs, qw, qb, qh0=None, qc0=None, sig_table=None,
-                      tanh_table=None, impl: str | None = None, **kw):
-    """Fused fixed-point sequence (paper C1–C5).  ``kw`` carries the format
-    (``frac_bits``/``total_bits``), LUT bounds, and kernel tiling knobs."""
-    impl = _auto_impl(impl)
-    if impl == "ref":
-        kw.pop("block_b", None)
-        kw.pop("mxu_onehot", None)
-        sig_bounds = (kw.pop("sig_lo", -8.0), kw.pop("sig_hi", 8.0))
-        tanh_bounds = (kw.pop("tanh_lo", -4.0), kw.pop("tanh_hi", 4.0))
-        return _ref.lstm_sequence_fxp_ref(qxs, qw, qb, qh0, qc0, sig_table,
-                                          tanh_table, sig_bounds=sig_bounds,
-                                          tanh_bounds=tanh_bounds, **kw)
-    return lstm_sequence_fxp_pallas(qxs, qw, qb, qh0, qc0, sig_table, tanh_table,
-                                    interpret=(impl == "interpret"), **kw)
-
-
-def lut_act(x, table, lo: float, hi: float, impl: str | None = None, **kw):
-    impl = _auto_impl(impl)
-    if impl == "ref":
-        return _ref.lut_act_ref(x, table, lo, hi)
-    return lut_act_pallas(x, table, lo=lo, hi=hi, interpret=(impl == "interpret"), **kw)
-
-
-def fxp_matmul(a_q, b_q, bias_q=None, frac_bits: int = 8, total_bits: int = 16,
-               impl: str | None = None, **kw):
-    impl = _auto_impl(impl)
-    if impl == "ref":
-        return _ref.fxp_matmul_ref(a_q, b_q, bias_q, frac_bits, total_bits)
-    return fxp_matmul_pallas(a_q, b_q, bias_q, frac_bits=frac_bits,
-                             total_bits=total_bits,
-                             interpret=(impl == "interpret"), **kw)
 
 
 def ssd_chunk_scan(x, a_log, b, c, h0=None, chunk: int = 128,

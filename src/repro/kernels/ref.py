@@ -1,7 +1,18 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles, each restated self-contained from the paper's equations.
 
-Each function is the bit-level specification its kernel is tested against
-(``tests/test_kernels.py`` sweeps shapes/dtypes and asserts allclose).
+Every function here is what a test compares a datapath against:
+
+* ``lstm_sequence_fxp_ref`` / ``gru_sequence_fxp_ref`` — the bit-level spec
+  of the fused fixed-point kernel (``lstm_fxp_seq.py``), integer-equal;
+* ``ssd_chunk_scan_ref`` — the SSD kernel (``ssd_scan.py``), allclose;
+* ``lstm_step_ref`` / ``lstm_sequence_ref`` — the float C1+C2 cell and its
+  scan, against ``repro.core.lstm.lstm_cell_fused`` and
+  ``lstm_forward(backend="fused")``;
+* ``lut_act_ref`` / ``fxp_matmul_ref`` — C3 and C4, against
+  ``repro.core.lut.lut_apply`` / ``lut_apply_fxp`` and
+  ``repro.core.fxp.fxp_matmul``.
+
+``tests/test_kernels.py`` holds those comparisons.
 """
 
 from __future__ import annotations

@@ -1,10 +1,9 @@
 """Cross-backend equivalence property suite (ISSUE 2).
 
-One API, six datapaths: every ``lstm_forward`` backend must agree on every
+One API, four datapaths: every ``lstm_forward`` backend must agree on every
 shape.  Two contract classes:
 
-* float backends (``sequential``, ``fused``, ``pallas``, ``pallas_seq``)
-  agree to float tolerance, pairwise;
+* float backends (``sequential``, ``fused``) agree to float tolerance;
 * fxp backends (``fxp``, ``pallas_fxp`` — un-tiled *and* time-tiled) are
   *integer-equal*, pairwise, including ``n_seq >> time_tile`` (the
   acceptance criterion is n_seq at least 8x the tile), ragged tails, and
@@ -31,10 +30,8 @@ from repro.core.lut import make_lut_pair
 
 RNG = np.random.default_rng(42)
 
-FLOAT_BACKENDS = ("sequential", "fused", "pallas", "pallas_seq")
+FLOAT_BACKENDS = ("sequential", "fused")
 FXP_BACKENDS = ("fxp", "pallas_fxp")
-# GRU has no dedicated float Pallas kernels (see core/lstm.py docstring)
-GRU_FLOAT_BACKENDS = ("sequential", "fused")
 
 
 def _setup(n_in, n_h, t, b, key=0):
@@ -126,8 +123,7 @@ def test_fxp_backends_integer_equal_without_luts():
 @pytest.mark.parametrize("n_seq,n_h,b", [(7, 20, 3), (26, 33, 2)])
 def test_float_backends_allclose_pairwise(n_seq, n_h, b):
     params, xs = _setup(2, n_h, n_seq, b)
-    outs = {be: lstm_forward(params, xs, backend=be, block_b=2, block_h=8)
-            for be in FLOAT_BACKENDS}
+    outs = {be: lstm_forward(params, xs, backend=be) for be in FLOAT_BACKENDS}
     for be in FLOAT_BACKENDS[1:]:
         for a, o in zip(outs[FLOAT_BACKENDS[0]], outs[be]):
             np.testing.assert_allclose(np.asarray(a), np.asarray(o),
@@ -135,19 +131,26 @@ def test_float_backends_allclose_pairwise(n_seq, n_h, b):
 
 
 def test_all_six_backends_one_shape():
-    """The full backend matrix on one shape: every backend produces the right
-    shape; float family allclose, fxp family integer-equal."""
+    """The full backend matrix (all four backends) on one shape: every
+    backend produces the right shape; float family allclose, fxp family
+    integer-equal."""
     fmt = FxpFormat(8, 16)
     params, xs = _setup(2, 20, 16, 3)
     qp, qxs = _quantized(params, xs, fmt)
     luts = make_lut_pair(128)
+    outs = {}
     for be in LSTM_BACKENDS:
         if be in FXP_BACKENDS:
-            h, c = lstm_forward(qp, qxs, backend=be, fmt=fmt, luts=luts,
-                                block_b=2, time_tile=4 if be == "pallas_fxp" else None)
+            outs[be] = lstm_forward(
+                qp, qxs, backend=be, fmt=fmt, luts=luts, block_b=2,
+                time_tile=4 if be == "pallas_fxp" else None)
         else:
-            h, c = lstm_forward(params, xs, backend=be, block_b=2, block_h=8)
+            outs[be] = lstm_forward(params, xs, backend=be)
+        h, c = outs[be]
         assert h.shape == (3, 20) and c.shape == (3, 20), be
+    for a, o in zip(outs["sequential"], outs["fused"]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(o), atol=1e-5)
+    _assert_int_equal_pairwise({be: outs[be] for be in FXP_BACKENDS})
 
 
 def test_time_tiled_multi_layer_stack_integer_equal():
@@ -394,10 +397,10 @@ def test_gru_fxp_backends_integer_equal_with_sequence(n_seq, n_h, b, tile):
 def test_gru_float_backends_allclose_pairwise(n_seq, n_h, b):
     params, xs = _gru_setup(2, n_h, n_seq, b)
     outs = {be: gru_forward(params, xs, backend=be)
-            for be in GRU_FLOAT_BACKENDS}
-    for be in GRU_FLOAT_BACKENDS[1:]:
+            for be in FLOAT_BACKENDS}
+    for be in FLOAT_BACKENDS[1:]:
         np.testing.assert_allclose(
-            np.asarray(outs[GRU_FLOAT_BACKENDS[0]]), np.asarray(outs[be]),
+            np.asarray(outs[FLOAT_BACKENDS[0]]), np.asarray(outs[be]),
             atol=1e-5, err_msg=be)
 
 
@@ -499,8 +502,6 @@ def test_property_float_backends_allclose(n_seq, n_h, n_in, b):
     params = init_lstm_params(jax.random.PRNGKey(1), n_in, n_h)
     xs = jnp.asarray(rng.normal(size=(b, n_seq, n_in)).astype(np.float32))
     ref = lstm_forward(params, xs, backend="fused")
-    for be in ("sequential", "pallas_seq"):
-        out = lstm_forward(params, xs, backend=be, block_b=2, block_h=8)
-        for a, o in zip(ref, out):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(o),
-                                       atol=1e-5, err_msg=be)
+    out = lstm_forward(params, xs, backend="sequential")
+    for a, o in zip(ref, out):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(o), atol=1e-5)

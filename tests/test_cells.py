@@ -10,8 +10,8 @@ Two contract classes:
 * **GRU exactness** — the fxp GRU is integer-equal to
   ``kernels.ref.gru_sequence_fxp_ref`` through every face of the stack:
   the simulator, PTQ (``quantize_lstm_model``), QAT -> freeze, and the
-  backend dispatcher (unsupported float-Pallas backends refuse loudly,
-  the single-state cell rejects ``c0``).
+  backend dispatcher (an unregistered backend refuses loudly, the
+  single-state cell rejects ``c0``).
 
 Everything here is fast; the wide randomly-drawn GRU sweeps live in
 ``test_backend_equiv.py`` on the slow tier.
@@ -51,7 +51,7 @@ FMT = FxpFormat(8, 16)
 LSTM_FORWARD_PARAMS = (
     "params", "xs", "backend", "fmt", "luts", "h0", "c0",
     "return_sequence", "return_state", "num_layers", "interpret",
-    "block_b", "block_h", "time_tile",
+    "block_b", "time_tile",
 )
 
 
@@ -69,8 +69,7 @@ def test_lstm_forward_signature_is_unchanged():
 
 def test_lstm_public_types_are_unchanged():
     assert [f.name for f in dataclasses.fields(LSTMParams)] == ["w", "b"]
-    assert LSTM_BACKENDS == ("sequential", "fused", "pallas", "pallas_seq",
-                             "fxp", "pallas_fxp")
+    assert LSTM_BACKENDS == ("sequential", "fused", "fxp", "pallas_fxp")
     assert RECURRENT_BACKENDS == LSTM_BACKENDS
 
 
@@ -179,12 +178,16 @@ def test_gru_qat_freeze_parity():
 # Dispatcher contracts: loud refusals, single-state geometry
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["pallas", "pallas_seq"])
-def test_gru_float_pallas_backends_refuse(backend):
-    p = init_gru_params(jax.random.PRNGKey(0), 2, 8)
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_gru_float_pallas_backends_refuse(cell):
+    """The float Pallas backends are gone: ``"pallas_seq"`` is an unknown
+    name for every cell kind, and the refusal names the four backends."""
+    p = init_recurrent_params(cell, jax.random.PRNGKey(0), 2, 8)
     xs = jnp.asarray(RNG.normal(size=(2, 5, 2)).astype(np.float32))
-    with pytest.raises(NotImplementedError, match="gru"):
-        gru_forward(p, xs, backend=backend)
+    with pytest.raises(ValueError, match="unknown backend 'pallas_seq'") as e:
+        recurrent_forward(cell, p, xs, backend="pallas_seq")
+    for name in ("sequential", "fused", "fxp", "pallas_fxp"):
+        assert repr(name) in str(e.value), name
 
 
 def test_gru_rejects_c0():

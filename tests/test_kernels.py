@@ -1,12 +1,18 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+"""Shape sweeps of the datapath against the ``ref.py`` oracles: the float
+C1+C2 cell and its scan, the C3 LUT and the C4 fixed-point matmul (the
+``repro.core`` ops every backend builds on), the fused fixed-point kernel and
+the SSD scan (Pallas, ``interpret=True``)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.lut import LutSpec, build_table
+from repro.core.fxp import FxpFormat, dequantize, fxp_matmul, quantize
+from repro.core.lstm import LSTMParams, lstm_cell_fused, lstm_forward
+from repro.core.lut import LutSpec, build_table, lut_apply, lut_apply_fxp
 from repro.kernels import ops, ref
+from repro.kernels.lstm_fxp_seq import lstm_sequence_fxp_pallas
 
 RNG = np.random.default_rng(0)
 
@@ -15,39 +21,47 @@ def _rand(shape, dtype=np.float32, scale=1.0):
     return jnp.asarray((RNG.normal(size=shape) * scale).astype(dtype))
 
 
+def _gate_major(w, b):
+    """Stacked ``(F, 4H)``/``(4H,)`` -> the oracle's gate-major
+    ``(4, F, H)``/``(4, H)``."""
+    F, h4 = w.shape
+    return w.reshape(F, 4, h4 // 4).transpose(1, 0, 2), b.reshape(4, h4 // 4)
+
+
 # ---------------------------------------------------------------------------
-# fused LSTM step
+# fused LSTM step (C1+C2): lstm_cell_fused, the "fused" backend's cell
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("b,f,h", [(1, 3, 4), (8, 24, 20), (5, 21, 20),
                                    (16, 40, 33), (128, 64, 128), (130, 48, 129)])
 @pytest.mark.parametrize("dtype", [np.float32])
 def test_lstm_step_sweep(b, f, h, dtype):
-    xh = _rand((b, f), dtype)
-    w = _rand((4, f, h), dtype, 0.2)
-    bias = _rand((4, h), dtype, 0.1)
+    """``f`` input features, ``h`` hidden units, batch ``b``."""
+    x = _rand((b, f), dtype)
+    h0 = _rand((b, h), dtype)
+    w = _rand((f + h, 4 * h), dtype, 0.2)
+    bias = _rand((4 * h,), dtype, 0.1)
     c = _rand((b, h), dtype)
-    h1, c1 = ops.lstm_step(xh, w, bias, c, impl="ref")
-    h2, c2 = ops.lstm_step(xh, w, bias, c, impl="interpret", block_b=64, block_h=64)
+    h1, c1 = ref.lstm_step_ref(jnp.concatenate([x, h0], axis=-1),
+                               *_gate_major(w, bias), c)
+    h2, c2 = lstm_cell_fused(LSTMParams(w, bias), x, h0, c)
     np.testing.assert_allclose(h1, h2, atol=2e-6)
     np.testing.assert_allclose(c1, c2, atol=2e-6)
 
 
-# Batch sizes deliberately NOT multiples of block_b=4 (1 < block, 5 and 9
-# straddle a partial tile) so the padding path is always exercised, and
-# n_seq in {1, 7, 24} so the fori_loop time slicing covers degenerate,
-# odd, and paper-Fig.6-scale sequence lengths.
+# Batch sizes 1, 5, 9 and n_seq in {1, 7, 24}: degenerate, odd, and
+# paper-Fig.6-scale sequence lengths through the "fused" backend's scan.
 @pytest.mark.parametrize("b", [1, 5, 9])
 @pytest.mark.parametrize("t", [1, 7, 24])
 @pytest.mark.parametrize("n_in,h", [(2, 20)])
 def test_lstm_sequence_sweep(b, t, n_in, h):
     xs = _rand((b, t, n_in))
-    w = _rand((4, n_in + h, h), scale=0.2)
-    bias = _rand((4, h), scale=0.1)
+    w = _rand((n_in + h, 4 * h), scale=0.2)
+    bias = _rand((4 * h,), scale=0.1)
     h0 = jnp.zeros((b, h))
     c0 = jnp.zeros((b, h))
-    r1 = ops.lstm_sequence(xs, w, bias, h0, c0, impl="ref")
-    r2 = ops.lstm_sequence(xs, w, bias, h0, c0, impl="interpret", block_b=4)
+    r1 = ref.lstm_sequence_ref(xs, *_gate_major(w, bias), h0, c0)
+    r2 = lstm_forward(LSTMParams(w, bias), xs, backend="fused", h0=h0, c0=c0)
     np.testing.assert_allclose(r1[0], r2[0], atol=5e-6)
     np.testing.assert_allclose(r1[1], r2[1], atol=5e-6)
 
@@ -55,14 +69,13 @@ def test_lstm_sequence_sweep(b, t, n_in, h):
 def test_lstm_sequence_return_sequence():
     b, t, n_in, h = 5, 7, 3, 16
     xs = _rand((b, t, n_in))
-    w = _rand((4, n_in + h, h), scale=0.2)
-    bias = _rand((4, h), scale=0.1)
+    w = _rand((n_in + h, 4 * h), scale=0.2)
+    bias = _rand((4 * h,), scale=0.1)
     h0 = jnp.zeros((b, h))
     c0 = jnp.zeros((b, h))
-    from repro.kernels.lstm_step import lstm_sequence_pallas
-    h_seq, hT, cT = lstm_sequence_pallas(xs, w, bias, h0, c0, block_b=4,
-                                         return_sequence=True, interpret=True)
-    hr, cr = ops.lstm_sequence(xs, w, bias, h0, c0, impl="ref")
+    h_seq, (hT, cT) = lstm_forward(LSTMParams(w, bias), xs, backend="fused",
+                                   h0=h0, c0=c0, return_sequence=True)
+    hr, cr = ref.lstm_sequence_ref(xs, *_gate_major(w, bias), h0, c0)
     assert h_seq.shape == (b, t, h)
     np.testing.assert_allclose(h_seq[:, -1], hr, atol=5e-6)
     np.testing.assert_allclose(hT, hr, atol=5e-6)
@@ -83,20 +96,23 @@ def _fxp_seq_inputs(b, t, n_in, h, total):
 
 @pytest.mark.parametrize("b,t", [(1, 1), (5, 7), (9, 24)])
 @pytest.mark.parametrize("frac,total", [(8, 16), (6, 12)])
-@pytest.mark.parametrize("mxu", [True, False])
-def test_lstm_sequence_fxp_kernel_vs_oracle(b, t, frac, total, mxu):
+@pytest.mark.parametrize("time_tile", [None, 4])
+def test_lstm_sequence_fxp_kernel_vs_oracle(b, t, frac, total, time_tile):
     from repro.core.lut import make_lut_pair
     n_in, h = 2, 20
     qxs, qw, qb = _fxp_seq_inputs(b, t, n_in, h, total)
     luts = make_lut_pair(64)
     (sig_t, sig_s), (tanh_t, tanh_s) = luts["sigmoid"], luts["tanh"]
-    kw = dict(frac_bits=frac, total_bits=total,
-              sig_lo=sig_s.bounds[0], sig_hi=sig_s.bounds[1],
-              tanh_lo=tanh_s.bounds[0], tanh_hi=tanh_s.bounds[1])
-    o1 = ops.lstm_sequence_fxp(qxs, qw, qb, None, None, sig_t, tanh_t,
-                               impl="ref", **kw)
-    o2 = ops.lstm_sequence_fxp(qxs, qw, qb, None, None, sig_t, tanh_t,
-                               impl="interpret", block_b=4, mxu_onehot=mxu, **kw)
+    o1 = ref.lstm_sequence_fxp_ref(qxs, qw, qb, None, None, sig_t, tanh_t,
+                                   frac_bits=frac, total_bits=total,
+                                   sig_bounds=sig_s.bounds,
+                                   tanh_bounds=tanh_s.bounds)
+    o2 = lstm_sequence_fxp_pallas(
+        qxs, qw, qb, None, None, sig_t, tanh_t,
+        frac_bits=frac, total_bits=total,
+        sig_lo=sig_s.bounds[0], sig_hi=sig_s.bounds[1],
+        tanh_lo=tanh_s.bounds[0], tanh_hi=tanh_s.bounds[1],
+        block_b=4, time_tile=time_tile, interpret=True)
     np.testing.assert_array_equal(np.asarray(o1[0]), np.asarray(o2[0]))
     np.testing.assert_array_equal(np.asarray(o1[1]), np.asarray(o2[1]))
 
@@ -146,42 +162,48 @@ def test_fxp_kernel_refuses_inexact_widths(frac, total, n_in):
     lanes; anything wider is refused at trace time, never computed wrong."""
     qxs, qw, qb = _fxp_seq_inputs(2, 3, n_in, 20, 16)
     with pytest.raises(ValueError, match="total bits|contraction width"):
-        ops.lstm_sequence_fxp(qxs, qw, qb, impl="interpret",
-                              frac_bits=frac, total_bits=total)
+        lstm_sequence_fxp_pallas(qxs, qw, qb, frac_bits=frac,
+                                 total_bits=total, interpret=True)
 
 
 def test_lstm_sequence_fxp_no_lut_and_seq_output():
     b, t, n_in, h = 3, 7, 1, 20
     qxs, qw, qb = _fxp_seq_inputs(b, t, n_in, h, 16)
-    o1 = ops.lstm_sequence_fxp(qxs, qw, qb, impl="ref", return_sequence=True)
-    o2 = ops.lstm_sequence_fxp(qxs, qw, qb, impl="interpret", block_b=2,
-                               return_sequence=True)
+    o1 = ref.lstm_sequence_fxp_ref(qxs, qw, qb, return_sequence=True)
+    o2 = lstm_sequence_fxp_pallas(qxs, qw, qb, block_b=2,
+                                  return_sequence=True, interpret=True)
     assert o1[0].shape == (b, t, h)
     for a, e in zip(o1, o2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
 
 
 # ---------------------------------------------------------------------------
-# LUT activation
+# LUT activation (C3): core.lut on float and on fixed-point inputs
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("fn", ["sigmoid", "tanh"])
 @pytest.mark.parametrize("depth", [64, 256])
 @pytest.mark.parametrize("shape", [(7,), (3, 50), (2, 5, 130)])
-@pytest.mark.parametrize("mxu", [True, False])
-def test_lut_act_sweep(fn, depth, shape, mxu):
+@pytest.mark.parametrize("inp", ["float", "fxp"])
+def test_lut_act_sweep(fn, depth, shape, inp):
     spec = LutSpec(fn, depth)
     table = build_table(spec)
     lo, hi = spec.bounds
     x = _rand(shape, scale=4.0)
-    y1 = ops.lut_act(x, table, lo, hi, impl="ref")
-    y2 = ops.lut_act(x, table, lo, hi, impl="interpret", mxu_onehot=mxu,
-                     block_rows=8)
-    np.testing.assert_allclose(y1, y2, atol=1e-6)
+    if inp == "float":
+        y1 = ref.lut_act_ref(x, table, lo, hi)
+        y2 = lut_apply(x, table, spec)
+        np.testing.assert_allclose(y1, y2, atol=1e-6)
+    else:
+        fmt = FxpFormat(8, 16)
+        q = quantize(x, fmt)
+        y1 = quantize(ref.lut_act_ref(dequantize(q, fmt), table, lo, hi), fmt)
+        y2 = lut_apply_fxp(q, table, spec, fmt)
+        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
 
 
 # ---------------------------------------------------------------------------
-# fixed-point matmul
+# fixed-point matmul (C4): core.fxp.fxp_matmul
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("m,k,n", [(1, 4, 3), (9, 21, 17), (64, 32, 64),
@@ -192,9 +214,8 @@ def test_fxp_matmul_sweep(m, k, n, frac, total):
     aq = jnp.asarray(RNG.integers(-hi, hi, size=(m, k)), jnp.int32)
     bq = jnp.asarray(RNG.integers(-hi, hi, size=(k, n)), jnp.int32)
     bias = jnp.asarray(RNG.integers(-hi // 2, hi // 2, size=(n,)), jnp.int32)
-    o1 = ops.fxp_matmul(aq, bq, bias, frac_bits=frac, total_bits=total, impl="ref")
-    o2 = ops.fxp_matmul(aq, bq, bias, frac_bits=frac, total_bits=total,
-                        impl="interpret", block_m=32, block_n=32)
+    o1 = ref.fxp_matmul_ref(aq, bq, bias, frac, total)
+    o2 = fxp_matmul(aq, bq, FxpFormat(frac, total), bias=bias)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 

@@ -5,8 +5,8 @@ Two contracts (ISSUE 1 acceptance criteria):
 * ``lstm_sequence_fxp_pallas(interpret=True)`` is *integer-equal* (not
   allclose) to ``lstm_layer_fxp`` across the paper's Fig. 6 ``(x, y)``
   format sweep and Table 1 LUT depths, for multiple sequence lengths.
-* ``lstm_forward`` dispatches all six backends through one shared signature,
-  with multi-layer stacking and sequence output.
+* ``lstm_forward`` dispatches all four backends through one shared
+  signature, with multi-layer stacking and sequence output.
 """
 
 import jax
@@ -80,14 +80,14 @@ def test_fused_fxp_sequence_bit_exact_without_luts():
 
 
 # ---------------------------------------------------------------------------
-# Dispatcher: one signature, six backends
+# Dispatcher: one signature, four backends
 # ---------------------------------------------------------------------------
 
 def _forward(backend, params, xs, qp, qxs, fmt, luts, **kw):
     if backend in ("fxp", "pallas_fxp"):
         return lstm_forward(qp, qxs, backend=backend, fmt=fmt, luts=luts,
                             block_b=2, **kw)
-    return lstm_forward(params, xs, backend=backend, block_b=2, block_h=8, **kw)
+    return lstm_forward(params, xs, backend=backend, **kw)
 
 
 def test_all_backends_dispatch_one_signature():
@@ -102,9 +102,8 @@ def test_all_backends_dispatch_one_signature():
         assert h.shape == (B, N_H) and c.shape == (B, N_H), be
 
     # float backends agree numerically
-    for be in ("sequential", "pallas", "pallas_seq"):
-        np.testing.assert_allclose(outs["fused"][0], outs[be][0], atol=1e-5)
-        np.testing.assert_allclose(outs["fused"][1], outs[be][1], atol=1e-5)
+    np.testing.assert_allclose(outs["fused"][0], outs["sequential"][0], atol=1e-5)
+    np.testing.assert_allclose(outs["fused"][1], outs["sequential"][1], atol=1e-5)
     # fxp backends agree bitwise
     np.testing.assert_array_equal(np.asarray(outs["fxp"][0]),
                                   np.asarray(outs["pallas_fxp"][0]))
@@ -112,7 +111,7 @@ def test_all_backends_dispatch_one_signature():
                                   np.asarray(outs["pallas_fxp"][1]))
 
 
-@pytest.mark.parametrize("backend", ["fused", "pallas_seq", "fxp", "pallas_fxp"])
+@pytest.mark.parametrize("backend", ["fused", "sequential", "fxp", "pallas_fxp"])
 def test_return_sequence_last_step_matches_final_state(backend):
     fmt = FxpFormat(8, 16)
     params, xs = _float_setup()
@@ -124,7 +123,7 @@ def test_return_sequence_last_step_matches_final_state(backend):
     np.testing.assert_array_equal(np.asarray(seq[:, -1]), np.asarray(h))
 
 
-@pytest.mark.parametrize("backend", ["fused", "pallas_seq"])
+@pytest.mark.parametrize("backend", ["fused", "sequential"])
 def test_two_layer_stack_float(backend):
     params, xs = _float_setup()
     p2 = init_lstm_params(jax.random.PRNGKey(1), N_H, N_H)
@@ -151,6 +150,39 @@ def test_two_layer_stack_fxp_bit_exact():
     np.testing.assert_array_equal(np.asarray(o_sim[1]), np.asarray(o_ker[1]))
 
 
+@pytest.mark.parametrize("n_layers", [1, 2])
+def test_pallas_fxp_one_branch_at_every_depth(monkeypatch, n_layers):
+    """One layer or a stack, ``pallas_fxp`` takes the one stacked-kernel
+    branch: exactly one call of the stack face with every layer, never the
+    single-layer face, and the result is integer-equal to ``fxp``."""
+    from repro.kernels import lstm_fxp_seq
+
+    fmt = FxpFormat(8, 16)
+    params, xs = _float_setup()
+    layers = [params] + [init_lstm_params(jax.random.PRNGKey(k), N_H, N_H)
+                         for k in range(1, n_layers)]
+    qps = [_quantized(p, xs, fmt)[0] for p in layers]
+    qxs = quantize(xs, fmt)
+    calls = []
+    face = lstm_fxp_seq.lstm_sequence_fxp_stack_pallas
+
+    def counted(*a, **kw):
+        calls.append(len(a[1]))
+        return face(*a, **kw)
+
+    def refused(*a, **kw):
+        raise AssertionError("the dispatcher took the single-layer face")
+
+    monkeypatch.setattr(lstm_fxp_seq, "lstm_sequence_fxp_stack_pallas", counted)
+    monkeypatch.setattr(lstm_fxp_seq, "lstm_sequence_fxp_pallas", refused)
+    got = lstm_forward(qps if n_layers > 1 else qps[0], qxs,
+                       backend="pallas_fxp", fmt=fmt, block_b=2)
+    assert calls == [n_layers]
+    want = lstm_forward(qps, qxs, backend="fxp", fmt=fmt)
+    for a, e in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
+
+
 def test_dispatcher_validation():
     fmt = FxpFormat(8, 16)
     params, xs = _float_setup()
@@ -165,45 +197,61 @@ def test_dispatcher_validation():
 
 
 def test_unbatched_input_pallas_backends():
+    """An unbatched ``(n_seq, n_in)`` input runs the kernel as a batch of
+    one and comes back unbatched, integer-equal to the ``fxp`` oracle, with
+    and without a sequence output."""
+    fmt = FxpFormat(8, 16)
     params, xs = _float_setup()
-    h_ref, c_ref = lstm_forward(params, xs[0], backend="fused")
-    for be in ("pallas", "pallas_seq"):
-        h, c = lstm_forward(params, xs[0], backend=be, block_b=2, block_h=8)
+    qp, qxs = _quantized(params, xs, fmt)
+    luts = make_lut_pair(64)
+    for kw in ({}, {"return_sequence": True}):
+        want = lstm_forward(qp, qxs[0], backend="fxp", fmt=fmt, luts=luts, **kw)
+        got = lstm_forward(qp, qxs[0], backend="pallas_fxp", fmt=fmt,
+                           luts=luts, block_b=2, **kw)
+        h = got[1][0] if kw else got[0]
         assert h.shape == (N_H,)
-        np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref), atol=1e-5)
-        np.testing.assert_allclose(np.asarray(c), np.asarray(c_ref), atol=1e-5)
+        for a, e in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            assert a.shape == e.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
 
 
 def test_extra_leading_batch_dims_fold_into_pallas_batch():
-    """(..., n_seq, n_in) holds for every backend: pallas backends fold the
-    leading dims into one batch axis and unfold on the way out."""
+    """(..., n_seq, n_in) holds for every backend: ``pallas_fxp`` folds the
+    leading dims into the kernel's one batch axis and unfolds on the way
+    out — the same integers as the flat batch and as the ``fxp`` oracle."""
     fmt = FxpFormat(8, 16)
     params, _ = _float_setup()
     xs4 = jnp.asarray(RNG.normal(size=(2, 3, 7, N_IN)).astype(np.float32))
-    h_ref, c_ref = lstm_forward(params, xs4, backend="fused")
-    h, c = lstm_forward(params, xs4, backend="pallas_seq", block_b=2)
-    assert h.shape == (2, 3, N_H)
-    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(c), np.asarray(c_ref), atol=1e-5)
     qp, qxs4 = _quantized(params, xs4, fmt)
+    h, c = lstm_forward(qp, qxs4, backend="pallas_fxp", fmt=fmt, block_b=2)
+    assert h.shape == (2, 3, N_H) and c.shape == (2, 3, N_H)
+    h_flat, c_flat = lstm_forward(qp, qxs4.reshape(6, 7, N_IN),
+                                  backend="pallas_fxp", fmt=fmt, block_b=2)
+    np.testing.assert_array_equal(np.asarray(h).reshape(6, N_H),
+                                  np.asarray(h_flat))
+    np.testing.assert_array_equal(np.asarray(c).reshape(6, N_H),
+                                  np.asarray(c_flat))
     a = lstm_forward(qp, qxs4, backend="fxp", fmt=fmt)
-    b = lstm_forward(qp, qxs4, backend="pallas_fxp", fmt=fmt, block_b=2)
-    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
-    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(h))
+    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(c))
 
 
 def test_per_layer_initial_state_list_unbatched_input():
+    fmt = FxpFormat(8, 16)
     params, xs = _float_setup()
     p2 = init_lstm_params(jax.random.PRNGKey(1), N_H, N_H)
-    h0 = [jnp.full((N_H,), 0.1), jnp.full((N_H,), -0.1)]
-    c0 = [jnp.zeros((N_H,)), jnp.zeros((N_H,))]
-    h_ref, c_ref = lstm_forward([params, p2], xs[0], backend="fused",
+    qp1, qxs = _quantized(params, xs, fmt)
+    qp2 = LSTMParams(w=quantize(p2.w, fmt), b=quantize(p2.b, fmt))
+    h0 = [quantize(jnp.full((N_H,), 0.1), fmt),
+          quantize(jnp.full((N_H,), -0.1), fmt)]
+    c0 = [jnp.zeros((N_H,), jnp.int32), quantize(jnp.full((N_H,), 0.2), fmt)]
+    h_ref, c_ref = lstm_forward([qp1, qp2], qxs[0], backend="fxp", fmt=fmt,
                                 h0=h0, c0=c0)
-    h, c = lstm_forward([params, p2], xs[0], backend="pallas_seq",
+    h, c = lstm_forward([qp1, qp2], qxs[0], backend="pallas_fxp", fmt=fmt,
                         h0=h0, c0=c0, block_b=2)
-    assert h.shape == (N_H,)
-    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(c), np.asarray(c_ref), atol=1e-5)
+    assert h.shape == (N_H,) and c.shape == (N_H,)
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(h_ref))
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(c_ref))
 
 # ---------------------------------------------------------------------------
 # Mixed precision + heterogeneous hidden sizes through the FUSED stack kernel
